@@ -16,15 +16,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu.parallel import create_hierarchical_mesh
 from horovod_tpu.parallel.hierarchical import hierarchical_allreduce
-
-# jax < 0.5 only exposes jax.shard_map through the compat alias the
-# horovod_tpu import installs — bind it after that import.
-shard_map = jax.shard_map
 
 
 def main():

@@ -1,15 +1,15 @@
 """Flash-attention block-size sweep: pick HOROVOD_FLASH_BLOCK_Q/K.
 
-The r04 kernel rework runs the score/output/gradient matmuls in the
-input dtype (bf16 on the MXU) and makes the q/k block sizes
-env-tunable; this sweep measures fwd+bwd wall time across (T, bq, bk)
-combinations on the real chip to pick shipping defaults and quantify
-the mixed-precision win vs the r04 long-T sweep (flash_r4.jsonl, which
-ran the all-f32 kernel at 128x128).
+The kernel runs the score/output/gradient matmuls in the input dtype
+(bf16 on the MXU) and its q/k block sizes are env-tunable; this sweep
+measures fwd+bwd wall time across (T, bq, bk) combinations on the real
+chip to pick shipping defaults and quantify the mixed-precision win vs
+the earlier long-T sweep of the all-f32 kernel at 128x128.
 
-Each config runs in a fresh killable subprocess (same wedge defense as
-flash_sweep.py).  One JSON line per config on stdout; human summary on
-stderr.  Results feed docs/PERF_NOTES.md.
+Each config runs in a fresh subprocess (the block sizes are read from
+the environment; the parent never touches the backend, so one child at
+a time owns the chip).  One JSON line per config on stdout; human
+summary on stderr.  Results feed docs/PERF_NOTES.md.
 """
 
 import json
@@ -27,7 +27,9 @@ CHILD_CODE = r"""
 import json, sys, time
 sys.path.insert(0, {repo!r})
 import jax, jax.numpy as jnp
+from horovod_tpu.common.util import configure_compile_cache
 
+configure_compile_cache()
 import os
 T, B, BQ, BK = (int(a) for a in sys.argv[1:5])
 # The kernel reads tile sizes from env; set them from argv so a
@@ -48,20 +50,14 @@ def loss(q, k, v):
 step = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
 
 
-def sync(x):
-    import numpy as np
-    jax.block_until_ready(x)
-    return float(np.asarray(jax.tree_util.tree_leaves(x)[0]).ravel()[0])
-
-
 warmup, iters = 2, 5
 for _ in range(warmup):
     g = step(q, k, v)
-sync(g)
+jax.block_until_ready(g)
 t0 = time.perf_counter()
 for _ in range(iters):
     g = step(q, k, v)
-sync(g)
+jax.block_until_ready(g)
 dt = (time.perf_counter() - t0) / iters
 print(json.dumps({{"ms_iter": dt * 1e3, "tok_per_s": B * T / dt}}))
 """

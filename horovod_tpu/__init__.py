@@ -18,32 +18,6 @@ Canonical usage mirrors `import horovod.torch as hvd`:
 
 from .version import __version__
 
-# jax < 0.5 compat: `jax.shard_map` (used throughout this package and its
-# tests) only exists as `jax.experimental.shard_map.shard_map` there, and
-# spells `check_vma` as `check_rep`.  Install a translating alias before
-# any submodule import so every `from jax import shard_map` resolves.
-import jax as _jax  # noqa: E402
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def _shard_map_compat(f, *args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_impl(f, *args, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
-from jax import lax as _lax  # noqa: E402
-
-if not hasattr(_lax, "axis_size"):
-    from jax import core as _jax_core
-
-    def _axis_size_compat(axis_name):
-        return _jax_core.axis_frame(axis_name)
-
-    _lax.axis_size = _axis_size_compat
-
 from .common.basics import (  # noqa: F401
     init,
     shutdown,

@@ -365,10 +365,6 @@ CATALOG: Tuple[EnvVar, ...] = (
     _v("HOROVOD_JOIN_MODE", "0", "ops",
        "1 arms hvd.join() semantics: ranks that exhausted data "
        "contribute masked zeros.", "PROCESS_SETS.md"),
-    _v("HOROVOD_BACKEND_PROBE_TIMEOUT", "20.0", "ops",
-       "Seconds the guarded jax.devices() probe waits before declaring "
-       "the accelerator unreachable (bench.py uses 120).",
-       "COMPONENTS.md"),
     _v("HOROVOD_FUSED_COLLECTIVES", "0", "ops",
        "1 routes bucket reductions and the ZeRO-1 scatter/gather pair "
        "through the chunked fused computation-collective pipeline.",
@@ -380,9 +376,6 @@ CATALOG: Tuple[EnvVar, ...] = (
     _v("HOROVOD_ADASUM_PALLAS", "0", "ops",
        "1 routes Adasum dot/norm/scaled-add through the fused Pallas "
        "kernels.", "ADASUM.md"),
-    _v("HOROVOD_PALLAS_INTERPRET", "0", "ops",
-       "1 runs Pallas kernels in interpret mode (CPU testing of TPU "
-       "kernel code).", "PERF_NOTES.md"),
     _v("HOROVOD_FLASH_ATTENTION", "0", "ops",
        "1 enables the Pallas flash-attention kernel in ring/sequence "
        "parallel attention.", "PERF_NOTES.md"),
@@ -401,17 +394,14 @@ CATALOG: Tuple[EnvVar, ...] = (
 
     # -- bench harness ---------------------------------------------------
     _v("HOROVOD_BENCH_BATCH", "0 (auto)", "bench",
-       "Global batch override for bench.py (0 picks the per-backend "
-       "default).", "BENCHMARKS.md"),
+       "Global batch override for bench.py (0 = 256).",
+       "BENCHMARKS.md"),
     _v("HOROVOD_BENCH_MEGASTEP", "8", "bench",
        "Megastep k for bench.py timing (1 restores one dispatch per "
        "step).", "BENCHMARKS.md"),
     _v("HOROVOD_BENCH_LEGACY_PIPELINE", "0", "bench",
        "1 restores the pre-overlap barriered gradient pipeline for A/B "
        "runs.", "BENCHMARKS.md"),
-    _v("HOROVOD_BENCH_PROBE_WINDOW", "900", "bench",
-       "Seconds bench.py waits for the accelerator probe subprocess.",
-       "BENCHMARKS.md"),
     _v("HOROVOD_BENCH_SIM_RUNS", "7", "bench",
        "Repetitions of each simulated-scaling bench point.",
        "BENCHMARKS.md"),
@@ -422,8 +412,8 @@ CATALOG: Tuple[EnvVar, ...] = (
        "Extra XLA_FLAGS appended for bench.py child processes.",
        "BENCHMARKS.md"),
     _v("HOROVOD_BENCH_CACHE_MAX_AGE_H", "24", "bench",
-       "Hours before bench.py's cached last-known-good on-chip record "
-       "is reported as stale instead of silently reused.",
+       "Hours after which the previous record in a BENCH_*.json file is "
+       "marked stale and not compared against.",
        "BENCHMARKS.md"),
     _v("HOROVOD_BENCH_CHAOS_NP", "2", "bench",
        "Fleet size of the `bench.py --chaos` fault-loaded soak "

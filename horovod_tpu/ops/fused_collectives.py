@@ -48,15 +48,12 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
 
 from ..common import util
 from ..common.exceptions import HorovodTpuError
-from .pallas_kernels import _LANES, _interpret, PALLAS_AVAILABLE
+from .pallas_kernels import _LANES, _interpret
 from .wire import _BLOCK, get_codec
-
-if PALLAS_AVAILABLE:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
 
 
 def fused_enabled() -> bool:
@@ -71,7 +68,7 @@ def fused_pallas_enabled(n_elements: int) -> bool:
     kernel (HOROVOD_FUSED_PALLAS=1) instead of the XLA dot
     decomposition.  Mirrors `pallas_enabled`: opt-in, and tiny operands
     stay on XLA where kernel launch overhead would dominate."""
-    if not PALLAS_AVAILABLE or n_elements < _LANES * _LANES:
+    if n_elements < _LANES * _LANES:
         return False
     return util.env_bool("FUSED_PALLAS", False)
 
@@ -271,10 +268,6 @@ def pallas_matmul(a: jax.Array, b: jax.Array) -> jax.Array:
     f32 accumulation — the compute stage of the fused chunks when
     `fused_pallas_enabled`.  Interpret mode (`_interpret()`) keeps the
     kernel CI-runnable on CPU; zero padding is exact for matmul."""
-    if not PALLAS_AVAILABLE:
-        raise HorovodTpuError(
-            "pallas_matmul requires jax.experimental.pallas (gate calls "
-            "on fused_pallas_enabled)")
     (m, k), (k2, n) = a.shape, b.shape
     if k != k2:
         raise HorovodTpuError(

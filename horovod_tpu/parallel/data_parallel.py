@@ -38,12 +38,16 @@ from ..utils import timeline as _tl
 
 def shard_batch(batch: Any, mesh: Optional[Mesh] = None) -> Any:
     """Place a host batch pytree onto the mesh, sharded on dim 0 over the
-    `hvd` axis (the input-pipeline half of data parallelism)."""
+    `hvd` axis (the input-pipeline half of data parallelism).
+
+    Each process passes the rows of ITS OWN ranks — Horovod's contract:
+    every rank feeds its own batch.  With one process that is the whole
+    batch; with several, the global batch is their concatenation in rank
+    order (a plain `device_put` onto a mesh that spans processes would
+    instead demand the same full batch from every process)."""
     mesh = mesh or basics.global_mesh()
     sharding = NamedSharding(mesh, P(GLOBAL_AXIS))
-    return jax.tree_util.tree_map(
-        lambda x: jax.device_put(x, sharding), batch
-    )
+    return jax.make_array_from_process_local_data(sharding, batch)
 
 
 def _bucket_permutation(n, bucket_order):

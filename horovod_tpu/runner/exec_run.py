@@ -8,7 +8,9 @@ TPU-native differences: workers bootstrap through
 `jax.distributed.initialize` (coordinator = rank-0 host), so the env
 contract is HOROVOD_COORDINATOR_ADDR/NUM_PROCESSES/PROCESS_ID plus the
 classic HOROVOD_RANK/SIZE/LOCAL_RANK/... set, and the rendezvous KV serves
-the control plane only.
+the control plane only.  A chip belongs to one process at a time, so
+several slots on one TPU host each get a chip of their own through
+libtpu's per-process variables (`tpu_chip_env`).
 """
 
 from __future__ import annotations
@@ -34,6 +36,49 @@ LOCAL_HOSTNAMES = ("localhost", "127.0.0.1", socket.gethostname())
 # Port the rank-0 worker binds its jax.distributed coordinator to when it
 # runs on a remote host (free-port probing is only possible locally).
 DEFAULT_COORDINATOR_PORT = 46327
+
+
+# libtpu's arrangement of one-chip processes over the chips of ONE host,
+# by slots on it (the table jax's own multi-process TPU test launcher
+# uses).  4 = the v5e 2x2 host this was run on; 8 is taken from that
+# table and has not been run here.
+_TPU_PROCESS_BOUNDS = {4: "2,2,1", 8: "4,2,1"}
+# libtpu's default port for the first process of a host; slot i takes +i.
+_TPU_PROCESS_PORT = 8476
+
+
+def tpu_chip_env(slot: SlotInfo, env: Dict[str, str]) -> Dict[str, str]:
+    """Variables that make local slot i own chip i and nothing else, so
+    that N slots on a host are N one-chip ranks of one job (Horovod's
+    one rank per accelerator) instead of N processes reaching for every
+    chip.  Nothing is needed for one slot a host — that process drives
+    all the local chips — or when `env` sends the job to the CPU.  A
+    layout that cannot be given a chip a slot is refused here, before
+    anything is spawned."""
+    if slot.local_size == 1 or \
+            env.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        return {}
+    bounds = _TPU_PROCESS_BOUNDS.get(slot.local_size)
+    if bounds is None or slot.cross_size != 1:
+        raise HorovodTpuError(
+            f"cannot give each of {slot.local_size} slots on "
+            f"{slot.hostname} a TPU chip of its own: one chip a process "
+            f"is supported on a single host with "
+            f"{sorted(_TPU_PROCESS_BOUNDS)} slots.  Use one slot per "
+            f"host (-H {slot.hostname}:1 — that process drives all the "
+            f"host's chips), or set JAX_PLATFORMS=cpu if the job does "
+            f"not run on TPUs.")
+    ports = [_TPU_PROCESS_PORT + i for i in range(slot.local_size)]
+    return {
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": bounds,
+        "TPU_PROCESS_ADDRESSES": ",".join(f"localhost:{p}" for p in ports),
+        "TPU_PROCESS_PORT": str(ports[slot.local_rank]),
+        "TPU_VISIBLE_CHIPS": str(slot.local_rank),
+        "CLOUD_TPU_TASK_ID": str(slot.local_rank),
+        # libtpu otherwise refuses a second process on the host
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
 
 
 def _is_local(hostname: str) -> bool:
@@ -75,6 +120,7 @@ def slot_env(
         "HOROVOD_RENDEZVOUS_PORT": str(settings.rendezvous_port or 0),
         "HOROVOD_SECRET_KEY": secret,
     })
+    env.update(tpu_chip_env(slot, env))
     if settings.timeline_filename:
         # Workers handle per-rank suffixing themselves (timeline.py
         # init_from_env): rank 0 writes the base file; other ranks only
@@ -159,8 +205,10 @@ def exec_run(settings: Settings, slots: List[SlotInfo],
     procs = []
     out_files = []
     try:
-        for slot in slots:
-            env = slot_env(slot, settings, server.secret, coordinator_addr)
+        # Every env first: a layout slot_env refuses must spawn nothing.
+        envs = [slot_env(slot, settings, server.secret, coordinator_addr)
+                for slot in slots]
+        for slot, env in zip(slots, envs):
             cmd = build_command(slot, settings, env)
             stdout = stderr = None
             if settings.output_filename:

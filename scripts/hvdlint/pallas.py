@@ -1,11 +1,9 @@
 """pallas-guard: every Pallas kernel entry point must degrade to CPU.
 
-The repo's contract (docs/PERF_NOTES.md, docs/FUSED_COLLECTIVES.md) is
-that tier-1 runs EVERY code path on CPU: TPU kernels execute in Pallas
-interpret mode instead of being skipped.  That only holds if each
-``pl.pallas_call`` site threads a runtime interpret decision
-(``interpret=_interpret()``) and the ``jax.experimental.pallas`` import
-itself cannot crash import time on builds without Pallas.
+The repo's contract (docs/FUSED_COLLECTIVES.md) is that tier-1 runs
+EVERY code path on CPU: TPU kernels execute in Pallas interpret mode
+instead of being skipped.  That only holds if each ``pl.pallas_call``
+site threads a runtime interpret decision (``interpret=_interpret()``).
 
 Rules:
 
@@ -17,11 +15,6 @@ Rules:
     that either never interprets (broken on CPU) or always interprets
     (broken on TPU); the decision must be a runtime call like
     ``pallas_kernels._interpret()``.
-``unguarded-import``
-    a module-level ``jax.experimental.pallas`` import at function
-    nesting depth zero with no try/except or ``if`` guard around it —
-    `pallas_kernels.py` sets ``PALLAS_AVAILABLE`` exactly so other
-    modules can gate on it.
 """
 
 from __future__ import annotations
@@ -31,13 +24,10 @@ from typing import List
 
 from .core import Analyzer, Finding, Project
 
-_PALLAS_MODULES = ("jax.experimental.pallas",)
-
 
 class PallasGuard(Analyzer):
     name = "pallas-guard"
-    description = ("pallas_call sites carry a runtime interpret= "
-                   "fallback and pallas imports are guarded")
+    description = "pallas_call sites carry a runtime interpret= fallback"
 
     def run(self, project: Project) -> List[Finding]:
         out: List[Finding] = []
@@ -48,11 +38,6 @@ class PallasGuard(Analyzer):
             for node in ast.walk(tree):
                 if isinstance(node, ast.Call):
                     self._check_call(sf, node, out)
-            # Only imports that are DIRECT children of the module body
-            # are unconditional: anything nested under try/except,
-            # `if PALLAS_AVAILABLE:`, a function, etc. is a guard.
-            for node in tree.body:
-                self._check_import(sf, node, out)
         return out
 
     def _check_call(self, sf, node: ast.Call, out: List[Finding]) -> None:
@@ -76,25 +61,3 @@ class PallasGuard(Analyzer):
                     f"{name}(...) pins interpret={interp.value.value!r} "
                     f"at compile time; the fallback must be a runtime "
                     f"decision (interpret=_interpret())"))
-
-    def _check_import(self, sf, node: ast.stmt,
-                      out: List[Finding]) -> None:
-        mods: List[str] = []
-        if isinstance(node, ast.Import):
-            mods = [a.name for a in node.names]
-        elif isinstance(node, ast.ImportFrom) and node.module:
-            # `from jax.experimental import pallas` spells the module
-            # across node.module and the alias name.
-            mods = [node.module] + [f"{node.module}.{a.name}"
-                                    for a in node.names]
-        for mod in mods:
-            if any(mod == p or mod.startswith(p + ".")
-                   for p in _PALLAS_MODULES):
-                if not sf.allowed("unguarded-import", node.lineno):
-                    out.append(Finding(
-                        self.name, "unguarded-import", sf.rel,
-                        node.lineno,
-                        f"unconditional top-level import of {mod}; "
-                        f"wrap in try/except or gate on "
-                        f"PALLAS_AVAILABLE so builds without Pallas "
-                        f"still import"))

@@ -12,7 +12,7 @@ and, for the toggled run, the controller's decision trace.
 With random weights an independent draft rarely agrees with the
 target, so forced-spec numbers here are a LOWER bound; the self-draft
 config shows the 100%-acceptance upper bound on round efficiency.
-Each config runs in a fresh killable subprocess; one JSON line per
+Each config runs in a fresh subprocess; one JSON line per
 config on stdout, human table on stderr, machine-readable record
 appended to BENCH_serve.json.
 
@@ -40,10 +40,9 @@ CHILD_CODE = r"""
 import json, sys
 sys.path.insert(0, {repo!r})
 import jax, jax.numpy as jnp
+from horovod_tpu.common.util import configure_compile_cache
 
-if {tiny!r} == "1":
-    jax.config.update("jax_platforms", "cpu")
-
+configure_compile_cache()
 from horovod_tpu.models import TransformerConfig, transformer_init
 from horovod_tpu.serve import InferenceServer
 from horovod_tpu.serve.loadgen import make_trace, run_trace

@@ -30,21 +30,15 @@ import sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 os.environ["JAX_PLATFORMS"] = "cpu"
 
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
 
+import jax  # noqa: E402
 import numpy as np  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 import horovod_tpu as hvd  # noqa: E402
-
-# After the hvd import: jax < 0.5 only gains `jax.shard_map` through the
-# compat alias horovod_tpu installs.
-shard_map = jax.shard_map  # noqa: E402
 from horovod_tpu.parallel import hierarchical  # noqa: E402
 from horovod_tpu.parallel.mesh import create_hierarchical_mesh  # noqa: E402
 

@@ -64,6 +64,8 @@ class TestCrossProcessCollectives:
             assert res["alltoall"] == [0.0, 1.0]
             # summed tensor rows, one per rank
             assert res["reducescatter"] == [3.0, 3.0]
+            # each rank fed its own rows: mean of rank values 0,1
+            assert res["data_parallel_mean"] == 0.5
         # Singleton process sets at np=2: each rank reduces alone.
         assert results[0]["ps_sum"] == [1.0]
         assert results[1]["ps_sum"] == [2.0]
