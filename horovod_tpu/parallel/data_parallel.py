@@ -18,6 +18,7 @@ update in place in HBM.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 from typing import Any, Callable, Optional, Sequence
@@ -947,11 +948,14 @@ def data_parallel(
             return jax.device_put(x, sharding)
         return x
 
-    # Per-step host spans for the fleet tracer (docs/TRACE.md): one
-    # `ph="X"` record per dispatched step, carrying the step ID the
-    # cross-rank merger aligns on.  Gate exists so a timeline run can
-    # drop back to instants-only.
-    trace_step_spans = util.env_bool("TRACE_STEP_SPANS", True)
+    # Per-step host spans: `hvd.step.step` in a profiler trace, beside
+    # the device's operations, and for the fleet tracer (docs/TRACE.md)
+    # one `ph="X"` record per dispatched step in a HOROVOD_TIMELINE
+    # file, carrying the step ID the cross-rank merger aligns on.  Gate
+    # exists so a timeline run can drop back to instants-only.
+    step_span = ((lambda: _tl.span("step", "step"))
+                 if util.env_bool("TRACE_STEP_SPANS", True)
+                 else contextlib.nullcontext)
 
     def call(*args):
         n_args = len(args)
@@ -982,32 +986,31 @@ def data_parallel(
                 del compiled_cache[k]
             compiled_cache[key] = entry
         fn, in_shardings = entry
-        tl = _tl.get_timeline()
         t0 = time.perf_counter()
-        t0_us = (tl.now_us()
-                 if tl is not None and trace_step_spans else None)
-        args = tuple(
-            (jax.tree_util.tree_map(lambda x, s=s: _coerce(x, s), a)
-             if isinstance(s, NamedSharding)
-             # arg_specs entry: a sharding tree mirroring the arg's own
-             # structure, so pair the two trees leaf-by-leaf.
-             else jax.tree_util.tree_map(_coerce, a, s))
-            for a, s in zip(args, in_shardings)
-        )
-        out = fn(*args)
-        # Feed the autotuner (HOROVOD_AUTOTUNE=1): one throughput sample
-        # per steps_per_sample invocations drives the GP/EI proposal loop
-        # (reference: parameter_manager.cc fed from the runtime, not by
-        # user code).
-        _autotune_record(args)
-        # Step-cycle marker (reference: HOROVOD_TIMELINE_MARK_CYCLES
-        # marks each runloop cycle; the SPMD analog is one compiled step).
-        if tl is not None:
-            tl.mark_cycle()
-            if t0_us is not None:
-                # Emitted after mark_cycle so the span carries the ID of
-                # the step it measured (step N ends at CYCLE_N).
-                tl.complete("step", category="step", start_us=t0_us)
+        # The timeline's `step` event is written on leaving, so after
+        # mark_cycle: it carries the ID of the step it measured (step N
+        # ends at CYCLE_N).
+        with step_span():
+            args = tuple(
+                (jax.tree_util.tree_map(lambda x, s=s: _coerce(x, s), a)
+                 if isinstance(s, NamedSharding)
+                 # arg_specs entry: a sharding tree mirroring the arg's
+                 # own structure, so pair the two trees leaf-by-leaf.
+                 else jax.tree_util.tree_map(_coerce, a, s))
+                for a, s in zip(args, in_shardings)
+            )
+            out = fn(*args)
+            # Feed the autotuner (HOROVOD_AUTOTUNE=1): one throughput
+            # sample per steps_per_sample invocations drives the GP/EI
+            # proposal loop (reference: parameter_manager.cc fed from the
+            # runtime, not by user code).
+            _autotune_record(args)
+            # Step-cycle marker (reference: HOROVOD_TIMELINE_MARK_CYCLES
+            # marks each runloop cycle; the SPMD analog is one compiled
+            # step).
+            tl = _tl.get_timeline()
+            if tl is not None:
+                tl.mark_cycle()
         if _met.enabled():
             _met.steps.inc()
             # Host-side wall time of this step's dispatch; the fleet view

@@ -150,8 +150,11 @@ class FlightRecorder:
 
     # -- feed ----------------------------------------------------------
 
-    def now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+    def now_us(self, at: Optional[float] = None) -> float:
+        """This recorder's clock: now, or of the `time.perf_counter()`
+        reading `at` a caller already holds."""
+        return ((time.perf_counter() if at is None else at)
+                - self._t0) * 1e6
 
     def record(self, kind: str, data: Optional[Dict] = None,
                step: Optional[int] = None,

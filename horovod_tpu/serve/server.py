@@ -30,6 +30,12 @@ token is the argmax of target logits computed over a correct prefix
 readable before they are overwritten — the same always-write-before-
 read ring property ``transformer_speculative_generate`` relies on).
 
+The host's side of a step is five spans under one
+(`utils/timeline.span`, category ``serve``: ``step`` holding ``admit``
+with one ``prefill`` a request, ``sample``, ``launch``, ``fetch``,
+``observe``; docs/SERVING.md has the table), so a profiler trace shows
+which of them the device waits for.
+
 All host orchestration (clocks, metrics, env) stays OUTSIDE the jitted
 programs; the compiled pieces are the same module-cached
 ``_spec_step_fn`` / ``_spec_extend_fn`` programs the speculative
@@ -61,7 +67,7 @@ from ..models.decode import (
     transformer_prefill,
 )
 from ..utils import autotune
-from ..utils.timeline import get_timeline
+from ..utils.timeline import get_timeline, span
 from .flightrec import FlightRecorder
 from .pool import PagedKVPool, PoolExhaustedError
 from .scheduler import ActiveSeq, ContinuousScheduler, Request
@@ -211,12 +217,9 @@ class InferenceServer:
                       max_new_tokens=max_new_tokens, eos_id=eos_id,
                       arrival_step=self.step_no, slo_class=slo_class)
         self._submit_wall[req_id] = time.perf_counter()
-        tl = get_timeline()
         self._req_obs[req_id] = {
-            "submit_us": tl.now_us() if tl is not None else None,
-            "admit_us": None, "prefill_end_us": None,
-            "wall_prefill_end": None, "first": False, "spec_ms": 0.0,
-        }
+            "prefill_end": None, "first": False, "spec_ms": 0.0}
+        tl = get_timeline()
         if tl is not None:
             tl.instant("serve_submit", category="serve",
                        args={"req": req_id,
@@ -249,60 +252,60 @@ class InferenceServer:
         pool.scatter_pages(seq.req.req_id, scratch["k"], scratch["v"])
         return lg
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
+        """Board what the scheduler admits; returns how many.  Each
+        prefill is timed by one pair of clock reads, which the request's
+        `_req_obs` entry, the flight recorder and (through `_finish`)
+        the timeline's `decode` span all take."""
+        admitted = 0
         for seq in self.sched.admit(self.step_no, self._can_admit):
+            admitted += 1
             rid = seq.req.req_id
             obs = self._req_obs.get(rid)
             tl = get_timeline()
             t_submit = self._submit_wall.get(rid)
+            t_start = time.perf_counter()
+            queue_wait = t_start - t_submit if t_submit is not None else 0.0
             if t_submit is not None and _met.enabled():
-                _met.serve_queue_delay.observe(
-                    time.perf_counter() - t_submit)
-            if tl is not None and obs is not None \
-                    and obs["submit_us"] is not None:
+                _met.serve_queue_delay.observe(queue_wait)
+            if tl is not None and t_submit is not None:
                 # queue_wait ends exactly where prefill starts: the
-                # stamp captured right after this complete() call is the
-                # prefill span's start, so the request's three spans
-                # abut and their durations sum to its e2e latency.
+                # request's three spans abut and their durations sum to
+                # its e2e latency.
                 tl.complete("queue_wait", category="serve",
-                            start_us=obs["submit_us"],
+                            start_us=tl.now_us(t_submit),
                             args={"req": rid}, tid=f"req/{rid}")
-            t_prefill_us = tl.now_us() if tl is not None else None
-            wall_prefill = time.perf_counter()
             budget = self._budget_tokens(seq.req)
-            pids = self.pool.alloc(rid, budget)
-            lg = self._prefill_into(self.pool, self.params, self.cfg,
-                                    seq, len(pids))
-            if self.dpool is not None:
-                dpids = self.dpool.alloc(rid, budget)
-                self._prefill_into(self.dpool, self.draft_params,
-                                   self.draft_cfg, seq, len(dpids))
             T0 = int(seq.req.prompt.size)
+            with span("prefill", "serve",
+                      {"req": rid, "prompt_tokens": T0, "row": seq.row,
+                       "pages": self.pool.pages_needed(budget),
+                       "queue_wait_us": round(queue_wait * 1e6, 1)},
+                      tid=f"req/{rid}"):
+                pids = self.pool.alloc(rid, budget)
+                lg = self._prefill_into(self.pool, self.params, self.cfg,
+                                        seq, len(pids))
+                if self.dpool is not None:
+                    dpids = self.dpool.alloc(rid, budget)
+                    self._prefill_into(self.dpool, self.draft_params,
+                                       self.draft_cfg, seq, len(dpids))
+                first_logits = np.asarray(lg)[0]   # waits for the prefill
+            t_end = time.perf_counter()
             if obs is not None:
-                obs["admit_us"] = t_prefill_us
-            if tl is not None and t_prefill_us is not None:
-                tl.complete("prefill", category="serve",
-                            start_us=t_prefill_us,
-                            args={"req": rid, "prompt_tokens": T0,
-                                  "row": seq.row},
-                            tid=f"req/{rid}")
-            if obs is not None:
-                obs["prefill_end_us"] = (tl.now_us()
-                                         if tl is not None else None)
-                obs["wall_prefill_end"] = time.perf_counter()
+                obs["prefill_end"] = t_end
             if self.flightrec is not None:
-                dur_us = (time.perf_counter() - wall_prefill) * 1e6
-                end = self.flightrec.now_us()
                 self.flightrec.record(
                     "span", {"name": "prefill", "req": rid,
                              "prompt_tokens": T0, "row": seq.row},
-                    step=self.step_no, ts_us=end - dur_us,
-                    dur_us=dur_us)
+                    step=self.step_no,
+                    ts_us=self.flightrec.now_us(t_start),
+                    dur_us=(t_end - t_start) * 1e6)
             seq.pos = T0
             self.row_pos[seq.row] = T0
-            self.last_logits[seq.row] = np.asarray(lg)[0]
+            self.last_logits[seq.row] = first_logits
             self.row_seq[seq.row] = rid
             self._dirty_rows[seq.row] = rid
+        return admitted
 
     def _first_token(self, seq: ActiveSeq) -> None:
         """Called once per request, right after its first token is
@@ -334,18 +337,19 @@ class InferenceServer:
         self.row_seq[seq.row] = None
         self.row_pos[seq.row] = 0
         self._dirty_rows.pop(seq.row, None)
+        now = time.perf_counter()
         t0 = self._submit_wall.pop(rid, None)
         if t0 is not None:
-            self.request_latencies_ms.append(
-                (time.perf_counter() - t0) * 1e3)
+            self.request_latencies_ms.append((now - t0) * 1e3)
             if _met.enabled():
-                _met.serve_e2e_latency.observe(time.perf_counter() - t0)
+                _met.serve_e2e_latency.observe(now - t0)
         obs = self._req_obs.pop(rid, None)
+        t_decode = obs["prefill_end"] if obs is not None else None
         tl = get_timeline()
         if tl is not None:
-            if obs is not None and obs["prefill_end_us"] is not None:
+            if t_decode is not None:
                 tl.complete("decode", category="serve",
-                            start_us=obs["prefill_end_us"],
+                            start_us=tl.now_us(t_decode),
                             args={"req": rid,
                                   "tokens": len(seq.generated),
                                   "spec_ms": round(obs["spec_ms"], 3)},
@@ -354,15 +358,13 @@ class InferenceServer:
                        args={"req": rid,
                              "tokens": len(seq.generated)},
                        tid=f"req/{rid}")
-        if self.flightrec is not None and obs is not None \
-                and obs["wall_prefill_end"] is not None:
-            dur_us = (time.perf_counter()
-                      - obs["wall_prefill_end"]) * 1e6
+        if self.flightrec is not None and t_decode is not None:
             self.flightrec.record(
                 "span", {"name": "decode", "req": rid,
                          "tokens": len(seq.generated)},
                 step=self.step_no,
-                ts_us=self.flightrec.now_us() - dur_us, dur_us=dur_us)
+                ts_us=self.flightrec.now_us(t_decode),
+                dur_us=(now - t_decode) * 1e6)
 
     def _refresh_views(self) -> None:
         """Bring the pooled decode view up to date: a full gather the
@@ -406,31 +408,43 @@ class InferenceServer:
             raise
 
     def _step_impl(self) -> List[ActiveSeq]:
+        with span("step", "serve",
+                  {"step": self.step_no,
+                   "queued": self.sched.queue_depth(),
+                   "active": len(self.sched.active)}):
+            finished = self._step_phases()
+        self.step_no += 1
+        return finished
+
+    def _step_phases(self) -> List[ActiveSeq]:
         t0 = time.perf_counter()
-        self._admit()
+        with span("admit", "serve"):
+            admitted = self._admit()
         finished: List[ActiveSeq] = []
         feed = np.zeros(self.max_batch, np.int64)
-        for row in sorted(self.sched.active):
-            seq = self.sched.active[row]
-            if not seq.done:
-                tok = int(np.argmax(self.last_logits[row]))
-                seq.generated.append(tok)
-                self.tokens_out += 1
-                feed[row] = tok
-                if len(seq.generated) == 1:
-                    self._first_token(seq)
-            if seq.done:
-                finished.append(seq)
-                self._finish(seq)
+        with span("sample", "serve"):
+            for row in sorted(self.sched.active):
+                seq = self.sched.active[row]
+                if not seq.done:
+                    tok = int(np.argmax(self.last_logits[row]))
+                    seq.generated.append(tok)
+                    self.tokens_out += 1
+                    feed[row] = tok
+                    if len(seq.generated) == 1:
+                        self._first_token(seq)
+                if seq.done:
+                    finished.append(seq)
+                    self._finish(seq)
         rows = sorted(self.sched.active)
         decided = 0
         if rows:
-            self._refresh_views()
             spec = (self.draft_params is not None
                     and (self.force_spec or self.slo.update(self.step_no)))
             if spec:
                 t_spec = time.perf_counter()
-                decided = self._spec_round(rows, feed)
+                with span("launch", "serve"):
+                    self._refresh_views()
+                    decided = self._spec_round(rows, feed)
                 spec_ms = (time.perf_counter() - t_spec) * 1e3
                 for r in rows:
                     sid = self.row_seq[r]
@@ -443,32 +457,36 @@ class InferenceServer:
                 self._plain_step(rows, feed)
             self.device_steps += 1
             self.occupancy_sum += len(rows) / self.max_batch
-            dt_ms = (time.perf_counter() - t0) * 1e3
-            per_tok = dt_ms / (1 + decided)
-            self.token_latencies_ms.append(per_tok)
-            self.slo.record(per_tok)
-            if _met.enabled():
-                _met.serve_intertoken.observe(per_tok / 1e3)
-        self._update_gauges()
-        if self.flightrec is not None:
-            self.flightrec.record(
-                "step", {"rows": len(rows), "decided": 1 + decided,
-                         "finished": len(finished)}, step=self.step_no)
-        self.step_no += 1
+        counts = {"rows": len(rows), "admitted": admitted,
+                  "finished": len(finished), "decided": 1 + decided}
+        with span("observe", "serve", {"step": self.step_no, **counts}):
+            if rows:
+                dt_ms = (time.perf_counter() - t0) * 1e3
+                per_tok = dt_ms / (1 + decided)
+                self.token_latencies_ms.append(per_tok)
+                self.slo.record(per_tok)
+                if _met.enabled():
+                    _met.serve_intertoken.observe(per_tok / 1e3)
+            self._update_gauges()
+            if self.flightrec is not None:
+                self.flightrec.record("step", counts, step=self.step_no)
         return finished
 
     def _plain_step(self, rows: Sequence[int], feed: np.ndarray) -> None:
-        base = self.row_pos.copy()
-        cache = {"k": self.view_k, "v": self.view_v,
-                 "pos": jnp.asarray(base, jnp.int32)}
-        lg, cache = _spec_step_fn(self.cfg)(
-            self.params, cache, jnp.asarray(feed, jnp.int32))
-        self.view_k, self.view_v = cache["k"], cache["v"]
-        sids = [self.row_seq[r] for r in rows]
-        slots = [int(base[r]) % self.view_tokens for r in rows]
-        self.pool.scatter_slots(self.view_k, self.view_v, sids, rows,
-                                slots)
-        self.last_logits = np.array(lg)    # copy: row writes on admit
+        with span("launch", "serve"):      # dispatches only, no wait
+            self._refresh_views()
+            base = self.row_pos.copy()
+            cache = {"k": self.view_k, "v": self.view_v,
+                     "pos": jnp.asarray(base, jnp.int32)}
+            lg, cache = _spec_step_fn(self.cfg)(
+                self.params, cache, jnp.asarray(feed, jnp.int32))
+            self.view_k, self.view_v = cache["k"], cache["v"]
+            sids = [self.row_seq[r] for r in rows]
+            slots = [int(base[r]) % self.view_tokens for r in rows]
+            self.pool.scatter_slots(self.view_k, self.view_v, sids, rows,
+                                    slots)
+        with span("fetch", "serve"):       # the step's one sync
+            self.last_logits = np.array(lg)    # copy: row writes on admit
         for r in rows:
             self.row_pos[r] += 1
             self.sched.active[r].pos = int(self.row_pos[r])

@@ -19,6 +19,10 @@ the *host-side control plane* — eager collective dispatch, compile hits, step
 cycles, elastic events — in the same Chrome ``chrome://tracing`` JSON format
 the reference emits, so the two traces can be viewed with the same tooling.
 
+`span(...)` at the bottom is the one place the program writes a host
+span onto the profiler's own clock (and, with a timeline active, into
+this file as well): docs/TIMELINE.md.
+
 The writer mirrors the reference design: events are appended to an in-memory
 queue by the hot path (no IO), and a dedicated writer thread drains it to
 disk (`TimelineWriter` with its short-circuit buffer, timeline.cc).
@@ -178,10 +182,12 @@ class Timeline:
     def _now_us(self) -> float:
         return (time.perf_counter() - self._t0) * 1e6
 
-    def now_us(self) -> float:
+    def now_us(self, at: Optional[float] = None) -> float:
         """Timeline-clock timestamp, for `complete()` callers bracketing
-        their own spans (e.g. the per-step span in data_parallel)."""
-        return self._now_us()
+        their own spans: now, or of the `time.perf_counter()` reading
+        `at` (the clock is that one with an origin, so a caller that
+        already holds a reading hands it over and reads no second one)."""
+        return self._now_us() if at is None else (at - self._t0) * 1e6
 
     @property
     def current_cycle(self) -> int:
@@ -304,6 +310,55 @@ def stop_timeline() -> None:
     if _timeline is not None:
         _timeline.close()
         _timeline = None
+
+
+_TraceAnnotation = None
+
+
+class span:
+    """One host span of the program, `with span(name, category, args)`.
+
+    It is always a `jax.profiler.TraceAnnotation` named
+    ``hvd.<category>.<name>`` carrying `args`: whenever a profiler
+    session is open (`utils/profiler.start_device_trace`, a benchmark's
+    traced run, an operator's own `jax.profiler.trace`) the span lies in
+    the same file and on the same clock as the device's operations, so
+    an idle gap of the device can be put down to it with no merge step.
+    With a `Timeline` active it is also that timeline's `complete(...)`
+    event (same name, category, args, `tid`), written on leaving.  With
+    neither on it reads no clock; the annotation costs under a
+    microsecond.  An annotation takes its arguments when it opens, so
+    `args` holds what is known then.  Its parent is the enclosing span
+    on the same thread.
+    """
+
+    __slots__ = ("_name", "_category", "_args", "_tid", "_ann", "_tl",
+                 "_start_us")
+
+    def __init__(self, name: str, category: str,
+                 args: Optional[dict] = None, tid: Optional[str] = None):
+        self._name, self._category = name, category
+        self._args, self._tid = args, tid
+
+    def __enter__(self) -> "span":
+        global _TraceAnnotation
+        if _TraceAnnotation is None:        # jax is imported lazily here
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        self._ann = _TraceAnnotation(
+            f"hvd.{self._category}.{self._name}", **(self._args or {}))
+        self._ann.__enter__()
+        self._tl = _timeline
+        if self._tl is not None:
+            self._start_us = self._tl.now_us()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+        # Written only to the timeline that saw the span open: one
+        # started meanwhile has no start stamp on its clock.
+        if self._tl is not None and self._tl is _timeline:
+            self._tl.complete(self._name, self._category, self._start_us,
+                              args=self._args, tid=self._tid)
 
 
 # Close the trace (emitting the closing bracket / draining the native

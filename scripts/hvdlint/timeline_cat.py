@@ -5,8 +5,9 @@ call sites in `horovod_tpu/`) must appear in the instant-catalog table
 of docs/TIMELINE.md — the table the fleet tracer's docs/TRACE.md span
 schema is defined against — and every documented name must still be
 emitted somewhere.  The same contract holds for COMPLETE spans
-(`Timeline.complete(...)`, e.g. the serve lifecycle spans
-`queue_wait`/`prefill`/`decode`) against the span-catalog table.
+(`Timeline.complete(...)` and `timeline.span(...)` call sites, e.g. the
+serve lifecycle spans `queue_wait`/`prefill`/`decode`) against the
+span-catalog table.
 Drift in either direction is a finding.
 
 Name matching: a literal call site (`tl.instant("PROFILER_TRACE_START"`,
@@ -29,9 +30,11 @@ from .core import Analyzer, Finding, Project
 _CALL_RE = re.compile(
     r"""\.instant\(\s*(f?)["']([A-Za-z0-9_{}\[\].]+)["']""")
 
-#: Same, for complete-span call sites (`tl.complete("queue_wait", ...)`).
+#: Same, for complete-span call sites: `tl.complete("queue_wait", ...)`
+#: and the span primitive, `span("prefill", "serve", ...)`, which writes
+#: the same event on leaving (`utils/timeline.span`).
 _SPAN_CALL_RE = re.compile(
-    r"""\.complete\(\s*(f?)["']([A-Za-z0-9_{}\[\].]+)["']""")
+    r"""(?:\.complete|\bspan)\(\s*(f?)["']([A-Za-z0-9_{}\[\].]+)["']""")
 
 #: Instant passed as a module-level constant: `tl.instant(TRACE_MARKER`.
 _CONST_CALL_RE = re.compile(r"\.instant\(\s*([A-Z][A-Z0-9_]*)\s*[,)]")

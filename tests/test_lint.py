@@ -888,7 +888,8 @@ TRACE_INSTANT_ROWS = ("CYCLE_n", "guard_bucket_k", "wire_bucket_k",
                       "fused_bucket_k", "PROFILER_TRACE_START",
                       "serve_submit", "serve_first_token", "serve_evict",
                       "slo_toggle")
-SERVE_SPAN_ROWS = ("step", "queue_wait", "prefill", "decode")
+SERVE_SPAN_ROWS = ("step", "queue_wait", "prefill", "decode",
+                   "admit", "sample", "launch", "fetch", "observe")
 
 
 def _timeline_doc(rows, span_rows=None):
@@ -983,6 +984,35 @@ def test_timeline_catalog_span_drift_both_directions(tmp_path):
     ]
     assert any("mystery_span" in f.message for f in findings)
     assert any("ghost_span" in f.message for f in findings)
+
+
+def test_timeline_catalog_sees_span_primitive_call_sites(tmp_path):
+    """`span("name", "category")` (utils/timeline.span) writes the same
+    complete event as `tl.complete("name", ...)`: its call sites are
+    linted against the span catalog in both directions too."""
+    proj = make_project(tmp_path, {
+        "horovod_tpu/a.py": '''\
+            from .utils.timeline import span
+            from .utils import timeline as _tl
+
+            def f(tl, t0):
+                with span("fetch", "serve"):
+                    pass
+                with _tl.span("mystery_phase", "serve", {"n": 1}):
+                    pass
+                tl.instant("evt", category="event")
+                wingspan("not_a_span")
+            ''',
+        "docs/TIMELINE.md": _timeline_doc(
+            ("evt",), span_rows=("fetch", "ghost_span")),
+    })
+    findings = TimelineCatalog().run(proj)
+    assert sorted((f.rule, f.path) for f in findings) == [
+        ("stale-doc-entry", "docs/TIMELINE.md"),
+        ("undocumented-span", "horovod_tpu/a.py"),
+    ]
+    assert any("mystery_phase" in f.message for f in findings)
+    assert not any("not_a_span" in f.message for f in findings)
 
 
 def test_timeline_catalog_spans_need_section_only_when_emitted(tmp_path):

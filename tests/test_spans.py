@@ -1,0 +1,294 @@
+"""The program's spans on the profiler's clock (`utils/timeline.span`):
+the primitive alone, then a tiny server run inside a real `jax.profiler`
+session and read back with `jax.profiler.ProfileData`, the same server
+with `HOROVOD_TIMELINE` on as well, and with neither on."""
+
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import horovod_tpu as hvd
+from horovod_tpu.models import (TransformerConfig, transformer_generate,
+                                transformer_init)
+from horovod_tpu.serve import InferenceServer
+from horovod_tpu.trace import core as trace_core
+from horovod_tpu.utils import timeline as tl_mod
+from horovod_tpu.utils.timeline import span, start_timeline, stop_timeline
+
+PHASES = ("admit", "sample", "launch", "fetch", "observe")
+OUTPUTS = (2, 4, 3, 5, 2)          # tokens asked of the five requests
+# What the parent of the PR that added the spans gives on this traffic
+# (max_batch 2, fifo): the spans may move neither.
+PARENT_DEVICE_STEPS, PARENT_STEPS = 8, 9
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=4, d_head=8,
+                            d_ff=64, n_layers=2, compute_dtype=jnp.float32)
+    return cfg, transformer_init(jax.random.PRNGKey(0), cfg)
+
+
+def _serve(model, **kw):
+    """Five requests through a two-row server; -> (server, prompts by
+    request, generated tokens by request)."""
+    cfg, params = model
+    srv = InferenceServer(params, cfg, max_seq_tokens=24, max_batch=2,
+                          page_tokens=4, **kw)
+    rng = np.random.RandomState(2)
+    prompts = {}
+    for n in OUTPUTS:
+        prompt = rng.randint(0, 64, size=4)
+        prompts[srv.submit(prompt.tolist(), n)] = prompt
+    done = srv.run()
+    return srv, prompts, {s.req.req_id: list(s.generated) for s in done}
+
+
+def _profiled(tmp_path, fn):
+    """Run `fn` inside a profiler session; -> (its result, the `hvd.*`
+    events of the host plane as (name, start_ns, end_ns, stats))."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        result = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    spans = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                spans += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                           {k: v for k, v in ev.stats})
+                          for ev in line.events if ev.name.startswith("hvd.")]
+    return result, sorted(spans, key=lambda s: (s[1], -s[2]))
+
+
+@pytest.fixture(scope="module")
+def traced(model, tmp_path_factory):
+    """One server run with a profiler session and a timeline both on."""
+    tmp = tmp_path_factory.mktemp("spans")
+    tlf = str(tmp / "timeline.json")
+    start_timeline(tlf)
+    try:
+        (srv, prompts, tokens), spans = _profiled(
+            tmp / "prof", lambda: _serve(model))
+    finally:
+        stop_timeline()
+    return dict(srv=srv, prompts=prompts, tokens=tokens, spans=spans,
+                events=trace_core.load_events(tlf))
+
+
+def _inside(spans, outer, name=None):
+    return [s for s in spans if s is not outer
+            and outer[1] <= s[1] and s[2] <= outer[2]
+            and (name is None or s[0] == name)]
+
+
+def _steps(spans):
+    return [s for s in spans if s[0] == "hvd.serve.step"]
+
+
+# -- the primitive -------------------------------------------------------
+
+def test_span_off_reads_no_clock_and_passes_exceptions(monkeypatch):
+    assert tl_mod.get_timeline() is None
+
+    def no_clock():
+        raise AssertionError("a span read the clock with nothing on")
+    monkeypatch.setattr(tl_mod.time, "perf_counter", no_clock)
+    with span("step", "serve", {"step": 1}):
+        with span("admit", "serve"):
+            pass
+    with pytest.raises(KeyError):
+        with span("admit", "serve"):
+            raise KeyError("through")
+
+
+def test_span_writes_the_timeline_event(tmp_path):
+    tlf = str(tmp_path / "tl.json")
+    start_timeline(tlf)
+    try:
+        with span("outer", "unit", {"n": 3}, tid="lane/7"):
+            with span("inner", "unit"):
+                pass
+    finally:
+        stop_timeline()
+    evs = {e["name"]: e for e in trace_core.load_events(tlf)}
+    outer, inner = evs["outer"], evs["inner"]
+    assert (outer["ph"], outer["cat"], outer["tid"], outer["args"]) == \
+        ("X", "unit", "lane/7", {"n": 3})
+    assert (inner["cat"], inner["tid"]) == ("unit", "unit")
+    assert "args" not in inner
+    # the parent is the enclosing span: the child lies inside it
+    assert outer["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 0.2
+
+
+def test_span_in_a_profiler_session_carries_its_arguments(tmp_path):
+    def body():
+        with span("step", "unit", {"step": 7, "rows": 28}):
+            with span("child", "unit"):
+                pass
+    _, spans = _profiled(tmp_path, body)
+    by = {s[0]: s for s in spans}
+    assert by["hvd.unit.step"][3] == {"step": 7, "rows": 28}
+    assert by["hvd.unit.child"][3] == {}
+    assert _inside(spans, by["hvd.unit.step"]) == [by["hvd.unit.child"]]
+
+
+def test_trace_annotation_only_in_the_primitive():
+    """The program writes into the profiler's trace in one place."""
+    root = os.path.dirname(hvd.__file__)
+    users = []
+    for path in glob.glob(os.path.join(root, "**", "*.py"), recursive=True):
+        with open(path) as f:
+            if "TraceAnnotation" in f.read():
+                users.append(os.path.relpath(path, root))
+    assert users == [os.path.join("utils", "timeline.py")]
+
+
+# -- the server's step ---------------------------------------------------
+
+def test_step_and_observe_carry_the_steps_counts(traced):
+    steps = _steps(traced["spans"])
+    assert [s[3]["step"] for s in steps] == list(range(PARENT_STEPS))
+    finished = 0
+    for s in steps:
+        assert set(s[3]) == {"step", "queued", "active"}
+        obs, = _inside(traced["spans"], s, "hvd.serve.observe")
+        assert set(obs[3]) == {"step", "rows", "admitted", "finished",
+                               "decided"}
+        assert obs[3]["step"] == s[3]["step"]
+        prefills = _inside(traced["spans"], s, "hvd.serve.prefill")
+        assert obs[3]["admitted"] == len(prefills)
+        # rows on entry, plus boarded, less ended = rows decoded
+        assert obs[3]["rows"] == (s[3]["active"] + obs[3]["admitted"]
+                                  - obs[3]["finished"])
+        finished += obs[3]["finished"]
+    assert steps[0][3] == {"step": 0, "queued": len(OUTPUTS), "active": 0}
+    assert finished == len(OUTPUTS)
+
+
+def test_phases_nest_without_overlap_and_cover_the_step(traced):
+    covered = total = 0
+    for s in _steps(traced["spans"]):
+        kids = [k for k in _inside(traced["spans"], s)
+                if k[0] != "hvd.serve.prefill"]
+        names = [k[0].rsplit(".", 1)[1] for k in kids]
+        rows = _inside(traced["spans"], s, "hvd.serve.observe")[0][3]["rows"]
+        # a step that decodes has all five, in this order; one that only
+        # retires its last rows launches and fetches nothing
+        assert names == (list(PHASES) if rows
+                         else ["admit", "sample", "observe"])
+        for a, b in zip(kids, kids[1:]):
+            assert a[2] <= b[1]
+        covered += sum(k[2] - k[1] for k in kids)
+        total += s[2] - s[1]
+    assert covered >= 0.95 * total
+    # nothing of the server lies outside a step
+    in_steps = sum(len(_inside(traced["spans"], s)) + 1
+                   for s in _steps(traced["spans"]))
+    assert in_steps == len(traced["spans"])
+
+
+def test_one_prefill_span_per_admitted_request(traced):
+    prefills = [s for s in traced["spans"] if s[0] == "hvd.serve.prefill"]
+    assert sorted(p[3]["req"] for p in prefills) == sorted(traced["prompts"])
+    for p in prefills:
+        assert set(p[3]) == {"req", "prompt_tokens", "row", "pages",
+                             "queue_wait_us"}
+        assert p[3]["prompt_tokens"] == 4
+        assert p[3]["pages"] == -(-(4 + OUTPUTS[p[3]["req"]]) // 4)
+        assert p[3]["queue_wait_us"] >= 0
+        # a child of exactly one step's admit
+        assert len([a for a in traced["spans"] if a[0] == "hvd.serve.admit"
+                    and a[1] <= p[1] and p[2] <= a[2]]) == 1
+    # requests that waited for a row waited longer than the first two
+    wait = {p[3]["req"]: p[3]["queue_wait_us"] for p in prefills}
+    assert wait[4] > wait[0]
+
+
+def test_timeline_prefill_event_keeps_its_shape(traced):
+    """With HOROVOD_TIMELINE on as well, the `prefill` event is what it
+    was (name, category, lane, today's keys) with the new keys beside."""
+    prefills = [e for e in traced["events"] if e["name"] == "prefill"]
+    assert len(prefills) == len(OUTPUTS)
+    for e in prefills:
+        rid = e["args"]["req"]
+        assert (e["ph"], e["cat"], e["tid"]) == ("X", "serve", f"req/{rid}")
+        assert {"req", "prompt_tokens", "row"} <= set(e["args"])
+        assert {"pages", "queue_wait_us"} <= set(e["args"])
+    # the step's phases are there too, on the category's own lane
+    names = {e["name"] for e in traced["events"]
+             if e.get("cat") == "serve" and e.get("tid") == "serve"}
+    assert {"step", *PHASES} <= names
+    report = trace_core.analyze_serve({0: traced["events"]}, align="wall")
+    assert report["summary"]["completed"] == len(OUTPUTS)
+
+
+def test_speculative_round_is_one_launch(model, tmp_path):
+    cfg, params = model
+    (srv, _, _), spans = _profiled(
+        tmp_path, lambda: _serve(model, draft_params=params, draft_cfg=cfg,
+                                 gamma=2, force_spec=True))
+    assert srv.spec_steps > 0
+    names = {s[0] for s in spans}
+    assert "hvd.serve.launch" in names and "hvd.serve.fetch" not in names
+
+
+def test_same_tokens_and_steps_with_everything_off(model, traced):
+    """Neither a timeline nor a session: the tokens are the plain
+    reference's, the step counts the parent's; and tracing moved
+    neither."""
+    assert tl_mod.get_timeline() is None
+    cfg, params = model
+    srv, prompts, tokens = _serve(model)
+    assert (srv.device_steps, srv.step_no) == \
+        (PARENT_DEVICE_STEPS, PARENT_STEPS)
+    for rid, prompt in prompts.items():
+        want, _ = transformer_generate(params, cfg,
+                                       jnp.asarray(prompt[None]),
+                                       OUTPUTS[rid])
+        assert tokens[rid] == np.asarray(want)[0].tolist()
+    assert tokens == traced["tokens"]
+    assert (traced["srv"].device_steps, traced["srv"].step_no) == \
+        (srv.device_steps, srv.step_no)
+
+
+# -- the trainer's step --------------------------------------------------
+
+def test_data_parallel_step_is_a_span(tmp_path):
+    import optax
+
+    opt = hvd.DistributedOptimizer(optax.sgd(0.1))
+    params = {"w": jnp.zeros((4,))}
+    state = opt.init(params)
+
+    def train_step(params, state, batch):
+        grads = jax.grad(lambda p: jnp.mean((batch @ p["w"]) ** 2))(params)
+        updates, state = opt.update(grads, state, params)
+        return optax.apply_updates(params, updates), state
+
+    step = hvd.data_parallel(train_step, batch_args=(2,))
+    batch = jnp.ones((16, 4))
+    params, state = step(params, state, batch)          # compiles
+    tlf = str(tmp_path / "tl.json")
+    start_timeline(tlf, mark_cycles=True)
+    def three_steps(params=params, state=state):
+        for _ in range(3):              # the step donates what it is fed
+            params, state = step(params, state, batch)
+
+    try:
+        _, spans = _profiled(tmp_path / "prof", three_steps)
+    finally:
+        stop_timeline()
+    assert [s[0] for s in spans] == ["hvd.step.step"] * 3
+    steps = [e for e in trace_core.load_events(tlf)
+             if e["name"] == "step" and e["cat"] == "step"]
+    # written after mark_cycle: each carries the step it measured
+    assert [e["step"] for e in steps] == [1, 2, 3]
