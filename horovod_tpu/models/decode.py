@@ -33,7 +33,11 @@ Layout: cache k/v are [L, B, max_len, Hkv, Dh] in `cfg.compute_dtype`,
 fixed-shape (dynamic_update_slice into the ring; band masks over the
 full buffer), so one compiled program serves the whole generation.
 Prefill is ONE batched forward through the training attention path
-(`parallel.sequence.full_attention`), not a per-token loop.
+(`parallel.sequence.full_attention`), not a per-token loop.  The cache
+is updated IN PLACE: the layer walk carries the stacked arrays and each
+layer writes only the slots it fills (`_layer_walk`); the compiled
+entry points that take a cache donated (`_spec_step_fn` and its
+siblings, `make_decode_step`) return it in the same buffers.
 """
 
 from __future__ import annotations
@@ -113,38 +117,50 @@ def _quant_vec(x, qdt):
     return q, scale
 
 
-def _cache_write(c, val, slot):
-    """Write `val` (one position at decode, the whole prompt at
-    prefill — the slice length comes from val) into a possibly
-    quantized cache slice starting at `slot`."""
+def _cache_put(c, val, put):
+    """Apply `put(array, update)` to a plain cache array or, after
+    quantizing `val` per vector, to both leaves of a {"q", "scale"}
+    cache (payload and scale take the same indices)."""
     if isinstance(c, dict):
         q, scale = _quant_vec(val, c["q"].dtype)
-        return {"q": lax.dynamic_update_slice(c["q"], q,
-                                              (0, slot, 0, 0)),
-                "scale": lax.dynamic_update_slice(c["scale"], scale,
-                                                  (0, slot, 0))}
-    return lax.dynamic_update_slice(c, val, (0, slot, 0, 0))
+        return {"q": put(c["q"], q), "scale": put(c["scale"], scale)}
+    return put(c, val)
 
 
-def _cache_write_rows(c, val, slots):
+def _cache_write(c, i, val, slot):
+    """Write `val` [B, n, Hkv, Dh] (one position at decode, the whole
+    prompt at prefill — the slice length comes from val) into layer
+    `i` of the STACKED, possibly quantized cache `c` [L, B, S, ...],
+    starting at ring slot `slot` of every row.  Only those n slots are
+    touched: the update lands in the carried array itself."""
+    return _cache_put(c, val, lambda a, u: lax.dynamic_update_slice(
+        a, u[None], (i, 0, slot) + (0,) * (a.ndim - 3)))
+
+
+def _cache_write_rows(c, i, val, slots):
     """Per-row variant of `_cache_write`: each batch row writes its
     chunk at its OWN ring slot (`slots` [B] int32) — the vector-pos
     decode path for continuously batched serving, where admitted
     sequences sit at different depths of the same compiled step.
     Writes the same bytes `_cache_write` would per row (quantization is
     per-vector, data movement is exact), so scalar/vector parity is
-    bitwise when all rows share a position."""
-    if isinstance(c, dict):
-        q, scale = _quant_vec(val, c["q"].dtype)
-        wq = jax.vmap(
-            lambda b, v, s: lax.dynamic_update_slice(b, v, (s, 0, 0)))
-        ws = jax.vmap(
-            lambda b, v, s: lax.dynamic_update_slice(b, v, (s, 0)))
-        return {"q": wq(c["q"], q, slots),
-                "scale": ws(c["scale"], scale, slots)}
-    return jax.vmap(
-        lambda b, v, s: lax.dynamic_update_slice(b, v, (s, 0, 0)))(
-            c, val, slots)
+    bitwise when all rows share a position.
+
+    One scatter of B x n vectors at (i, row, slot + j).  Not a vmap of
+    `dynamic_update_slice` over the rows: batched over axis 1 of the
+    stacked array that makes the v5e compiler transpose the whole cache
+    batch-major at the program's entry and back at its exit."""
+    rows = jnp.arange(slots.shape[0])[:, None]
+    cols = slots[:, None] + jnp.arange(val.shape[1])[None, :]   # [B, n]
+    return _cache_put(c, val, lambda a, u: a.at[i, rows, cols].set(
+        u, indices_are_sorted=True, unique_indices=True))
+
+
+def _cache_layer(c, i):
+    """Layer `i` of a stacked cache, [B, S, ...] per leaf, for reading:
+    a slice the consumer takes straight out of the carried array."""
+    return jax.tree_util.tree_map(
+        lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), c)
 
 
 def _rope_rows(x, positions, theta: float):
@@ -163,14 +179,6 @@ def _rope_rows(x, positions, theta: float):
     return out.reshape(x.shape)
 
 
-def _tree_idx(t, i):
-    return jax.tree_util.tree_map(lambda a: a[i], t)
-
-
-def _tree_set(t, i, v):
-    return jax.tree_util.tree_map(lambda a, b: a.at[i].set(b), t, v)
-
-
 def _slot_positions(pos, S):
     """Absolute position held by each ring slot after the write at
     `pos`: slot j holds pos - ((pos - j) mod S); negative = never
@@ -179,28 +187,33 @@ def _slot_positions(pos, S):
     return pos - ((pos - j) % S)
 
 
-def _decode_layer(lp, ck, cv, x, pos, cfg: TransformerConfig,
+def _decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
                   tp_axis=None):
-    """One layer's attention for a CHUNK of c new token positions
+    """Layer `i`'s attention for a CHUNK of c new token positions
     (c == 1 is the plain decode step; c > 1 serves `transformer_extend`
     and the speculative verify pass).
 
-    x [B, c, D]; ck/cv [B, S, Hkv, Dh] (this layer's ring slices —
-    LOCAL head counts under tensor parallelism; head dims are derived
-    from the weights, not cfg, so tp shards just work).  Returns
-    (x, ck, cv) with slots `pos % S .. (pos+c-1) % S` overwritten.
-    Chunks with c > 1 must not wrap the ring (the c == 1 step may).
+    x [B, c, D]; ck/cv [L, B, S, Hkv, Dh], the WHOLE stacked cache
+    (LOCAL head counts under tensor parallelism; head dims are derived
+    from the weights, not cfg, so tp shards just work); `i` the layer's
+    index into it, a traced scalar under the scan or a Python int.
+    Returns (x, ck, cv): the same stacked arrays with only slots
+    `pos % S .. (pos+c-1) % S` of layer `i` overwritten — B x c vectors
+    written in place, nothing else of the cache moved — and attention
+    reads layer `i` out of them.  Chunks with c > 1 must not wrap the
+    ring (the c == 1 step may).
 
     `pos` may be a SCALAR (all rows at the same depth — the classic
     batch path) or a [B] VECTOR (each row at its own depth — the
     continuous-batching serving path): rope angles, ring slots, and the
     causal mask are then computed per row.  With equal entries the
     vector path is bitwise-identical to the scalar path (same
-    elementwise ops, broadcast vs materialized operands).
+    elementwise ops, broadcast vs materialized operands;
+    tests/test_decode.py::test_layer_walk_in_place).
     """
     dt = cfg.compute_dtype
     _shape_src = ck["q"] if isinstance(ck, dict) else ck
-    B, S = _shape_src.shape[0], _shape_src.shape[1]
+    B, S = _shape_src.shape[1], _shape_src.shape[2]
     Dh = cfg.d_head
     c = x.shape[1]
 
@@ -216,15 +229,15 @@ def _decode_layer(lp, ck, cv, x, pos, cfg: TransformerConfig,
         positions = pos[:, None] + jnp.arange(c)[None, :]   # [B, c]
         q = _rope_rows(q, positions, cfg.rope_theta).astype(dt)
         k = _rope_rows(k, positions, cfg.rope_theta).astype(dt)
-        ck = _cache_write_rows(ck, k, pos % S)
-        cv = _cache_write_rows(cv, v, pos % S)
+        ck = _cache_write_rows(ck, i, k, pos % S)
+        cv = _cache_write_rows(cv, i, v, pos % S)
     else:
         positions = pos + jnp.arange(c)                # [c]
         q = _rope(q, positions, cfg.rope_theta).astype(dt)
         k = _rope(k, positions, cfg.rope_theta).astype(dt)
         slot = pos % S
-        ck = _cache_write(ck, k, slot)
-        cv = _cache_write(cv, v, slot)
+        ck = _cache_write(ck, i, k, slot)
+        cv = _cache_write(cv, i, v, slot)
 
     # Grouped attention against the ring: q [B,c,Hkv,g,Dh] x
     # cache [B,S,Hkv,Dh] — the repeated kv heads never materialize.
@@ -233,14 +246,15 @@ def _decode_layer(lp, ck, cv, x, pos, cfg: TransformerConfig,
     # [..,S]-shaped scores/probs instead of a Dh-times-larger
     # dequantized cache copy.
     qg = q.reshape(B, c, Hkv, g, Dh)
-    if isinstance(ck, dict):
+    lk, lv = _cache_layer(ck, i), _cache_layer(cv, i)
+    if isinstance(lk, dict):
         s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
-                       ck["q"].astype(jnp.float32))
-        s = s * ck["scale"].transpose(0, 2, 1)[:, :, None, None, :]
+                       lk["q"].astype(jnp.float32))
+        s = s * lk["scale"].transpose(0, 2, 1)[:, :, None, None, :]
         s = s / (Dh ** 0.5)
     else:
         s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
-                       ck.astype(jnp.float32)) / (Dh ** 0.5)
+                       lk.astype(jnp.float32)) / (Dh ** 0.5)
     # Per-query causal mask over reconstructed absolute positions:
     # query i (absolute pos+i) sees slots holding abs <= pos+i.  The
     # chunk's own keys were just written, so intra-chunk causality
@@ -266,13 +280,13 @@ def _decode_layer(lp, ck, cv, x, pos, cfg: TransformerConfig,
                              < cfg.attn_window)
         s = jnp.where(valid[None, None, None, :, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    if isinstance(cv, dict):
-        pv = p * cv["scale"].transpose(0, 2, 1)[:, :, None, None, :]
+    if isinstance(lv, dict):
+        pv = p * lv["scale"].transpose(0, 2, 1)[:, :, None, None, :]
         o = jnp.einsum("bhgqk,bkhd->bqhgd", pv,
-                       cv["q"].astype(jnp.float32))
+                       lv["q"].astype(jnp.float32))
     else:
         o = jnp.einsum("bhgqk,bkhd->bqhgd", p,
-                       cv.astype(jnp.float32))
+                       lv.astype(jnp.float32))
     o = o.reshape(B, c, Hq, Dh).astype(dt)
     out = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(dt))
     if tp_axis is not None:
@@ -302,32 +316,36 @@ def _moe_tokens(mp, scale, x, cfg: TransformerConfig):
 
 
 def _layer_walk(params, ck, cv, x, attn_fn, cfg, tp_axis=None):
-    """Layer walk shared by decode and prefill: homogeneous dense
-    configs scan over the stacked params; mixed dense/MoE configs take
-    the unrolled walk.  attn_fn(lp, ck_i, cv_i, x) -> (x, ck_i, cv_i)
-    supplies the step- or prompt-shaped attention."""
+    """Layer walk shared by decode, chunked extend and prefill, ONE
+    contract for every caller: `ck`/`cv` are the whole stacked cache
+    (arrays, or the {"q", "scale"} dicts of the quantized layouts) and
+    are UPDATED IN PLACE — attn_fn(lp, ck, cv, i, x) -> (x, ck, cv)
+    writes only the slots layer `i` fills and reads layer `i` out of
+    the same arrays (`_decode_layer`, `_prefill_layer`).
+
+    Homogeneous dense configs scan over (layer index, stacked params)
+    with the cache in the scan's CARRY, never among its xs / ys: a
+    cache scanned as xs -> ys is cut out and written back a whole
+    layer at a time, which was two thirds of a served decode step
+    (PERF.md, PR 27).  Mixed dense/MoE configs walk the layers
+    unrolled, with static indices and the same contract.  A caller
+    that donates the cache to its jit (the server's programs do) gets
+    the result in the argument's own buffer."""
     if not cfg.moe_every:
-        def layer_step(x, inputs):
-            lp, cki, cvi = inputs
-            x, cki, cvi = attn_fn(lp, cki, cvi, x)
-            x = _mlp_block(lp, x, cfg, tp_axis)
-            return x, (cki, cvi)
+        def layer_step(carry, inputs):
+            x, ck, cv = carry
+            i, lp = inputs
+            x, ck, cv = attn_fn(lp, ck, cv, i, x)
+            return (_mlp_block(lp, x, cfg, tp_axis), ck, cv), None
 
-        x, (ck, cv) = lax.scan(layer_step, x, (params["blocks"], ck, cv))
+        (x, ck, cv), _ = lax.scan(
+            layer_step, (x, ck, cv),
+            (jnp.arange(cfg.n_layers), params["blocks"]))
         return x, ck, cv
-    return _mixed_layer_walk(params, ck, cv, x, attn_fn, cfg, tp_axis)
-
-
-def _mixed_layer_walk(params, ck, cv, x, attn_fn, cfg, tp_axis=None):
-    """Unrolled dense/MoE layer walk shared by decode and prefill
-    (mirrors transformer_ref_apply): attn_fn(lp, ck_i, cv_i, x) ->
-    (x, ck_i, cv_i) supplies the step- or prompt-shaped attention."""
     moe_idx = 0
     for i in range(cfg.n_layers):
         lp = jax.tree_util.tree_map(lambda p: p[i], params["blocks"])
-        x, cki, cvi = attn_fn(lp, _tree_idx(ck, i), _tree_idx(cv, i), x)
-        ck = _tree_set(ck, i, cki)
-        cv = _tree_set(cv, i, cvi)
+        x, ck, cv = attn_fn(lp, ck, cv, i, x)
         if _is_moe_layer(cfg, i):
             mp = jax.tree_util.tree_map(lambda p: p[moe_idx],
                                         params["moe"])
@@ -364,8 +382,8 @@ def transformer_decode_step(params: Dict, cache: Dict, tokens,
 
     x, ck, cv = _layer_walk(
         params, cache["k"], cache["v"], x,
-        lambda lp, cki, cvi, x: _decode_layer(lp, cki, cvi, x, pos,
-                                              cfg, tp_axis),
+        functools.partial(_decode_layer, pos=pos, cfg=cfg,
+                          tp_axis=tp_axis),
         cfg, tp_axis)
     x = _rmsnorm(params["final_norm"]["scale"], x)
     logits = jnp.einsum("bod,vd->bov", x.astype(dt),
@@ -419,8 +437,8 @@ def transformer_extend(params: Dict, cache: Dict, tokens,
     x = params["embed"][tokens].astype(dt)                # [B,c,D]
     x, ck, cv = _layer_walk(
         params, cache["k"], cache["v"], x,
-        lambda lp, cki, cvi, x: _decode_layer(lp, cki, cvi, x, pos,
-                                              cfg, tp_axis),
+        functools.partial(_decode_layer, pos=pos, cfg=cfg,
+                          tp_axis=tp_axis),
         cfg, tp_axis)
     x = _rmsnorm(params["final_norm"]["scale"], x)
     logits = jnp.einsum("bod,vd->bov", x.astype(dt),
@@ -659,21 +677,32 @@ def _spec_accept(d_tok: int, p, q, rng_np):
     return False, int(rng_np.choice(len(resid), p=resid))
 
 
+# The compiled step / chunk programs CONSUME their cache argument
+# (donate_argnums=(1,)): with the in-place layer walk the returned
+# cache is the argument's own buffer, so a caller holds the cache once,
+# must rebind the result and may never touch the argument again.  They
+# stay lambdas: the benchmark's trace readers find the server's
+# programs as `jit__lambda(` (PERF.md section 7 (b)).
+
+
 @functools.lru_cache(maxsize=None)
 def _spec_extend_fn(cfg: TransformerConfig):
-    return jax.jit(lambda p, c, t: transformer_extend(p, c, t, cfg))
+    return jax.jit(lambda p, c, t: transformer_extend(p, c, t, cfg),
+                   donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
 def _spec_step_fn(cfg: TransformerConfig):
-    return jax.jit(lambda p, c, t: transformer_decode_step(p, c, t, cfg))
+    return jax.jit(lambda p, c, t: transformer_decode_step(p, c, t, cfg),
+                   donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
 def _spec_draft_scan(cfg: TransformerConfig, n: int, sampled: bool):
     """One compiled program proposing n draft tokens per row: scan of
     (pick from current logits, feed, next logits).  Returns
-    (drafts [n, B] int32, qlogits [n, B, V] f32, cache)."""
+    (drafts [n, B] int32, qlogits [n, B, V] f32, cache); consumes the
+    cache argument like `_spec_step_fn`."""
 
     def run(params, cache, first_logits, keys, temp):
         def body(carry, key):
@@ -699,7 +728,35 @@ def _spec_draft_scan(cfg: TransformerConfig, n: int, sampled: bool):
         return ((drafts, qlogits, cache) if sampled
                 else (drafts, cache))
 
-    return jax.jit(run)
+    return jax.jit(run, donate_argnums=(1,))
+
+
+def _prefill_layer(lp, ck, cv, i, x, cfg: TransformerConfig,
+                   tp_axis=None):
+    """Layer `i`'s attention over a whole prompt x [B, T0, D] (the
+    training attention path), under `_layer_walk`'s contract: slots
+    0..T0-1 of layer `i` of the stacked cache are written in place and
+    nothing of the cache is read — the prompt attends to its own k/v."""
+    dt = cfg.compute_dtype
+    positions = jnp.arange(x.shape[1])
+    h = _rmsnorm(lp["ln1"]["scale"], x)
+    q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dt))
+    k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dt))
+    v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dt))
+    q = _rope(q, positions, cfg.rope_theta).astype(dt)
+    k = _rope(k, positions, cfg.rope_theta).astype(dt)
+
+    # The prompt pass itself attends at full precision; decode
+    # steps read the quantized store (documented lossy boundary).
+    ck = _cache_write(ck, i, k, 0)
+    cv = _cache_write(cv, i, v, 0)
+    o = seq_mod.full_attention(q, k, v, causal=True,
+                               window=cfg.attn_window or None)
+    out = jnp.einsum("bthk,hkd->btd", o.astype(dt),
+                     lp["wo"].astype(dt))
+    if tp_axis is not None:
+        out = lax.psum(out, tp_axis)
+    return x + out.astype(x.dtype), ck, cv
 
 
 def transformer_prefill(params: Dict, cache: Dict, prompt,
@@ -728,32 +785,11 @@ def transformer_prefill(params: Dict, cache: Dict, prompt,
             raise ValueError(
                 f"transformer_prefill requires a fresh cache "
                 f"(pos == 0), got pos = {int(cache['pos'])}")
-    window = cfg.attn_window or None
     x = params["embed"][prompt].astype(dt)                # [B,T0,D]
-    positions = jnp.arange(T0)
-
-    def attn(lp, ck, cv, x):
-        h = _rmsnorm(lp["ln1"]["scale"], x)
-        q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dt))
-        k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dt))
-        v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dt))
-        q = _rope(q, positions, cfg.rope_theta).astype(dt)
-        k = _rope(k, positions, cfg.rope_theta).astype(dt)
-
-        # The prompt pass itself attends at full precision; decode
-        # steps read the quantized store (documented lossy boundary).
-        ck = _cache_write(ck, k, 0)
-        cv = _cache_write(cv, v, 0)
-        o = seq_mod.full_attention(q, k, v, causal=True, window=window)
-        out = jnp.einsum("bthk,hkd->btd", o.astype(dt),
-                         lp["wo"].astype(dt))
-        if tp_axis is not None:
-            out = lax.psum(out, tp_axis)
-        return x + out.astype(x.dtype), ck, cv
-
     x, ck, cv = _layer_walk(
         params, cache["k"], cache["v"], x,
-        lambda lp, cki, cvi, x: attn(lp, cki, cvi, x), cfg, tp_axis)
+        functools.partial(_prefill_layer, cfg=cfg, tp_axis=tp_axis),
+        cfg, tp_axis)
     x = _rmsnorm(params["final_norm"]["scale"], x[:, -1:])
     logits = jnp.einsum("bod,vd->bov", x.astype(dt),
                         params["embed"].astype(dt),
@@ -919,7 +955,11 @@ def make_decode_step(mesh, cfg: TransformerConfig, quantize=None):
     - wo/wd are row-parallel (one psum per layer, the decode analog of
       the training block's tensor parallelism);
     - `ep` is not supported at decode (MoE weights stay replicated and
-      route with the no-capacity inference semantics).
+      route with the no-capacity inference semantics);
+    - `step`, `prefill` and `extend` CONSUME their cache argument
+      (donated: the in-place layer walk returns it in the same
+      buffers), so rebind it — `logits, sc = step(sp, sc, tokens)` —
+      and never read the argument again.
     """
     from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -961,17 +1001,20 @@ def make_decode_step(mesh, cfg: TransformerConfig, quantize=None):
     step = jax.jit(shard_map(
         lambda p, c, t: transformer_decode_step(p, c, t, cfg, tp_axis),
         mesh=mesh, in_specs=(pspecs, cache_spec, tok_spec),
-        out_specs=(logits_spec, cache_spec), check_vma=False))
+        out_specs=(logits_spec, cache_spec), check_vma=False),
+        donate_argnums=(1,))
     prefill = jax.jit(shard_map(
         lambda p, c, t: transformer_prefill(p, c, t, cfg, tp_axis),
         mesh=mesh,
         in_specs=(pspecs, cache_spec, P(dp, None)),
-        out_specs=(logits_spec, cache_spec), check_vma=False))
+        out_specs=(logits_spec, cache_spec), check_vma=False),
+        donate_argnums=(1,))
     extend = jax.jit(shard_map(
         lambda p, c, t: transformer_extend(p, c, t, cfg, tp_axis),
         mesh=mesh,
         in_specs=(pspecs, cache_spec, P(dp, None)),
-        out_specs=(P(dp, None, None), cache_spec), check_vma=False))
+        out_specs=(P(dp, None, None), cache_spec), check_vma=False),
+        donate_argnums=(1,))
 
     def shard_params(params):
         return jax.tree_util.tree_map(

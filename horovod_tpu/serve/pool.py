@@ -16,7 +16,11 @@ exact shape ``transformer_decode_step`` already takes — and
 the owning page.  Both are pure data movement (no arithmetic), which
 is why pooled decode is BITWISE-equal to contiguous-cache decode: the
 step consumes identical bytes either way
-(tests/test_serve.py::test_pooled_decode_bitwise_equal).
+(tests/test_serve.py::test_pooled_decode_bitwise_equal).  The view is
+UPDATED IN PLACE: the server's step programs take it donated and write
+only the new slots into it (models/decode.py ``_layer_walk``), so a
+server holds the pool and ONE view, and whoever passes a view to a
+step or to ``gather_rows`` rebinds the result and drops the argument.
 
 Amortization contract (see docs/SERVING.md): the view is rebuilt only
 on MEMBERSHIP change (admit/evict); steady-state steps pay one

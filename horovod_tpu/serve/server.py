@@ -21,6 +21,9 @@ Step anatomy (``step()``):
   4. decode  — one vector-pos ``transformer_decode_step`` (plain), or
                one speculative round (draft chain + chunked verify)
                when the SLO controller has flipped speculation on.
+               The programs CONSUME the view (donated, updated in
+               place): ``view_k`` / ``view_v`` are rebound to what
+               they return, and the arrays passed in are gone.
   5. scatter — copy each active row's written ring slot(s) back into
                its pages; the pool stays the source of truth.
 
@@ -76,7 +79,10 @@ from .slo import SloController
 
 @functools.lru_cache(maxsize=None)
 def _prefill_fn(cfg):
-    return jax.jit(lambda p, c, t: transformer_prefill(p, c, t, cfg))
+    # Consumes the scratch cache like the step programs consume the
+    # view (models/decode.py): `_prefill_into` makes it and rebinds it.
+    return jax.jit(lambda p, c, t: transformer_prefill(p, c, t, cfg),
+                   donate_argnums=(1,))
 
 
 def _flush_at_exit(ref: "weakref.ref") -> None:

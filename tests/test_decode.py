@@ -936,3 +936,156 @@ def test_sharded_fp8_cache_builds_and_steps():
     lg, sc = prefill(sp, sc, toks)
     lg, sc = step(sp, sc, shard_tokens(jnp.argmax(lg, axis=-1)))
     assert bool(jnp.isfinite(lg).all())
+
+
+# -- the in-place layer walk (PR 27) ---------------------------------------
+
+def _jaxpr_eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (pjit, scan, ...) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _jaxpr_eqns(sub)
+
+
+def _walk_reference(params, cache, tokens, cfg, mode):
+    """The walk as a plain per-layer loop: each layer's attention from
+    `_decode_layer` / `_prefill_layer` on THAT layer's slice of the
+    cache (a one-layer stack, index 0), the slices joined afterwards."""
+    from horovod_tpu.models import decode as D
+    from horovod_tpu.models.transformer import (
+        _is_moe_layer, _mlp_block, _rmsnorm)
+
+    tm = jax.tree_util.tree_map
+    dt = cfg.compute_dtype
+    x = params["embed"][tokens].astype(dt)
+    if tokens.ndim == 1:
+        x = x[:, None, :]
+    ks, vs, moe_idx = [], [], 0
+    for i in range(cfg.n_layers):
+        lp = tm(lambda p: p[i], params["blocks"])
+        cki = tm(lambda a: a[i:i + 1], cache["k"])
+        cvi = tm(lambda a: a[i:i + 1], cache["v"])
+        if mode == "prefill":
+            x, cki, cvi = D._prefill_layer(lp, cki, cvi, 0, x, cfg)
+        else:
+            x, cki, cvi = D._decode_layer(lp, cki, cvi, 0, x,
+                                          cache["pos"], cfg)
+        ks.append(cki)
+        vs.append(cvi)
+        if _is_moe_layer(cfg, i):
+            mp = tm(lambda p: p[moe_idx], params["moe"])
+            x = D._moe_tokens(mp, lp["ln2"]["scale"], x, cfg)
+            moe_idx += 1
+        else:
+            x = _mlp_block(lp, x, cfg, None)
+    if mode == "prefill":
+        x = x[:, -1:]
+    x = _rmsnorm(params["final_norm"]["scale"], x)
+    logits = jnp.einsum("bod,vd->bov", x.astype(dt),
+                        params["embed"].astype(dt),
+                        preferred_element_type=jnp.float32)
+    if mode != "chunk":
+        logits = logits[:, 0]
+    join = lambda *a: jnp.concatenate(a, axis=0)
+    return logits, {"k": tm(join, *ks), "v": tm(join, *vs)}
+
+
+def _leaves_equal(a, b):
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(
+            np.asarray(x.astype(jnp.float32)),
+            np.asarray(y.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("arch", ["dense", "moe"])
+@pytest.mark.parametrize("quantize", [None, "int8", "fp8_e4m3"],
+                         ids=["bf16", "int8", "fp8"])
+@pytest.mark.parametrize("mode", ["scalar", "vector", "chunk", "prefill"])
+def test_layer_walk_in_place(mode, quantize, arch):
+    """The layer walk's contract, for every caller and cache layout:
+    the cache rides in the scan's carry (never xs / ys), only the new
+    slots are written, the compiled program consumes the donated cache,
+    and the result is bitwise the per-layer reference."""
+    from horovod_tpu.models import decode as D
+    from horovod_tpu.serve.server import _prefill_fn
+
+    kw = dict(n_kv_heads=2, compute_dtype=jnp.bfloat16, n_layers=3)
+    if arch == "moe":
+        kw.update(moe_every=2, n_experts=2, n_layers=4)
+    cfg = _cfg(**kw)
+    params = transformer_init(jax.random.PRNGKey(0), cfg)
+    B, S, T0 = 2, 12, 5
+    prompt = jax.random.randint(jax.random.PRNGKey(1), (B, T0), 0, 64)
+
+    def warm(pos):
+        c = init_decode_cache(cfg, B, S, quantize=quantize)
+        _, c = transformer_prefill(params, c, prompt, cfg)
+        return {"k": c["k"], "v": c["v"],
+                "pos": jnp.asarray(pos, jnp.int32)}
+
+    if mode == "prefill":
+        fn, toks = _prefill_fn(cfg), prompt
+        make = lambda: init_decode_cache(cfg, B, S, quantize=quantize)
+    elif mode == "chunk":
+        fn = D._spec_extend_fn(cfg)
+        toks = jax.random.randint(jax.random.PRNGKey(2), (B, 3), 0, 64)
+        make = lambda: warm([T0, 3])
+    else:
+        fn = D._spec_step_fn(cfg)
+        toks = jax.random.randint(jax.random.PRNGKey(2), (B,), 0, 64)
+        make = lambda: warm(T0 if mode == "scalar" else [T0, 3])
+    n_new = toks.size // B              # slots a row fills in a layer
+
+    # -- structure: what the walk does with the cache ---------------------
+    cache = make()
+    pos0 = np.asarray(cache["pos"])
+    held = jax.tree_util.tree_leaves((cache["k"], cache["v"]))
+    leaf_shapes = {a.shape for a in held}
+    eqns = list(_jaxpr_eqns(jax.make_jaxpr(fn)(params, cache, toks).jaxpr))
+    scans = [e for e in eqns if e.primitive.name == "scan"]
+    if arch == "dense":
+        (scan,) = scans                 # the one walk over the layers
+        nc, nk = scan.params["num_consts"], scan.params["num_carry"]
+        carry = {v.aval.shape for v in scan.invars[nc:nc + nk]}
+        xs = {v.aval.shape for v in scan.invars[nc + nk:]}
+        ys = {v.aval.shape for v in scan.outvars[nk:]}
+        assert leaf_shapes <= carry
+        assert not leaf_shapes & (xs | ys)      # stacked [L, ...] both
+    else:
+        assert not scans                # static indices, same contract
+    writes = [e for e in eqns
+              if e.primitive.name in ("scatter", "dynamic_update_slice")
+              and e.invars[0].aval.shape in leaf_shapes]
+    assert len(writes) == len(held) * (1 if arch == "dense"
+                                       else cfg.n_layers)
+    for e in writes:                    # B x n_new vectors, no more
+        upd = e.invars[-1 if e.primitive.name == "scatter" else 1].aval
+        full = e.invars[0].aval.shape
+        assert upd.size == B * n_new * int(np.prod(full[3:])), (upd, full)
+
+    # -- values: bitwise the per-layer reference; the argument is consumed --
+    # Both sides are compiled to round every bf16 result (by default XLA
+    # keeps f32 between the ops it fuses, so a scan and an unrolled loop
+    # of the same layers differ in the last bf16 digit, on any backend).
+    exact = {"xla_allow_excess_precision": False}
+    ref_lg, ref_kv = jax.jit(
+        lambda p, c, t: _walk_reference(p, c, t, cfg, mode)).lower(
+            params, cache, toks).compile(compiler_options=exact)(
+                params, cache, toks)
+    run = fn.lower(params, cache, toks).compile(compiler_options=exact)
+    lg, out = run(params, cache, toks)
+    assert all(a.is_deleted() for a in held)
+    np.testing.assert_array_equal(np.asarray(lg), np.asarray(ref_lg))
+    _leaves_equal((out["k"], out["v"]), (ref_kv["k"], ref_kv["v"]))
+    np.testing.assert_array_equal(np.asarray(out["pos"]), pos0 + n_new)
+
+    if mode == "vector":
+        # `_decode_layer`'s promise: equal depths in a vector are the
+        # scalar path, bit for bit.
+        lg_s, out_s = fn(params, warm(T0), toks)    # scalar program
+        lg_v, out_v = fn(params, warm([T0] * B), toks)
+        np.testing.assert_array_equal(np.asarray(lg_s), np.asarray(lg_v))
+        _leaves_equal((out_s["k"], out_s["v"]), (out_v["k"], out_v["v"]))

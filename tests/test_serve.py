@@ -332,6 +332,38 @@ class TestInferenceServer:
                 params, cfg, jnp.asarray(prompt[None], jnp.int32), 6)
             assert by_id[rid] == np.asarray(ref)[0].tolist()
 
+    @pytest.mark.parametrize("spec", [False, True],
+                             ids=["plain", "speculative"])
+    def test_step_consumes_the_view(self, model, spec):
+        """The step's programs take the decode view donated: the arrays
+        that were the view before a step are gone after it (the server
+        holds pool + ONE view), and the server goes on stepping."""
+        cfg, params = model
+        kw = {}
+        if spec:
+            kw = dict(draft_params=transformer_init(
+                jax.random.PRNGKey(9), cfg), draft_cfg=cfg, gamma=3,
+                force_spec=True)
+        srv = InferenceServer(params, cfg, max_seq_tokens=24,
+                              max_batch=2, page_tokens=4, **kw)
+        prompts = [np.arange(4, dtype=np.int32), np.arange(3, 8,
+                                                           dtype=np.int32)]
+        rids = [srv.submit(p, 9) for p in prompts]
+        srv.step()                      # builds the view, first step
+        for _ in range(2):
+            held = [srv.view_k, srv.view_v]
+            if spec:
+                held += [srv.dview_k, srv.dview_v]
+            assert not any(a.is_deleted() for a in held)
+            srv.step()
+            assert all(a.is_deleted() for a in held)
+            assert not srv.view_k.is_deleted()
+        by_id = {s.req.req_id: s.generated for s in srv.run()}
+        for rid, p in zip(rids, prompts):
+            ref, _ = transformer_generate(
+                params, cfg, jnp.asarray(p[None], jnp.int32), 9)
+            assert by_id[rid] == np.asarray(ref)[0].tolist()
+
     def test_eos_stops_row(self, model):
         cfg, params = model
         prompt = np.arange(4, dtype=np.int32)
