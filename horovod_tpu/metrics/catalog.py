@@ -242,6 +242,11 @@ serve_pool_pages_free = _REG.gauge(
     "hvd_serve_pool_pages_free",
     "Free pages in the paged KV-cache pool (0 = admissions stall until "
     "an eviction returns pages).")
+serve_state_bytes = _REG.gauge(
+    "hvd_serve_state_bytes",
+    "Bytes the decode view holds as recurrent state: every row's state "
+    "and normaliser of a retention model, held once (0 for a model "
+    "whose cache is paged).")
 serve_p99_ms = _REG.gauge(
     "hvd_serve_p99_ms",
     "Observed p99 per-token decode latency over the SLO controller's "

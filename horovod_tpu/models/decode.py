@@ -38,6 +38,23 @@ is updated IN PLACE: the layer walk carries the stacked arrays and each
 layer writes only the slots it fills (`_layer_walk`); the compiled
 entry points that take a cache donated (`_spec_step_fn` and its
 siblings, `make_decode_step`) return it in the same buffers.
+
+Power retention (`cfg.attn_kind == "retention"`): key j weighs
+`exp(sum_{l=j+1..t} gamma_l) * (q_t . k_j / sqrt(d)) ** 2` for query t,
+divided by the sum of the weights plus `RETENTION_EPS`; no softmax.  With
+`phi(u)` the symmetric square of u (`_phi`), `phi(q) . phi(k)` is that
+weight before decay, so the layer is a recurrence over a FIXED state a
+row: per kv head `S = e^gamma S + phi(k) v^T` [D, d_head] and
+`z = e^gamma z + phi(k)` [D], read out as `phi(q)^T S / (phi(q)^T z +
+eps)`.  The cache is then {"s": [L, B, Hkv, D, Dh], "z": [L, B, Hkv, D],
+"pos"} whatever the context length (`init_decode_cache`); a decode step
+reads and writes all of it (`_retention_decode_layer`) and prefill is a
+scan over chunks of `RETENTION_CHUNK` tokens, quadratic inside a chunk,
+the state carried across (`_retention_prefill_layer`).  Both ride
+`_layer_walk` under its one contract, the state where keys and values
+ride for a softmax layer.  What needs snapshots or shards of a state
+(`transformer_extend`, speculation, beam search, `make_decode_step`) and
+the quantized layouts refuse the kind by name.
 """
 
 from __future__ import annotations
@@ -90,6 +107,14 @@ def init_decode_cache(cfg: TransformerConfig, batch: int,
     if quantize not in (None, "int8", "fp8_e4m3"):
         raise ValueError(f"quantize must be None, 'int8', or "
                          f"'fp8_e4m3', got {quantize!r}")
+    if cfg.attn_kind == "retention":
+        # A fixed state a row: `max_len` sizes nothing.
+        _refuse_retention(cfg, "quantize", quantize is not None)
+        shape = (cfg.n_layers, batch, cfg.kv_heads,
+                 retention_features(cfg.d_head))
+        return {"s": jnp.zeros(shape + (cfg.d_head,), cfg.state_dtype),
+                "z": jnp.zeros(shape, jnp.float32),
+                "pos": jnp.zeros((), jnp.int32)}
     shape = (cfg.n_layers, batch, max_len, cfg.kv_heads, cfg.d_head)
     if quantize is not None:
         qdt = jnp.int8 if quantize == "int8" else jnp.float8_e4m3fn
@@ -295,6 +320,241 @@ def _decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
     return x, ck, cv
 
 
+# -- power retention ----------------------------------------------------------
+
+RETENTION_EPS = 1e-6
+# One chunk length for every prompt: on the v5e a 4096-token prefill took
+# 0.455 to 0.512 s over chunks of 128 to 1024 (PERF.md, PR 28), so it is
+# no option.  The power is 2 by construction: `_phi` is the symmetric
+# square and nothing else.
+RETENTION_CHUNK = 256
+
+
+def retention_features(d_head: int) -> int:
+    """Numbers `_phi` holds a vector of `d_head` in: d_head / 2 + 1
+    wrapped diagonals of d_head, 8320 for 128 (the symmetric square has
+    8256 distinct entries; the middle diagonal holds its 64 twice)."""
+    return (d_head // 2 + 1) * d_head
+
+
+def cache_leaves(cfg: TransformerConfig) -> Tuple[str, str]:
+    """The cache's two stacked leaves by name: keys and values, or a
+    retention model's states and their normalisers."""
+    return ("s", "z") if cfg.attn_kind == "retention" else ("k", "v")
+
+
+def _refuse_retention(cfg: TransformerConfig, what: str,
+                      asked: bool = True) -> None:
+    if asked and cfg.attn_kind == "retention":
+        raise InvalidRequestError(
+            f"{what} is not supported for attn_kind='retention': the "
+            "cache is one state a row, with no slots to quantize, shard, "
+            "snapshot or roll back")
+
+
+@functools.lru_cache(maxsize=None)
+def _phi_tables(d: int):
+    """(scale [d/2+1, d], pick [d, (d/2+1) d]) of `_phi`'s layout: the
+    weight of each entry, and the 0/1 matrix whose column (o, i) picks
+    u_{(i+o) mod d}."""
+    n = d // 2 + 1
+    scale = np.full((n, d), np.sqrt(2.0 / d), np.float32)
+    scale[0] = scale[-1] = np.sqrt(1.0 / d)
+    pick = np.zeros((d, n * d), np.float32)
+    o, i = np.divmod(np.arange(n * d), d)
+    pick[(i + o) % d, np.arange(n * d)] = 1.0
+    return scale, pick
+
+
+def _phi(u):
+    """Symmetric square of u [..., d] as [..., (d/2+1) * d] float32, so
+    that `_phi(q) . _phi(k) == (q . k) ** 2 / d`.
+
+    Entry (o, i) is `c_o u_i u_{(i+o) mod d} / sqrt(d)` for o = 0..d/2:
+    wrapped diagonal o holds the pairs at distance o and at distance
+    d - o.  c is 1 on the main diagonal (the squares), sqrt 2 for
+    0 < o < d/2 (each pair once), and 1 for o = d/2, whose d entries are
+    d/2 pairs held twice.  The same inner products as the d(d+1)/2
+    upper-triangle entries, in rows of d lanes with no gather."""
+    u = u.astype(jnp.float32)
+    d = u.shape[-1]
+    rolled = jnp.stack([jnp.roll(u, -o, axis=-1)
+                        for o in range(d // 2 + 1)], axis=-2)
+    out = rolled * u[..., None, :] * _phi_tables(d)[0]
+    return out.reshape(u.shape[:-1] + (retention_features(d),))
+
+
+def _retention_qkv(lp, x, positions, cfg: TransformerConfig):
+    """What both retention paths start from: q [B, T, H, Dh] and
+    k [B, T, Hkv, Dh] normed per head (learned scale) and rotated,
+    returned in float32; v in the compute dtype; gamma [B, T, Hkv] = log
+    sigmoid of the decay gate, float32, <= 0.  What becomes of q and k
+    is the caller's: the decode step keeps them float32 through products
+    at "highest", the prefill's matrix products take them in the compute
+    dtype as its other products do.  `positions` [T] or, for rows at
+    their own depths, [B, T]."""
+    dt = cfg.compute_dtype
+    h = _rmsnorm(lp["ln1"]["scale"], x)
+    q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dt))
+    k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dt))
+    v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dt))
+    q = _rmsnorm(lp["q_norm"]["scale"], q.astype(jnp.float32))
+    k = _rmsnorm(lp["k_norm"]["scale"], k.astype(jnp.float32))
+    rope = _rope_rows if positions.ndim == 2 else _rope
+    q = rope(q, positions, cfg.rope_theta)
+    k = rope(k, positions, cfg.rope_theta)
+    gate = jnp.einsum("btd,dh->bth", h, lp["w_decay"].astype(dt),
+                      preferred_element_type=jnp.float32)
+    gamma = jax.nn.log_sigmoid(gate + lp["b_decay"].astype(jnp.float32))
+    return q, k, v, gamma
+
+
+def _retention_out(lp, x, y, cfg: TransformerConfig, tp_axis=None):
+    """Read-out y [B, T, H, Dh] through wo, onto the residual."""
+    dt = cfg.compute_dtype
+    out = jnp.einsum("bthk,hkd->btd", y.astype(dt), lp["wo"].astype(dt))
+    if tp_axis is not None:
+        out = lax.psum(out, tp_axis)
+    return x + out.astype(x.dtype)
+
+
+def _state_put(c, i, val):
+    """Write layer `i` of a stacked state or normaliser whole, in the
+    carried array itself."""
+    return lax.dynamic_update_slice(
+        c, val[None].astype(c.dtype), (i,) + (0,) * (c.ndim - 1))
+
+
+def _retention_decode_layer(lp, cs, cz, i, x, pos,
+                            cfg: TransformerConfig, tp_axis=None):
+    """Layer `i` for ONE new token a row: x [B, 1, D]; cs [L, B, Hkv, Df,
+    Dh] and cz [L, B, Hkv, Df] the whole stacked states and normalisers.
+    The state is decayed, takes the token's phi(k) v^T and is read out by
+    phi(q), all of it read and all of it written: a step's cost is the
+    state's bytes, whatever the tokens behind it.  The read-out runs at
+    "highest" so that a float32 state is read as float32 (a default
+    matmul would round it to bfloat16 on the way in).  `pos` scalar or
+    [B], as in `_decode_layer`."""
+    B = x.shape[0]
+    pos = jnp.asarray(pos)
+    positions = pos[:, None] if pos.ndim == 1 else pos[None]
+    q, k, v, gamma = _retention_qkv(lp, x, positions, cfg)
+    Hkv, Dh = k.shape[2], k.shape[3]
+    decay = jnp.exp(gamma[:, 0])                            # [B, Hkv]
+    s = _cache_layer(cs, i).astype(jnp.float32)
+    z = _cache_layer(cz, i)
+    k0, v0 = k[:, 0], v[:, 0].astype(jnp.float32)
+    qg = q[:, 0].reshape(B, Hkv, -1, Dh)                    # [B,Hkv,g,Dh]
+    # What came before, out of the state as it was; the token's own
+    # weight from q . k itself, so that it is >= 0 exactly (through phi
+    # it would round, and an empty state would divide by that).
+    fq = _phi(qg)                                           # [B,Hkv,g,Df]
+    own = jnp.square(jnp.einsum("bhgd,bhd->bhg", qg, k0,
+                                precision=lax.Precision.HIGHEST)) / Dh
+    num = decay[..., None, None] * jnp.einsum(
+        "bhgf,bhfd->bhgd", fq, s, precision=lax.Precision.HIGHEST) \
+        + own[..., None] * v0[:, :, None, :]
+    den = decay[..., None] * jnp.einsum(
+        "bhgf,bhf->bhg", fq, z, precision=lax.Precision.HIGHEST) + own
+    y = num / (den[..., None] + RETENTION_EPS)
+    x = _retention_out(lp, x, y.reshape(B, 1, -1, Dh), cfg, tp_axis)
+    fk = _phi(k0)                                           # [B, Hkv, Df]
+    s = decay[..., None, None] * s + fk[..., None] * v0[..., None, :]
+    z = decay[..., None] * z + fk
+    return x, _state_put(cs, i, s), _state_put(cz, i, z)
+
+
+def _phi_rows(u, mxu):
+    """`_phi` for the many vectors of a prefill chunk.  With `mxu`
+    bfloat16 the rolled copies of u come out of a matrix product with a
+    0/1 matrix, u split into two bfloat16 halves so that the copies are
+    u to 2^-17: on the v5e the lane rotations of `_phi` were a third of
+    a prefill and the MXU has room (PERF.md, PR 28).  Float32 models
+    take `_phi` itself."""
+    if mxu != jnp.bfloat16:
+        return _phi(u)
+    u = u.astype(jnp.float32)
+    scale, pick = _phi_tables(u.shape[-1])
+    hi = u.astype(mxu)
+    lo = (u - hi.astype(jnp.float32)).astype(mxu)
+    rolled = jnp.einsum("...d,df->...f", jnp.concatenate([hi, lo], -1),
+                        jnp.asarray(np.concatenate([pick, pick]), mxu),
+                        preferred_element_type=jnp.float32)
+    return rolled * jnp.tile(u, scale.shape[0]) * scale.reshape(-1)
+
+
+def _retention_prefill_layer(lp, cs, cz, i, x, cfg: TransformerConfig,
+                             tp_axis=None, chunk: int = RETENTION_CHUNK):
+    """Layer `i` over a whole prompt x [B, T0, D], from an empty state:
+    a scan over chunks of `chunk` tokens.  Inside a chunk the attention
+    form (every weight (q.k)^2 / d times its decay, so >= 0 exactly);
+    what came before the chunk is read out of the carried
+    state by phi(q), and the state then takes the chunk's keys and
+    values with the decay each has left at the chunk's end.  The scan
+    carries the state and its normaliser as ONE array, [B, Hkv, Df,
+    2 Dh]: the values get a column of ones beside them, so the column
+    of the state beside Dh sums phi(k) and one matrix product reads out
+    numerator and denominator, another updates both.  Every product
+    here takes its operands in the compute dtype and adds up in float32,
+    as the model's other products do; the carried state itself stays
+    float32 from chunk to chunk, so a rounding falls on what one product
+    reads and never on what is kept.  A last chunk that the prompt does not fill is padded with tokens that
+    neither decay the state nor add to it.  The final state is written
+    whole into layer `i` of cs / cz; nothing of them is read."""
+    B, T0, _ = x.shape
+    C = min(chunk, T0)
+    N = -(-T0 // C)
+    q, k, v, gamma = _retention_qkv(lp, x, jnp.arange(T0), cfg)
+    H, Hkv, Dh = q.shape[2], k.shape[2], k.shape[3]
+    g = H // Hkv
+    mxu = (jnp.bfloat16 if cfg.compute_dtype == jnp.bfloat16
+           else jnp.float32)
+    ones = jnp.zeros(v.shape[:-1] + (Dh,), jnp.float32).at[..., 0].set(1.0)
+    va = jnp.concatenate([v.astype(jnp.float32), ones], axis=-1)
+    pad = N * C - T0
+    if pad:
+        q, k, va, gamma = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),)
+                                   * (a.ndim - 2))
+                           for a in (q, k, va, gamma))
+    # [N, B, C, ...]: the scan walks the chunks
+    chunks = lambda a: jnp.moveaxis(
+        a.reshape((B, N, C) + a.shape[2:]), 1, 0)
+    causal = jnp.tril(jnp.ones((C, C), bool))
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    def chunk(sa, inp):                       # sa [B, Hkv, Df, 2 Dh]
+        qc, kc, vc, gc = inp
+        qg = qc.reshape(B, C, Hkv, g, Dh)
+        cum = jnp.cumsum(gc, axis=1)                     # [B, C, Hkv]
+        cum_h = cum.transpose(0, 2, 1)                   # [B, Hkv, C]
+        # inside the chunk
+        w = jnp.einsum("bthgd,bshd->bhgts", qg, kc) / (Dh ** 0.5)
+        left = jnp.where(causal, cum_h[..., :, None] - cum_h[..., None, :],
+                         -jnp.inf)                       # [B,Hkv,C,C]
+        a = jnp.square(w) * jnp.exp(left)[:, :, None]
+        ya = jnp.einsum("bhgts,bshe->bthge", a, vc)
+        # what came before it
+        fq = _phi_rows(qg, mxu).astype(mxu)              # [B,C,Hkv,g,Df]
+        ya += jnp.exp(cum)[..., None, None] * jnp.einsum(
+            "bthgf,bhfe->bthge", fq, sa.astype(mxu), **f32)
+        y = ya[..., :Dh] / (ya[..., Dh:Dh + 1] + RETENTION_EPS)
+        # the state at the chunk's end
+        kept = jnp.exp(cum_h[..., -1])                   # [B, Hkv]
+        fk = _phi_rows(kc, mxu) * jnp.exp(cum[:, -1:] - cum)[..., None]
+        sa = kept[..., None, None] * sa + jnp.einsum(
+            "bshf,bshe->bhfe", fk.astype(mxu), vc.astype(mxu), **f32)
+        return sa, y.reshape(B, C, H, Dh)
+
+    sa, y = lax.scan(
+        chunk, jnp.zeros((B, Hkv, retention_features(Dh), 2 * Dh),
+                         jnp.float32),
+        tuple(chunks(a) for a in (q, k, va, gamma)))
+    y = jnp.moveaxis(y, 0, 1).reshape(B, N * C, H, Dh)[:, :T0]
+    x = _retention_out(lp, x, y, cfg, tp_axis)
+    return (x, _state_put(cs, i, sa[..., :Dh]),
+            _state_put(cz, i, sa[..., Dh]))
+
+
 def _moe_tokens(mp, scale, x, cfg: TransformerConfig):
     """No-capacity top-1 MoE for decode/prefill: x [B, T, D] ->
     residual-added output.  All experts run on all tokens and the
@@ -379,17 +639,19 @@ def transformer_decode_step(params: Dict, cache: Dict, tokens,
     dt = cfg.compute_dtype
     x = params["embed"][tokens].astype(dt)[:, None, :]    # [B,1,D]
     pos = cache["pos"]
+    ka, kb = cache_leaves(cfg)
+    layer = (_retention_decode_layer if cfg.attn_kind == "retention"
+             else _decode_layer)
 
     x, ck, cv = _layer_walk(
-        params, cache["k"], cache["v"], x,
-        functools.partial(_decode_layer, pos=pos, cfg=cfg,
-                          tp_axis=tp_axis),
+        params, cache[ka], cache[kb], x,
+        functools.partial(layer, pos=pos, cfg=cfg, tp_axis=tp_axis),
         cfg, tp_axis)
     x = _rmsnorm(params["final_norm"]["scale"], x)
     logits = jnp.einsum("bod,vd->bov", x.astype(dt),
                         params["embed"].astype(dt),
                         preferred_element_type=jnp.float32)
-    return logits[:, 0], {"k": ck, "v": cv, "pos": pos + 1}
+    return logits[:, 0], {ka: ck, kb: cv, "pos": pos + 1}
 
 
 def transformer_extend(params: Dict, cache: Dict, tokens,
@@ -411,6 +673,8 @@ def transformer_extend(params: Dict, cache: Dict, tokens,
     Use `transformer_decode_step` past max_len instead (its single
     query is exactly the anchor, so no such skew exists).
     """
+    _refuse_retention(cfg, "transformer_extend (a chunk of tokens over "
+                           "a cache; speculative verify)")
     dt = cfg.compute_dtype
     B, c = tokens.shape
     _ck0 = cache["k"]
@@ -491,6 +755,9 @@ def transformer_speculative_generate(
     supported (rollback across a rolling ring would evict live slots).
     """
     B, T0 = prompt.shape
+    for c in (cfg, draft_cfg):
+        _refuse_retention(c, "speculative decoding (rolling back a round "
+                             "needs snapshots of the state)")
     if cfg.attn_window or draft_cfg.attn_window:
         raise ValueError(
             "speculative decoding does not support attn_window configs")
@@ -760,22 +1027,31 @@ def _prefill_layer(lp, ck, cv, i, x, cfg: TransformerConfig,
 
 
 def transformer_prefill(params: Dict, cache: Dict, prompt,
-                        cfg: TransformerConfig, tp_axis=None):
+                        cfg: TransformerConfig, tp_axis=None,
+                        chunk: int = RETENTION_CHUNK):
     """Absorb the whole prompt [B, T0] in ONE batched forward (the
     training attention path), filling ring slots 0..T0-1.  Returns
     (last-position logits [B, V], cache).  Requires a fresh cache
-    (pos == 0) and T0 <= max_len."""
+    (pos == 0) and T0 <= max_len.  `chunk` is a retention model's
+    chunk length; the result does not depend on it, and only the tests
+    of that pass another."""
     dt = cfg.compute_dtype
     B, T0 = prompt.shape
     if B < 1 or T0 < 1:
         raise InvalidRequestError(
             f"prompt must be non-empty, got shape {(B, T0)} (an empty "
             "prefill would silently leave the cache desynced)")
-    _ck0 = cache["k"]
-    S = (_ck0["q"] if isinstance(_ck0, dict) else _ck0).shape[2]
-    if T0 > S:
-        raise InvalidRequestError(
-            f"prompt length {T0} > cache max_len {S}")
+    ka, kb = cache_leaves(cfg)
+    if cfg.attn_kind == "retention":
+        # a state holds any length
+        layer = functools.partial(_retention_prefill_layer, chunk=chunk)
+    else:
+        layer = _prefill_layer
+        _ck0 = cache["k"]
+        S = (_ck0["q"] if isinstance(_ck0, dict) else _ck0).shape[2]
+        if T0 > S:
+            raise InvalidRequestError(
+                f"prompt length {T0} > cache max_len {S}")
     # Prefill writes the prompt at slot 0; a warm cache (pos != 0)
     # would silently desync slot <-> absolute-position bookkeeping.
     # Enforce eagerly whenever pos is concrete (inside jit pos is a
@@ -787,14 +1063,14 @@ def transformer_prefill(params: Dict, cache: Dict, prompt,
                 f"(pos == 0), got pos = {int(cache['pos'])}")
     x = params["embed"][prompt].astype(dt)                # [B,T0,D]
     x, ck, cv = _layer_walk(
-        params, cache["k"], cache["v"], x,
-        functools.partial(_prefill_layer, cfg=cfg, tp_axis=tp_axis),
+        params, cache[ka], cache[kb], x,
+        functools.partial(layer, cfg=cfg, tp_axis=tp_axis),
         cfg, tp_axis)
     x = _rmsnorm(params["final_norm"]["scale"], x[:, -1:])
     logits = jnp.einsum("bod,vd->bov", x.astype(dt),
                         params["embed"].astype(dt),
                         preferred_element_type=jnp.float32)
-    return logits[:, 0], {"k": ck, "v": cv,
+    return logits[:, 0], {ka: ck, kb: cv,
                           "pos": cache["pos"] + T0}
 
 
@@ -856,11 +1132,14 @@ def transformer_generate(params: Dict, cfg: TransformerConfig, prompt,
         raise InvalidRequestError(
             f"max_new_tokens must be >= 1, got {max_new_tokens} (a "
             "zero-length scan would silently return an empty batch)")
-    max_len = _resolve_max_len(cfg, T0, max_new_tokens, max_len)
-    if max_len < T0:
-        raise InvalidRequestError(
-            f"max_len {max_len} < prompt length {T0}: the prefill "
-            "would overrun the ring before the first generated token")
+    if cfg.attn_kind == "retention":
+        max_len = 1                  # a state a row: no ring to size
+    else:
+        max_len = _resolve_max_len(cfg, T0, max_new_tokens, max_len)
+        if max_len < T0:
+            raise InvalidRequestError(
+                f"max_len {max_len} < prompt length {T0}: the prefill "
+                "would overrun the ring before the first generated token")
     if temperature < 0:
         raise ValueError(f"temperature must be >= 0, got {temperature}")
     if temperature and rng is None:
@@ -966,6 +1245,8 @@ def make_decode_step(mesh, cfg: TransformerConfig, quantize=None):
 
     from .transformer import transformer_pspecs
 
+    _refuse_retention(cfg, "make_decode_step (dp/tp sharding of the "
+                           "state)")
     axes = {a: mesh.shape.get(a, 1) > 1 for a in mesh.axis_names}
     if axes.get("ep") and cfg.moe_every:
         raise NotImplementedError(
@@ -1062,6 +1343,8 @@ def transformer_beam_search(params: Dict, cfg: TransformerConfig,
     selects the top-W of the W*V continuations per batch and GATHERS
     the parent beams' cache rows, the standard reorder.  One lax.scan.
     """
+    _refuse_retention(cfg, "beam search (reordering beams copies "
+                           "their states)")
     B, T0 = prompt.shape
     W = int(beam_width)
     if W < 1:
@@ -1164,7 +1447,8 @@ def transformer_beam_search(params: Dict, cfg: TransformerConfig,
     return out, scores
 
 
-__all__ = ["init_decode_cache", "transformer_decode_step",
+__all__ = ["init_decode_cache", "cache_leaves", "retention_features",
+           "transformer_decode_step",
            "transformer_prefill", "transformer_extend",
            "transformer_generate", "transformer_speculative_generate",
            "transformer_beam_search", "make_decode_step",

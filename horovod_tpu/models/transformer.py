@@ -48,8 +48,28 @@ class TransformerConfig:
     aux_loss_weight: float = 0.01
     n_kv_heads: int = 0         # 0 = MHA; else GQA/MQA kv head count
     attn_window: int = 0        # 0 = full causal; else sliding window
+    # "softmax" | "retention".  A power-retention layer (models/decode.py,
+    # "Power retention") weighs key j for query t by
+    # decay(j..t) * (q.k / sqrt(d_head)) ** 2 with no softmax, q and k
+    # normed per head; its cache is a fixed state a row, not a ring of
+    # keys and values.  Served and generated; not trained
+    # (make_train_step).
+    attn_kind: str = "softmax"
+    state_dtype: Any = jnp.float32   # what the retention state is held in
 
     def __post_init__(self):
+        if self.attn_kind not in ("softmax", "retention"):
+            raise ValueError(
+                f"attn_kind must be 'softmax' or 'retention', got "
+                f"{self.attn_kind!r}")
+        if self.attn_kind == "retention":
+            if self.attn_window:
+                raise ValueError(
+                    "a retention layer has no window (its decay forgets); "
+                    "attn_window must be 0")
+            if self.d_head % 2:
+                raise ValueError(
+                    f"retention needs an even d_head, got {self.d_head}")
         if self.attn_window < 0:
             raise ValueError(
                 f"attn_window must be >= 0, got {self.attn_window}")
@@ -94,6 +114,19 @@ def transformer_init(key, cfg: TransformerConfig) -> Dict:
             "wd": norm(keys[7], (Lr, F, D), s_f),
         },
     }
+    if cfg.attn_kind == "retention":
+        # The decay gate: one number a kv head and token, through
+        # log-sigmoid; the bias starts where a token keeps 0.95 to 0.9995
+        # of the state, head by head (zero would halve it every token).
+        Hkv = cfg.kv_heads
+        params["blocks"]["w_decay"] = norm(
+            jax.random.fold_in(key, 98), (Lr, D, Hkv), s_d)
+        params["blocks"]["b_decay"] = jnp.broadcast_to(
+            jnp.linspace(3.0, 7.5, Hkv, dtype=jnp.float32), (Lr, Hkv))
+        # q and k are normed per head over d_head, with a learned scale
+        for name in ("q_norm", "k_norm"):
+            params["blocks"][name] = {
+                "scale": jnp.ones((Lr, Dh), jnp.float32)}
     if cfg.moe_every:
         n_moe = sum(1 for i in range(Lr) if (i + 1) % cfg.moe_every == 0)
         mkeys = jax.random.split(jax.random.fold_in(key, 99), n_moe)
@@ -134,6 +167,11 @@ def _rmsnorm(scale, x):
 def _attention_block(lp, x, positions, cfg, tp_axis, sp_axis):
     """Pre-norm attention with RoPE.  lp: this layer's params (unstacked).
     Inside shard_map: heads sharded over tp, sequence over sp."""
+    if cfg.attn_kind != "softmax":
+        raise HorovodTpuError(
+            f"the training forward has no {cfg.attn_kind!r} layer: such "
+            "a model is served and generated (models/decode.py), not "
+            "trained")
     dt = cfg.compute_dtype
     h = _rmsnorm(lp["ln1"]["scale"], x)
     q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dt))
@@ -436,6 +474,11 @@ def make_train_step(mesh, cfg: TransformerConfig, optimizer,
     from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    if cfg.attn_kind != "softmax":
+        raise HorovodTpuError(
+            f"make_train_step: attn_kind {cfg.attn_kind!r} is not "
+            "trained here (no backward pass through the retention state); "
+            "serve it through InferenceServer or transformer_generate")
     axes = {a: mesh.shape.get(a, 1) > 1 for a in mesh.axis_names}
     pp = mesh.shape.get("pp", 1)
     M = n_microbatches or max(1, pp)

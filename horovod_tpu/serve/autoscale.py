@@ -518,8 +518,7 @@ def snapshot_from_server(server, step: Optional[int] = None,
         occupancy=float(server.sched.occupancy()),
         queue_depth=int(server.sched.queue_depth()),
         queue_wait_ms=float(server.oldest_queue_wait_ms()),
-        pool_free_frac=(server.pool.pages_free()
-                        / max(1, server.pool.total_pages)),
+        pool_free_frac=1.0 - server.pool.utilization(),
         burn_fast=(budget.burn_rate(budget.fast_window_s)
                    if budget is not None else 0.0),
         burn_slow=(budget.burn_rate(budget.slow_window_s)

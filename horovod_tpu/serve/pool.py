@@ -42,9 +42,10 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..common.exceptions import HorovodTpuError, InvalidRequestError
-from ..models.decode import init_decode_cache
+from ..models.decode import cache_leaves, init_decode_cache
 
 
 # -- jitted data-movement kernels -------------------------------------------
@@ -321,4 +322,58 @@ class PagedKVPool:
             jnp.asarray(pids, jnp.int32), len(pids))
 
 
-__all__ = ["PagedKVPool", "PoolExhaustedError"]
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _state_install(view, state, row):
+    """Write a prefill's final state (batch 1) into slot `row` of the
+    decode view's stacked leaves, in the view's own buffers."""
+    return tuple(lax.dynamic_update_slice(
+        v, s.astype(v.dtype), (0, row) + (0,) * (v.ndim - 2))
+        for v, s in zip(view, state))
+
+
+class StateSlots:
+    """The cache of a retention model (`cfg.attn_kind == "retention"`,
+    models/decode.py): one fixed state a row, whatever the context
+    length, held ONCE, in the decode view the step programs take donated.
+    It is no pool: there are no pages to count, allocate or free, nothing
+    to gather into the view and nothing to scatter back out of it.  What
+    admission needs is a free row, and the scheduler counts rows
+    (`rows_held` is its count, so that `utilization` answers from the one
+    place that knows).  A prefill's final state is written into its row's
+    slot by `install`, whole, so a row reused after another request
+    carries nothing over."""
+
+    def __init__(self, cfg, rows: int, rows_held: Callable[[], int]):
+        self.cfg = cfg
+        self.rows = rows
+        self.rows_held = rows_held
+        slot = jax.eval_shape(self.scratch)
+        #: bytes of one row's state and normaliser, and of all rows'
+        self.row_bytes = sum(slot[n].size * slot[n].dtype.itemsize
+                             for n in cache_leaves(cfg))
+        self.state_bytes = rows * self.row_bytes
+
+    def new_view(self) -> Tuple:
+        """An empty view's two leaves, a slot a row.  The server owns
+        them: it rebinds what its programs and `install` return."""
+        view = init_decode_cache(self.cfg, self.rows, 1)
+        return tuple(view[n] for n in cache_leaves(self.cfg))
+
+    def utilization(self) -> float:
+        """Rows held over rows: what the pool's page share is for a
+        paged model (the autoscaler's signal)."""
+        return self.rows_held() / self.rows
+
+    def scratch(self) -> Dict:
+        """An empty batch-1 cache for one prefill to fill."""
+        return init_decode_cache(self.cfg, 1, 1)
+
+    def install(self, view: Tuple, scratch: Dict, row: int) -> Tuple:
+        """`view` with slot `row` holding `scratch`'s state; consumes
+        `view` (donated), so rebind the result."""
+        return _state_install(
+            view, tuple(scratch[n] for n in cache_leaves(self.cfg)),
+            jnp.int32(row))
+
+
+__all__ = ["PagedKVPool", "PoolExhaustedError", "StateSlots"]

@@ -27,6 +27,13 @@ Step anatomy (``step()``):
   5. scatter — copy each active row's written ring slot(s) back into
                its pages; the pool stays the source of truth.
 
+A retention model (``cfg.attn_kind == "retention"``) has no pages: its
+cache is one fixed state a row, held once, in the view (``StateSlots``,
+pool.py).  Admission then needs a free row and nothing else, the
+prefill's final state is written into the row's slot of the view
+(``state_install``, inside ``admit``), and steps 3 and 5 have nothing to
+copy.  Everything else of the step is the same code.
+
 Speculative rounds keep the greedy target chain EXACT: every decided
 token is the argmax of target logits computed over a correct prefix
 (accepted-prefix min over rows; stale speculative slots are never
@@ -66,13 +73,14 @@ from ..metrics import catalog as _met
 from ..models.decode import (
     _spec_extend_fn,
     _spec_step_fn,
+    cache_leaves,
     init_decode_cache,
     transformer_prefill,
 )
 from ..utils import autotune
 from ..utils.timeline import get_timeline, span
 from .flightrec import FlightRecorder
-from .pool import PagedKVPool, PoolExhaustedError
+from .pool import PagedKVPool, PoolExhaustedError, StateSlots
 from .scheduler import ActiveSeq, ContinuousScheduler, Request
 from .slo import SloController
 
@@ -129,6 +137,17 @@ class InferenceServer:
             raise InvalidRequestError(
                 "speculative serving does not support attn_window "
                 "configs (chunked verify over a rolling ring)")
+        self.retention = cfg.attn_kind == "retention"
+        if self.retention:
+            for what, asked in (
+                    ("quantize", quantize is not None),
+                    ("draft_params (speculative serving: the verify "
+                     "pass needs snapshots of the state)",
+                     draft_params is not None)):
+                if asked:
+                    raise InvalidRequestError(
+                        f"{what} is not supported for a retention model "
+                        "(attn_kind='retention')")
         # Per-sequence budget: the full ring a request may need.  The
         # gamma headroom mirrors transformer_speculative_generate — a
         # round writes up to gamma slots past the accepted frontier.
@@ -138,14 +157,18 @@ class InferenceServer:
         self.view_tokens = self.view_pages * self.page_tokens
         pool_pages = pool_pages or autotune.current_serve_pool_pages() \
             or self.max_batch * self.view_pages
-        self.pool = PagedKVPool(cfg, pool_pages, self.page_tokens,
-                                quantize=quantize)
+        self.sched = ContinuousScheduler(self.max_batch, policy=policy,
+                                         seed=seed)
+        if self.retention:
+            self.pool = StateSlots(cfg, self.max_batch,
+                                   lambda: len(self.sched.active))
+        else:
+            self.pool = PagedKVPool(cfg, pool_pages, self.page_tokens,
+                                    quantize=quantize)
         self.dpool = None
         if draft_params is not None:
             self.dpool = PagedKVPool(draft_cfg, pool_pages,
                                      self.page_tokens)
-        self.sched = ContinuousScheduler(self.max_batch, policy=policy,
-                                         seed=seed)
         if slo_ms is None:                 # HOROVOD_SERVE_SLO_MS
             slo_ms = util.env_float("SERVE_SLO_MS", 0.0)
         self.slo = SloController(slo_ms)
@@ -169,10 +192,11 @@ class InferenceServer:
             self.sched.observer = lambda step, event, req, row: \
                 rec.record("sched", {"event": event, "req": req,
                                      "row": row}, step=step)
-            self.pool.on_event = lambda ev, sid, n, free: \
-                rec.record("pool", {"event": ev, "req": sid,
-                                    "pages": n, "free": free},
-                           step=self.step_no)
+            if not self.retention:     # rows are the scheduler's events
+                self.pool.on_event = lambda ev, sid, n, free: \
+                    rec.record("pool", {"event": ev, "req": sid,
+                                        "pages": n, "free": free},
+                               step=self.step_no)
             if self.dpool is not None:
                 self.dpool.on_event = lambda ev, sid, n, free: \
                     rec.record("dpool", {"event": ev, "req": sid,
@@ -191,7 +215,12 @@ class InferenceServer:
         self.row_pos = np.zeros(self.max_batch, np.int64)
         self.last_logits = np.zeros((self.max_batch, V), np.float32)
         self.row_seq: List[Optional[int]] = [None] * self.max_batch
+        # The decode view's two stacked leaves: keys and values gathered
+        # from the pool, or a retention model's states and normalisers,
+        # which live nowhere else.
         self.view_k = self.view_v = None
+        if self.retention:
+            self.view_k, self.view_v = self.pool.new_view()
         self.dview_k = self.dview_v = None
         self._dirty_rows: Dict[int, int] = {}    # row -> seq_id to refresh
         self.step_no = 0
@@ -202,6 +231,9 @@ class InferenceServer:
         self.device_steps = 0
         self.spec_steps = 0
         self.occupancy_sum = 0.0
+        self.state_installs = 0
+        #: bytes the state view holds (0 for a paged model)
+        self.state_bytes = self.pool.state_bytes if self.retention else 0
         self.token_latencies_ms: List[float] = []
         self.request_latencies_ms: List[float] = []
 
@@ -244,6 +276,10 @@ class InferenceServer:
         return n
 
     def _can_admit(self, req: Request) -> bool:
+        if self.retention:
+            # A free row is all a state needs, and the scheduler asks
+            # only while it has one.
+            return True
         n = self._budget_tokens(req)
         if not self.pool.can_alloc(n):
             return False
@@ -256,6 +292,21 @@ class InferenceServer:
         lg, scratch = _prefill_fn(cfg)(
             params, scratch, jnp.asarray(seq.req.prompt[None]))
         pool.scatter_pages(seq.req.req_id, scratch["k"], scratch["v"])
+        return lg
+
+    def _prefill_state(self, seq):
+        """A retention model's prefill: the prompt's final state goes
+        into the row's slot of the view, whole, where the decode steps
+        will update it; no page is written."""
+        lg, scratch = _prefill_fn(self.cfg)(
+            self.params, self.pool.scratch(),
+            jnp.asarray(seq.req.prompt[None]))
+        with span("state_install", "serve",
+                  {"req": seq.req.req_id, "row": seq.row,
+                   "bytes": self.pool.row_bytes}):
+            self.view_k, self.view_v = self.pool.install(
+                (self.view_k, self.view_v), scratch, seq.row)
+        self.state_installs += 1
         return lg
 
     def _admit(self) -> int:
@@ -283,14 +334,18 @@ class InferenceServer:
                             args={"req": rid}, tid=f"req/{rid}")
             budget = self._budget_tokens(seq.req)
             T0 = int(seq.req.prompt.size)
+            pages = 0 if self.retention else self.pool.pages_needed(budget)
             with span("prefill", "serve",
                       {"req": rid, "prompt_tokens": T0, "row": seq.row,
-                       "pages": self.pool.pages_needed(budget),
+                       "pages": pages,
                        "queue_wait_us": round(queue_wait * 1e6, 1)},
                       tid=f"req/{rid}"):
-                pids = self.pool.alloc(rid, budget)
-                lg = self._prefill_into(self.pool, self.params, self.cfg,
-                                        seq, len(pids))
+                if self.retention:
+                    lg = self._prefill_state(seq)
+                else:
+                    pids = self.pool.alloc(rid, budget)
+                    lg = self._prefill_into(self.pool, self.params,
+                                            self.cfg, seq, len(pids))
                 if self.dpool is not None:
                     dpids = self.dpool.alloc(rid, budget)
                     self._prefill_into(self.dpool, self.draft_params,
@@ -337,7 +392,8 @@ class InferenceServer:
     def _finish(self, seq: ActiveSeq) -> None:
         rid = seq.req.req_id
         self.sched.evict(self.step_no, seq.row)
-        self.pool.free(rid)
+        if not self.retention:       # a row given back is all there is
+            self.pool.free(rid)
         if self.dpool is not None:
             self.dpool.free(rid)
         self.row_seq[seq.row] = None
@@ -375,7 +431,11 @@ class InferenceServer:
     def _refresh_views(self) -> None:
         """Bring the pooled decode view up to date: a full gather the
         first time, then per-admitted-row updates (evicted rows need
-        none — see PagedKVPool.gather_rows)."""
+        none — see PagedKVPool.gather_rows).  A retention model's view
+        is the only copy and `_prefill_state` has already written it."""
+        if self.retention:
+            self._dirty_rows.clear()
+            return
         if self.view_k is None:
             self.view_k, self.view_v = self.pool.gather(
                 self.row_seq, self.view_pages)
@@ -482,15 +542,17 @@ class InferenceServer:
         with span("launch", "serve"):      # dispatches only, no wait
             self._refresh_views()
             base = self.row_pos.copy()
-            cache = {"k": self.view_k, "v": self.view_v,
+            ka, kb = cache_leaves(self.cfg)
+            cache = {ka: self.view_k, kb: self.view_v,
                      "pos": jnp.asarray(base, jnp.int32)}
             lg, cache = _spec_step_fn(self.cfg)(
                 self.params, cache, jnp.asarray(feed, jnp.int32))
-            self.view_k, self.view_v = cache["k"], cache["v"]
-            sids = [self.row_seq[r] for r in rows]
-            slots = [int(base[r]) % self.view_tokens for r in rows]
-            self.pool.scatter_slots(self.view_k, self.view_v, sids, rows,
-                                    slots)
+            self.view_k, self.view_v = cache[ka], cache[kb]
+            if not self.retention:      # a state has no page to copy to
+                sids = [self.row_seq[r] for r in rows]
+                slots = [int(base[r]) % self.view_tokens for r in rows]
+                self.pool.scatter_slots(self.view_k, self.view_v, sids,
+                                        rows, slots)
         with span("fetch", "serve"):       # the step's one sync
             self.last_logits = np.array(lg)    # copy: row writes on admit
         for r in rows:
@@ -620,7 +682,9 @@ class InferenceServer:
     def _set_gauges(self) -> None:
         _met.serve_queue_depth.set(self.sched.queue_depth())
         _met.serve_batch_occupancy.set(self.sched.occupancy())
-        _met.serve_pool_pages_free.set(self.pool.pages_free())
+        if not self.retention:      # a state has no pages: not exported
+            _met.serve_pool_pages_free.set(self.pool.pages_free())
+        _met.serve_state_bytes.set(self.state_bytes)
         p99 = self.slo.p99_ms()
         if p99:
             _met.serve_p99_ms.set(p99)

@@ -27,9 +27,16 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture()
 def fake_ray(monkeypatch):
+    """The fake's actors run in this process, so `set_env` writes the
+    ranks into the test process's own environment: put it back, or
+    whichever test file this worker runs next reads HOROVOD_LOCAL_RANK=1
+    (tests/test_basics.py::test_sizes did, when it shared a worker)."""
     fake = FakeRay()
     monkeypatch.setattr(hvd_ray, "_ray", fake)
-    return fake
+    saved = dict(os.environ)
+    yield fake
+    os.environ.clear()
+    os.environ.update(saved)
 
 
 def fn_const():
