@@ -16,7 +16,10 @@ each result by the repo's own means:
                recomputation; no width is.  It is not a named model.
   server       InferenceServer at the same widths: a dozen requests of
                mixed prompt length, pool drained, every emitted token
-               within a logit tolerance of transformer_generate's path.
+               within a logit tolerance of transformer_generate's path;
+               then the decode step compiled at a stacked view, its
+               temporaries under one layer's K slice (no layer's K or V
+               is copied out of the view before its contraction).
   kernels      every pl.pallas_call site compiled by Mosaic and compared
                with its XLA oracle, including the automatic flash route
                at T = 16384.
@@ -32,6 +35,7 @@ on the CPU mesh.  The last line of stdout is one JSON object.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import importlib.metadata
 import json
 import os
@@ -56,6 +60,7 @@ from horovod_tpu.models import (
     transformer_init,
     transformer_prefill,
 )
+from horovod_tpu.models.decode import _spec_step_fn
 from horovod_tpu.ops import pallas_kernels as pk
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.ops.fused_collectives import pallas_matmul
@@ -83,6 +88,13 @@ LM_BATCH, LM_SEQ, LM_STEPS = 2, 2048, 4
 
 SERVE = dict(prompt_lens=(24, 96, 160, 256), n_requests=12,
              max_new=(6, 10), max_batch=4, page_tokens=16)
+# The decode view the step is compiled at for `phase_decode_layout`, and
+# the kv heads it groups the 16 query heads under.  No smaller: compiled
+# for a v5e, the slot-major cache of PR 27 copied a layer's K and V out
+# only where a slice held 128 MB or more and the heads were grouped
+# (32 x 4096 here: 134 MB copied, 32 x 3584: none; PERF.md, PR 29), so a
+# smaller view would pass whatever the layout.  Nothing is allocated.
+DECODE_VIEW = dict(rows=32, slots=4096, kv_heads=4)
 
 # Flash attention forward and backward, bf16, B 1, at KERNEL_SEQ: heads,
 # d_head and what else `_attention_case` takes.
@@ -347,6 +359,36 @@ def phase_server(cfg: TransformerConfig, prompt_lens, n_requests: int,
             "run_s": round(run_s, 2)}
 
 
+def phase_decode_layout(cfg: TransformerConfig, rows: int, slots: int,
+                        kv_heads: int) -> dict:
+    """The server's decode step (vector `pos`) compiled for this backend
+    at a view of `rows` x `slots` under `kv_heads` kv heads, nothing run.  The cache is held
+    head-major so that the step's contractions read a layer's slice of
+    the view where it lies (models/decode.py); if the compiler ever
+    copies the slice out again, the program's temporaries hold at least
+    one of it.  On a TPU that fails the phase; the CPU's compiler fuses
+    otherwise and is only reported."""
+    cfg = dataclasses.replace(cfg, n_kv_heads=kv_heads)
+    # weights in the compute dtype, as a server holds them: f32 ones
+    # would be cast inside the program, into temporaries of their own
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(cfg.compute_dtype),
+        transformer_init(jax.random.PRNGKey(0), cfg)))
+    cache = jax.eval_shape(lambda: init_decode_cache(cfg, rows, slots))
+    cache["pos"] = jax.ShapeDtypeStruct((rows,), jnp.int32)
+    compiled = _spec_step_fn(cfg).lower(
+        params, cache, jax.ShapeDtypeStruct((rows,), jnp.int32)).compile()
+    temp = int(compiled.memory_analysis().temp_size_in_bytes)
+    k = cache["k"]
+    k_slice = k.size // k.shape[0] * k.dtype.itemsize
+    if jax.default_backend() == "tpu":
+        _check(temp < k_slice, "decode layout",
+               f"the step's temporaries, {temp} B, hold a layer's K slice "
+               f"({k_slice} B): a copy precedes the contraction")
+    return {"view": list(k.shape), "temp_bytes": temp,
+            "layer_k_slice_bytes": k_slice}
+
+
 # --- kernels ---------------------------------------------------------------
 
 def _rel_err(got, want) -> float:
@@ -472,6 +514,7 @@ def main() -> int:
         report("transformer sharded", phase_transformer_sharded(
             cfg, LM_BATCH, LM_SEQ, LM_STEPS, one_chip))
     report("server", phase_server(cfg, tol=SERVE_LOGIT_TOL, **SERVE))
+    report("decode layout", phase_decode_layout(cfg, **DECODE_VIEW))
     report("kernels",
            phase_kernels(KERNEL_SEQ, KERNEL_LONG_SEQ, FLASH_CASES))
 

@@ -28,10 +28,24 @@ test anchor uses capacity_factor = n_experts).  Expert compute runs
 all-experts-then-mask (static shapes; E x the single-token MLP cost,
 negligible at decode and acceptable at prefill for modest E).
 
-Layout: cache k/v are [L, B, max_len, Hkv, Dh] in `cfg.compute_dtype`,
-`pos` a scalar int32 count of tokens already absorbed.  All steps are
-fixed-shape (dynamic_update_slice into the ring; band masks over the
-full buffer), so one compiled program serves the whole generation.
+Layout: cache k/v are HEAD-MAJOR, [L, B, Hkv, max_len, Dh] in
+`cfg.compute_dtype` (a quantized cache's scales [L, B, Hkv, max_len]),
+`pos` a scalar int32 count of tokens already absorbed.  The one reader
+of a layer's keys and values is the pair of contractions in
+`_decode_layer`, which want each kv head's slots contiguous.  The v5e
+compiler fuses a `dynamic-slice` of the carried stack into a
+contraction's operand, or a change of layout, never both: from a
+slot-major cache ([L, B, max_len, Hkv, Dh]) every layer's K and V were
+first copied out head-major, half of a served decode step (PERF.md,
+PR 27 and PR 29).  Held head-major the slice is read where it lies.  The
+price is the write's shape: a token's new K is Hkv pieces of Dh numbers
+a slot apart and no longer one vector (`_cache_write`,
+`_cache_write_rows`); prefill transposes the prompt's K and V once.
+`init_decode_cache` defines the layout and `cache_slots` reads the ring
+length off it; nobody else names an axis past the rows (axis 1).
+All steps are fixed-shape (dynamic_update_slice into the ring; band
+masks over the full buffer), so one compiled program serves the whole
+generation.
 Prefill is ONE batched forward through the training attention path
 (`parallel.sequence.full_attention`), not a per-token loop.  The cache
 is updated IN PLACE: the layer walk carries the stacked arrays and each
@@ -115,7 +129,9 @@ def init_decode_cache(cfg: TransformerConfig, batch: int,
         return {"s": jnp.zeros(shape + (cfg.d_head,), cfg.state_dtype),
                 "z": jnp.zeros(shape, jnp.float32),
                 "pos": jnp.zeros((), jnp.int32)}
-    shape = (cfg.n_layers, batch, max_len, cfg.kv_heads, cfg.d_head)
+    # Head-major: a layer's slice is what the contractions of
+    # `_decode_layer` read, with no copy between (module text).
+    shape = (cfg.n_layers, batch, cfg.kv_heads, max_len, cfg.d_head)
     if quantize is not None:
         qdt = jnp.int8 if quantize == "int8" else jnp.float8_e4m3fn
         kv = lambda: {"q": jnp.zeros(shape, qdt),
@@ -142,6 +158,12 @@ def _quant_vec(x, qdt):
     return q, scale
 
 
+def cache_slots(c) -> int:
+    """Ring slots a row holds, off a stacked K or V leaf (plain, or the
+    {"q", "scale"} dict of a quantized cache)."""
+    return (c["q"] if isinstance(c, dict) else c).shape[3]
+
+
 def _cache_put(c, val, put):
     """Apply `put(array, update)` to a plain cache array or, after
     quantizing `val` per vector, to both leaves of a {"q", "scale"}
@@ -155,11 +177,13 @@ def _cache_put(c, val, put):
 def _cache_write(c, i, val, slot):
     """Write `val` [B, n, Hkv, Dh] (one position at decode, the whole
     prompt at prefill — the slice length comes from val) into layer
-    `i` of the STACKED, possibly quantized cache `c` [L, B, S, ...],
+    `i` of the STACKED, possibly quantized cache `c` [L, B, Hkv, S, ...],
     starting at ring slot `slot` of every row.  Only those n slots are
-    touched: the update lands in the carried array itself."""
+    touched: the update lands in the carried array itself, laid
+    [B, Hkv, n, ...] as the cache is."""
     return _cache_put(c, val, lambda a, u: lax.dynamic_update_slice(
-        a, u[None], (i, 0, slot) + (0,) * (a.ndim - 3)))
+        a, jnp.swapaxes(u, 1, 2)[None],
+        (i, 0, 0, slot) + (0,) * (a.ndim - 4)))
 
 
 def _cache_write_rows(c, i, val, slots):
@@ -171,18 +195,27 @@ def _cache_write_rows(c, i, val, slots):
     per-vector, data movement is exact), so scalar/vector parity is
     bitwise when all rows share a position.
 
-    One scatter of B x n vectors at (i, row, slot + j).  Not a vmap of
-    `dynamic_update_slice` over the rows: batched over axis 1 of the
-    stacked array that makes the v5e compiler transpose the whole cache
-    batch-major at the program's entry and back at its exit."""
-    rows = jnp.arange(slots.shape[0])[:, None]
-    cols = slots[:, None] + jnp.arange(val.shape[1])[None, :]   # [B, n]
-    return _cache_put(c, val, lambda a, u: a.at[i, rows, cols].set(
+    One scatter a leaf, of B x n x Hkv pieces at (i, row, head,
+    slot + j) with Dh the update's window.  The kv heads ride among the
+    INDICES: with Hkv in the window beside Dh (B x n updates of a whole
+    vector, by `.at[i, rows, :, cols]` or by explicit dimension numbers
+    with a [Hkv, 1, Dh] window) the v5e compiler wants the window's
+    axes innermost in memory and transposes the whole cache slot-major
+    at the program's entry and back at its exit (1.9 GB of temporaries
+    at 32 rows x 3584 slots x 8 layers; PERF.md, PR 29), as it does for
+    a vmap of `dynamic_update_slice` over the rows (PR 27)."""
+    B, n, Hkv = val.shape[:3]
+    rows = jnp.arange(B)[:, None, None]
+    cols = (slots[:, None] + jnp.arange(n)[None, :])[:, :, None]   # [B,n,1]
+    heads = jnp.arange(Hkv)[None, None, :]
+    # (layer, row, head, slot) are adjacent indices, so the update is
+    # laid [B, n, Hkv, ...]: `val` as it comes.
+    return _cache_put(c, val, lambda a, u: a.at[i, rows, heads, cols].set(
         u, indices_are_sorted=True, unique_indices=True))
 
 
 def _cache_layer(c, i):
-    """Layer `i` of a stacked cache, [B, S, ...] per leaf, for reading:
+    """Layer `i` of a stacked cache, [B, Hkv, S, ...] per leaf, for reading:
     a slice the consumer takes straight out of the carried array."""
     return jax.tree_util.tree_map(
         lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), c)
@@ -218,7 +251,7 @@ def _decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
     (c == 1 is the plain decode step; c > 1 serves `transformer_extend`
     and the speculative verify pass).
 
-    x [B, c, D]; ck/cv [L, B, S, Hkv, Dh], the WHOLE stacked cache
+    x [B, c, D]; ck/cv [L, B, Hkv, S, Dh], the WHOLE stacked cache
     (LOCAL head counts under tensor parallelism; head dims are derived
     from the weights, not cfg, so tp shards just work); `i` the layer's
     index into it, a traced scalar under the scan or a Python int.
@@ -237,8 +270,7 @@ def _decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
     tests/test_decode.py::test_layer_walk_in_place).
     """
     dt = cfg.compute_dtype
-    _shape_src = ck["q"] if isinstance(ck, dict) else ck
-    B, S = _shape_src.shape[1], _shape_src.shape[2]
+    B, S = x.shape[0], cache_slots(ck)
     Dh = cfg.d_head
     c = x.shape[1]
 
@@ -265,7 +297,8 @@ def _decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
         cv = _cache_write(cv, i, v, slot)
 
     # Grouped attention against the ring: q [B,c,Hkv,g,Dh] x
-    # cache [B,S,Hkv,Dh] — the repeated kv heads never materialize.
+    # cache [B,Hkv,S,Dh] — the repeated kv heads never materialize, and
+    # the layer's slice of the stack is the operand as it lies.
     # Under an int8 cache the per-vector scales FACTOR OUT of the
     # contractions (scale is constant over Dh), so they multiply the
     # [..,S]-shaped scores/probs instead of a Dh-times-larger
@@ -273,12 +306,12 @@ def _decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
     qg = q.reshape(B, c, Hkv, g, Dh)
     lk, lv = _cache_layer(ck, i), _cache_layer(cv, i)
     if isinstance(lk, dict):
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
+        s = jnp.einsum("bqhgd,bhkd->bhgqk", qg.astype(jnp.float32),
                        lk["q"].astype(jnp.float32))
-        s = s * lk["scale"].transpose(0, 2, 1)[:, :, None, None, :]
+        s = s * lk["scale"][:, :, None, None, :]
         s = s / (Dh ** 0.5)
     else:
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
+        s = jnp.einsum("bqhgd,bhkd->bhgqk", qg.astype(jnp.float32),
                        lk.astype(jnp.float32)) / (Dh ** 0.5)
     # Per-query causal mask over reconstructed absolute positions:
     # query i (absolute pos+i) sees slots holding abs <= pos+i.  The
@@ -306,11 +339,11 @@ def _decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
         s = jnp.where(valid[None, None, None, :, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     if isinstance(lv, dict):
-        pv = p * lv["scale"].transpose(0, 2, 1)[:, :, None, None, :]
-        o = jnp.einsum("bhgqk,bkhd->bqhgd", pv,
+        pv = p * lv["scale"][:, :, None, None, :]
+        o = jnp.einsum("bhgqk,bhkd->bqhgd", pv,
                        lv["q"].astype(jnp.float32))
     else:
-        o = jnp.einsum("bhgqk,bkhd->bqhgd", p,
+        o = jnp.einsum("bhgqk,bhkd->bqhgd", p,
                        lv.astype(jnp.float32))
     o = o.reshape(B, c, Hq, Dh).astype(dt)
     out = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(dt))
@@ -677,8 +710,7 @@ def transformer_extend(params: Dict, cache: Dict, tokens,
                            "a cache; speculative verify)")
     dt = cfg.compute_dtype
     B, c = tokens.shape
-    _ck0 = cache["k"]
-    S = (_ck0["q"] if isinstance(_ck0, dict) else _ck0).shape[2]
+    S = cache_slots(cache["k"])
     pos = cache["pos"]
     if not isinstance(pos, jax.core.Tracer):
         # Vector pos (per-row serving depths): every row must fit — the
@@ -1002,8 +1034,10 @@ def _prefill_layer(lp, ck, cv, i, x, cfg: TransformerConfig,
                    tp_axis=None):
     """Layer `i`'s attention over a whole prompt x [B, T0, D] (the
     training attention path), under `_layer_walk`'s contract: slots
-    0..T0-1 of layer `i` of the stacked cache are written in place and
-    nothing of the cache is read — the prompt attends to its own k/v."""
+    0..T0-1 of layer `i` of the stacked cache are written in place
+    (`_cache_write` lays the prompt's k and v head-major, one transpose a
+    layer) and nothing of the cache is read — the prompt attends to its
+    own k/v, as projected."""
     dt = cfg.compute_dtype
     positions = jnp.arange(x.shape[1])
     h = _rmsnorm(lp["ln1"]["scale"], x)
@@ -1047,8 +1081,7 @@ def transformer_prefill(params: Dict, cache: Dict, prompt,
         layer = functools.partial(_retention_prefill_layer, chunk=chunk)
     else:
         layer = _prefill_layer
-        _ck0 = cache["k"]
-        S = (_ck0["q"] if isinstance(_ck0, dict) else _ck0).shape[2]
+        S = cache_slots(cache["k"])
         if T0 > S:
             raise InvalidRequestError(
                 f"prompt length {T0} > cache max_len {S}")
@@ -1274,9 +1307,9 @@ def make_decode_step(mesh, cfg: TransformerConfig, quantize=None):
         is_leaf=lambda x: isinstance(x, P))
     tok_spec = P(dp)
     logits_spec = P(dp, None)
-    kv_spec = P(None, dp, None, tp_axis, None)
+    kv_spec = P(None, dp, tp_axis, None, None)
     if quantize is not None:    # int8 and fp8_e4m3 share the layout
-        kv_spec = {"q": kv_spec, "scale": P(None, dp, None, tp_axis)}
+        kv_spec = {"q": kv_spec, "scale": P(None, dp, tp_axis, None)}
     cache_spec = {"k": kv_spec, "v": kv_spec, "pos": P()}
 
     step = jax.jit(shard_map(
@@ -1447,7 +1480,8 @@ def transformer_beam_search(params: Dict, cfg: TransformerConfig,
     return out, scores
 
 
-__all__ = ["init_decode_cache", "cache_leaves", "retention_features",
+__all__ = ["init_decode_cache", "cache_leaves", "cache_slots",
+           "retention_features",
            "transformer_decode_step",
            "transformer_prefill", "transformer_extend",
            "transformer_generate", "transformer_speculative_generate",

@@ -10,12 +10,22 @@ fp8_e4m3 quantized layouts ride along unchanged), with per-sequence
 page lists and LIFO alloc/free on admit/evict.
 
 The decode kernels never see pages.  ``gather`` materializes the
-active set's pages into a ``[L, B, view_tokens, Hkv, Dh]`` view — the
+active set's pages into a ``[L, B, Hkv, view_tokens, Dh]`` view — the
 exact shape ``transformer_decode_step`` already takes — and
 ``scatter_slots`` copies the one ring slot each step writes back into
-the owning page.  Both are pure data movement (no arithmetic), which
-is why pooled decode is BITWISE-equal to contiguous-cache decode: the
-step consumes identical bytes either way
+the owning page.  The layout is ``init_decode_cache``'s, head-major
+(models/decode.py's module text has why: the step's contractions read a
+layer's slice of the view where it lies), so a page is
+``[L, Hkv, page_tokens, Dh]`` and a row's pages are joined along the
+slot axis UNDER each kv head: the gathers swap the page and head axes
+before they merge page and offset, ``scatter_pages`` splits a prefilled
+ring the same way, and a slot is ``Hkv`` pieces of ``Dh`` numbers.
+The gathers run at admission; the write-back runs once a step and
+moves ``L x Hkv`` pieces a row, 0.14 to 0.30 ms at the benchmark's
+shapes (PERF.md, ``pool_scatter_share.*``), after the step and while
+the host fetches its logits.  All of it is pure data movement (no
+arithmetic), which is why pooled decode is BITWISE-equal to
+contiguous-cache decode: the step consumes identical bytes either way
 (tests/test_serve.py::test_pooled_decode_bitwise_equal).  The view is
 UPDATED IN PLACE: the server's step programs take it donated and write
 only the new slots into it (models/decode.py ``_layer_walk``), so a
@@ -45,7 +55,7 @@ import numpy as np
 from jax import lax
 
 from ..common.exceptions import HorovodTpuError, InvalidRequestError
-from ..models.decode import cache_leaves, init_decode_cache
+from ..models.decode import cache_leaves, cache_slots, init_decode_cache
 
 
 # -- jitted data-movement kernels -------------------------------------------
@@ -65,6 +75,12 @@ def _each(kv, f):
     return f(kv, False)
 
 
+def _leaf(kv, scale: bool):
+    """The array `_each` is visiting: a quantized dict's scale or
+    payload, or the plain array itself."""
+    return kv["scale" if scale else "q"] if isinstance(kv, dict) else kv
+
+
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _zero_pages_jit(pool_kv, idx):
     k, v = pool_kv
@@ -78,10 +94,16 @@ def _scatter_slots_jit(pool_kv, view_kv, pids, offs, rows, slots):
     vk, vv = view_kv
 
     def one(pool_c, view_c):
-        return _each(pool_c, lambda c, scale: c.at[:, pids, offs].set(
-            (view_c["scale"] if scale else
-             view_c["q"] if isinstance(view_c, dict) else
-             view_c)[:, rows, slots]))
+        def f(c, scale):
+            src = _leaf(view_c, scale)
+            # Layers and kv heads ride among the indices on both sides,
+            # L x Hkv x n pieces of Dh numbers: as window axes around the
+            # slot they make the v5e compiler transpose pool and view
+            # whole (models/decode.py `_cache_write_rows`).
+            ls = jnp.arange(c.shape[0])[:, None, None]
+            hs = jnp.arange(c.shape[2])[None, :, None]
+            return c.at[ls, pids, hs, offs].set(src[ls, rows, hs, slots])
+        return _each(pool_c, f)
 
     return one(k, vk), one(v, vv)
 
@@ -93,27 +115,27 @@ def _scatter_pages_jit(pool_kv, cache_kv, idx, n_pages):
 
     def one(pool_c, c):
         def f(pc, scale):
-            src = (c["scale"] if scale else
-                   c["q"] if isinstance(c, dict) else c)
-            src = src[:, 0].reshape(src.shape[0], n_pages, -1,
-                                    *src.shape[3:])
-            return pc.at[:, idx].set(src)
+            src = _leaf(c, scale)[:, 0]  # [L, Hkv, ring, ...]
+            src = src.reshape(*src.shape[:2], n_pages, -1, *src.shape[3:])
+            return pc.at[:, idx].set(jnp.swapaxes(src, 1, 2))
         return _each(pool_c, f)
 
     return one(k, ck), one(v, cv)
 
 
+def _join_pages(g):
+    """Rows' gathered pages [L, B, Vp, Hkv, pt, ...] as view rows
+    [L, B, Hkv, Vp * pt, ...]: a row's pages in order along the slot
+    axis, under each kv head."""
+    g = jnp.swapaxes(g, 2, 3)
+    return g.reshape(*g.shape[:3], -1, *g.shape[5:])
+
+
 @jax.jit
 def _gather_jit(pool_kv, idx):
     k, v = pool_kv
-
-    def one(pool_c):
-        def f(c, _s):
-            g = c[:, idx]                # [L, B, Vp, pt, ...]
-            return g.reshape(g.shape[0], g.shape[1], -1, *g.shape[4:])
-        return _each(pool_c, f)
-
-    return one(k), one(v)
+    return (_each(k, lambda c, _s: _join_pages(c[:, idx])),
+            _each(v, lambda c, _s: _join_pages(c[:, idx])))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -123,11 +145,8 @@ def _gather_rows_jit(view_kv, pool_kv, idx, rows):
 
     def one(view_c, pool_c):
         def f(vc, scale):
-            src = (pool_c["scale"] if scale else
-                   pool_c["q"] if isinstance(pool_c, dict) else pool_c)
-            g = src[:, idx]              # [L, n, Vp, pt, ...]
             return vc.at[:, rows].set(
-                g.reshape(g.shape[0], g.shape[1], -1, *g.shape[4:]))
+                _join_pages(_leaf(pool_c, scale)[:, idx]))
         return _each(view_c, f)
 
     return one(vk, k), one(vv, v)
@@ -144,7 +163,7 @@ class PagedKVPool:
 
     Storage layout: ``k``/``v`` are the plain decode-cache arrays with
     the BATCH axis reinterpreted as the PAGE axis —
-    ``[L, total_pages, page_tokens, Hkv, Dh]`` (quantized variants are
+    ``[L, total_pages, Hkv, page_tokens, Dh]`` (quantized variants are
     the same ``{"q", "scale"}`` dicts).  A sequence's logical ring of
     ``n`` tokens maps to ``ceil(n / page_tokens)`` pages; slot ``s``
     lives at ``(pages[s // page_tokens], s % page_tokens)``.
@@ -237,7 +256,7 @@ class PagedKVPool:
     def gather(self, seq_ids: Sequence[Optional[int]],
                view_pages: int) -> Tuple:
         """Materialize the active rows' pages as a contiguous decode
-        view ``[L, B, view_pages * page_tokens, Hkv, Dh]``.
+        view ``[L, B, Hkv, view_pages * page_tokens, Dh]``.
 
         ``seq_ids[b] is None`` marks an idle row; idle rows (and the
         tail of short page lists) index page 0 — never READ, because
@@ -311,8 +330,7 @@ class PagedKVPool:
         the admit-time bulk write."""
         pids = self.pages[seq_id]
         pt = self.page_tokens
-        ring = (cache_k["q"] if isinstance(cache_k, dict)
-                else cache_k).shape[2]
+        ring = cache_slots(cache_k)
         if ring != len(pids) * pt:
             raise InvalidRequestError(
                 f"prefill cache ring {ring} != page budget "

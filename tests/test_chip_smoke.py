@@ -55,6 +55,15 @@ def test_server_phase():
     assert rec["tokens_equal_to_generate"] == "16/16"
 
 
+def test_decode_layout_phase_reports_both_sizes():
+    cfg = chip_smoke.TransformerConfig(**TINY_LM)
+    rec = chip_smoke.phase_decode_layout(cfg, rows=4, slots=64, kv_heads=2)
+    # head-major: [L, rows, Hkv, slots, Dh]; a layer's slice in bf16
+    assert rec["view"] == [1, 4, 2, 64, 8]
+    assert rec["layer_k_slice_bytes"] == 4 * 2 * 64 * 8 * 2
+    assert rec["temp_bytes"] > 0        # the CPU's is reported, not held
+
+
 def test_kernels_phase_interpreted_on_the_cpu_mesh():
     assert pallas_kernels._interpret() is True
     # one flash case that takes every mask path at once

@@ -420,7 +420,7 @@ class TestRingCacheAndPrefill:
         tok = jnp.zeros((1,), jnp.int32)
         for _ in range(5):
             _, cache = transformer_decode_step(params, cache, tok, cfg)
-        assert int(cache["pos"]) == 5 > cache["k"].shape[2]
+        assert int(cache["pos"]) == 5 > cache["k"].shape[3]      # slots
 
     def test_windowed_generate_with_small_ring(self):
         cfg = _cfg(attn_window=4)
@@ -948,10 +948,11 @@ def _jaxpr_eqns(jaxpr):
             yield from _jaxpr_eqns(sub)
 
 
-def _walk_reference(params, cache, tokens, cfg, mode):
+def _walk_reference(params, cache, tokens, cfg, mode, decode_layer=None):
     """The walk as a plain per-layer loop: each layer's attention from
-    `_decode_layer` / `_prefill_layer` on THAT layer's slice of the
-    cache (a one-layer stack, index 0), the slices joined afterwards."""
+    `_decode_layer` / `_prefill_layer` (or `decode_layer`, of
+    `_decode_layer`'s signature) on THAT layer's slice of the cache (a
+    one-layer stack, index 0), the slices joined afterwards."""
     from horovod_tpu.models import decode as D
     from horovod_tpu.models.transformer import (
         _is_moe_layer, _mlp_block, _rmsnorm)
@@ -969,8 +970,8 @@ def _walk_reference(params, cache, tokens, cfg, mode):
         if mode == "prefill":
             x, cki, cvi = D._prefill_layer(lp, cki, cvi, 0, x, cfg)
         else:
-            x, cki, cvi = D._decode_layer(lp, cki, cvi, 0, x,
-                                          cache["pos"], cfg)
+            x, cki, cvi = (decode_layer or D._decode_layer)(
+                lp, cki, cvi, 0, x, cache["pos"], cfg)
         ks.append(cki)
         vs.append(cvi)
         if _is_moe_layer(cfg, i):
@@ -1064,7 +1065,9 @@ def test_layer_walk_in_place(mode, quantize, arch):
     for e in writes:                    # B x n_new vectors, no more
         upd = e.invars[-1 if e.primitive.name == "scatter" else 1].aval
         full = e.invars[0].aval.shape
-        assert upd.size == B * n_new * int(np.prod(full[3:])), (upd, full)
+        # full is [L, B, Hkv, S, (Dh)]: a vector is Hkv pieces of Dh
+        assert upd.size == B * n_new * full[2] * int(np.prod(full[4:])), \
+            (upd, full)
 
     # -- values: bitwise the per-layer reference; the argument is consumed --
     # Both sides are compiled to round every bf16 result (by default XLA
@@ -1089,3 +1092,106 @@ def test_layer_walk_in_place(mode, quantize, arch):
         lg_v, out_v = fn(params, warm([T0] * B), toks)
         np.testing.assert_array_equal(np.asarray(lg_s), np.asarray(lg_v))
         _leaves_equal((out_s["k"], out_s["v"]), (out_v["k"], out_v["v"]))
+
+
+# -- the head-major cache against a slot-major layer (PR 29) -----------------
+
+def _slot_major_layer(lp, ck, cv, i, x, pos, cfg):
+    """Layer `i`'s attention over a SLOT-MAJOR stacked cache, ck / cv
+    [L, B, S, Hkv, Dh] (scales [L, B, S, Hkv]): `_decode_layer` as it read
+    and wrote before the cache went head-major, kept here as the
+    reference the new layout is held to.  Same projections, rope,
+    quantisation, mask and operand types; only where a vector lies
+    differs."""
+    from horovod_tpu.models import decode as D
+    from horovod_tpu.models.transformer import _rmsnorm, _rope
+
+    dt, Dh = cfg.compute_dtype, cfg.d_head
+    quant = isinstance(ck, dict)
+    B, c = x.shape[:2]
+    S = (ck["q"] if quant else ck).shape[2]
+    h = _rmsnorm(lp["ln1"]["scale"], x)
+    q, k, v = (jnp.einsum("bod,dhk->bohk", h, lp[w].astype(dt))
+               for w in ("wq", "wk", "wv"))
+    Hkv = k.shape[2]
+    pos = jnp.asarray(pos)
+    rows = pos if pos.ndim else jnp.full((B,), pos)
+    positions = rows[:, None] + jnp.arange(c)[None, :]          # [B, c]
+    if pos.ndim:
+        rope = lambda a: D._rope_rows(a, positions, cfg.rope_theta)
+    else:
+        rope = lambda a: _rope(a, positions[0], cfg.rope_theta)
+    q, k = rope(q).astype(dt), rope(k).astype(dt)
+    at = (i, jnp.arange(B)[:, None], positions % S)
+
+    def put(cache, val):
+        if not quant:
+            return cache.at[at].set(val)
+        pay, scale = D._quant_vec(val, cache["q"].dtype)
+        return {"q": cache["q"].at[at].set(pay),
+                "scale": cache["scale"].at[at].set(scale)}
+
+    ck, cv = put(ck, k), put(cv, v)
+    lk, lv = (jax.tree_util.tree_map(lambda a: a[i], t) for t in (ck, cv))
+    qg = q.reshape(B, c, Hkv, -1, Dh).astype(jnp.float32)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg,
+                   (lk["q"] if quant else lk).astype(jnp.float32))
+    if quant:
+        s = s * lk["scale"].transpose(0, 2, 1)[:, :, None, None, :]
+    s = s / (Dh ** 0.5)
+    last = rows[:, None] + (c - 1)
+    abs_pos = last - ((last - jnp.arange(S)[None, :]) % S)      # [B, S]
+    valid = (abs_pos[:, None, :] >= 0) & \
+        (abs_pos[:, None, :] <= positions[:, :, None])
+    p = jax.nn.softmax(
+        jnp.where(valid[:, None, None, :, :], s, -1e30), axis=-1)
+    if quant:
+        p = p * lv["scale"].transpose(0, 2, 1)[:, :, None, None, :]
+    o = jnp.einsum("bhgqk,bkhd->bqhgd", p,
+                   (lv["q"] if quant else lv).astype(jnp.float32))
+    o = o.reshape(B, c, -1, Dh).astype(dt)
+    out = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(dt))
+    return x + out.astype(x.dtype), ck, cv
+
+
+@pytest.mark.parametrize("quantize", [None, "int8", "fp8_e4m3"],
+                         ids=["bf16", "int8", "fp8"])
+@pytest.mark.parametrize("pos_kind", ["scalar", "vector"])
+@pytest.mark.parametrize("mode", ["step", "chunk"])
+def test_head_major_cache_equals_slot_major_reference(mode, pos_kind,
+                                                      quantize):
+    """The cache's layout moves bytes and nothing else: a step or a chunk
+    over the head-major cache gives the logits, and (its slot and head
+    axes swapped back) the cache, of the slot-major layer kept above, bit
+    for bit.  Both sides compiled to round every bf16 result, as in
+    `test_layer_walk_in_place`."""
+    from horovod_tpu.models import decode as D
+
+    cfg = _cfg(n_kv_heads=2, compute_dtype=jnp.bfloat16, n_layers=3)
+    params = transformer_init(jax.random.PRNGKey(0), cfg)
+    B, S, T0 = 2, 12, 5
+    prompt = jax.random.randint(jax.random.PRNGKey(1), (B, T0), 0, 64)
+    cache = init_decode_cache(cfg, B, S, quantize=quantize)
+    _, cache = transformer_prefill(params, cache, prompt, cfg)
+    cache["pos"] = jnp.asarray(T0 if pos_kind == "scalar" else [T0, 3],
+                               jnp.int32)
+    if mode == "step":
+        fn = D._spec_step_fn(cfg)
+        toks = jax.random.randint(jax.random.PRNGKey(2), (B,), 0, 64)
+    else:
+        fn = D._spec_extend_fn(cfg)
+        toks = jax.random.randint(jax.random.PRNGKey(2), (B, 3), 0, 64)
+    swap = lambda t: jax.tree_util.tree_map(
+        lambda a: jnp.swapaxes(a, 2, 3), t)     # head- <-> slot-major
+    slot_major = {"k": swap(cache["k"]), "v": swap(cache["v"]),
+                  "pos": cache["pos"]}
+    exact = {"xla_allow_excess_precision": False}
+    ref_lg, ref_kv = jax.jit(lambda p, c, t: _walk_reference(
+        p, c, t, cfg, mode, decode_layer=_slot_major_layer)).lower(
+            params, slot_major, toks).compile(compiler_options=exact)(
+                params, slot_major, toks)
+    lg, out = fn.lower(params, cache, toks).compile(
+        compiler_options=exact)(params, cache, toks)
+    np.testing.assert_array_equal(np.asarray(lg), np.asarray(ref_lg))
+    _leaves_equal((swap(out["k"]), swap(out["v"])),
+                  (ref_kv["k"], ref_kv["v"]))

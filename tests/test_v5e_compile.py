@@ -1,0 +1,104 @@
+"""Programs of the serving path compiled for a DESCRIBED TPU v5e at the
+benchmark's real shapes, on this CPU-only machine: libtpu's compiler is
+installed, so what it would do with a program on the chip (which arrays
+it copies, how much it keeps in temporaries) is checked here at no chip
+time.  Nothing runs, so nothing here is a time or a result.
+
+All of it lives in this one file, and the topology is described inside a
+fixture: only one process may load libtpu, and only the worker that is
+handed this file does (a second file, or a call made while a module is
+imported, would make every other worker fail or skip).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from horovod_tpu.models import (
+    TransformerConfig,
+    init_decode_cache,
+    transformer_init,
+)
+
+# mistral7b_chat_steady's decode view (benchmark/traffic/chat_steady.json,
+# benchmark/configs/mistral-7b-serve.json): 32 rows x 3584 slots, pool of
+# 3200 pages of 16 tokens, at Mistral-7B's widths.  Two layers: the
+# compiler treats every layer of the loop alike, and compiles in 2 s.
+ROWS, SLOTS, PAGES, PAGE_TOKENS = 32, 3584, 3200, 16
+WIDTHS = dict(vocab_size=32000, d_model=4096, n_heads=32, d_head=128,
+              d_ff=14336, n_kv_heads=8, attn_window=4096, n_layers=2,
+              compute_dtype=jnp.bfloat16)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    mp = pytest.MonkeyPatch()
+    mp.setenv("TPU_LOG_DIR", "disabled")    # or the compiler logs to /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        mp.undo()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A program compiled for a described chip is written to the
+    # persistent cache but cannot be read back without the chip.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    sharding = SingleDeviceSharding(topo.devices[0])
+    yield lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+    mp.undo()
+
+
+def _leaf_bytes(leaf):
+    a = leaf["q"] if isinstance(leaf, dict) else leaf
+    return a.size // a.shape[0] * a.dtype.itemsize
+
+
+@pytest.mark.parametrize("quantize", [None, "int8"], ids=["bf16", "int8"])
+def test_decode_step_reads_the_view_where_it_lies(one_chip, quantize):
+    """The served step (vector `pos`): the program's temporaries stay
+    far under ONE layer's K slice of the view, so no layer's K or V is
+    copied out before its contraction (slot-major, PR 27: 235 MB of
+    them) and the per-row write does not make the compiler transpose
+    the cache (kv heads in the scatter's window: 1.9 GB)."""
+    from horovod_tpu.models.decode import _spec_step_fn
+
+    cfg = TransformerConfig(**WIDTHS)
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(cfg.compute_dtype),
+        transformer_init(jax.random.PRNGKey(0), cfg)))
+    cache = jax.eval_shape(
+        lambda: init_decode_cache(cfg, ROWS, SLOTS, quantize=quantize))
+    cache["pos"] = jax.ShapeDtypeStruct((ROWS,), jnp.int32)
+    tokens = jax.ShapeDtypeStruct((ROWS,), jnp.int32)
+    compiled = _spec_step_fn(cfg).lower(
+        one_chip(params), one_chip(cache), one_chip(tokens)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    k_slice = _leaf_bytes(cache["k"])
+    assert k_slice == ROWS * 8 * SLOTS * 128 * (1 if quantize else 2)
+    assert temp < k_slice // 8, (temp, k_slice)
+
+
+def test_pool_write_back_moves_slots_only(one_chip):
+    """`scatter_slots`, once a step: the slots' bytes and no transposed
+    copy of pool or view (layers or kv heads as window axes around the
+    slot: 3.6 GB of temporaries)."""
+    from horovod_tpu.serve import pool as P
+
+    cfg = TransformerConfig(**WIDTHS)
+    kv = lambda rows, slots: tuple(jax.eval_shape(
+        lambda: init_decode_cache(cfg, rows, slots))[n] for n in "kv")
+    idx = jax.ShapeDtypeStruct((ROWS,), jnp.int32)
+    compiled = P._scatter_slots_jit.lower(
+        one_chip(kv(PAGES, PAGE_TOKENS)), one_chip(kv(ROWS, SLOTS)),
+        *(one_chip(idx),) * 4).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
