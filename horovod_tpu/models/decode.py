@@ -74,7 +74,7 @@ the quantized layouts refuse the kind by name.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -90,6 +90,39 @@ from .transformer import (
     _rmsnorm,
     _rope,
 )
+
+
+class _Kind(NamedTuple):
+    """What `cfg.attn_kind` decides; the rest of this module is one code."""
+    leaves: Tuple[str, str]     # the cache's two stacked leaves, by name
+    #: a slot a token?  If not, `max_len` sizes nothing, and what
+    #: quantizes, shards, snapshots or rolls back refuses (`_needs_slots`)
+    slots: bool
+    empty: Callable             # (cfg, batch, max_len, quantize) -> leaves
+    step: Callable              # (lp, ca, cb, i, x, pos, cfg, tp_axis)
+    prefill: Callable           # (lp, ca, cb, i, x, cfg, tp_axis, chunk)
+
+
+def _kind(cfg: TransformerConfig) -> _Kind:
+    """`cfg`'s record: the one place this module reads `cfg.attn_kind`.
+    A new kind is one more entry (its layers under `_layer_walk`'s
+    contract) and, to be served, one cache class in serve/pool.py.  Made
+    when asked for, so that a layer a test has replaced in this module
+    is the one the next program traces."""
+    kinds = {
+        "softmax": _Kind(
+            ("k", "v"), True, _empty_ring, _decode_layer,
+            # a ring is filled in one pass: no chunks
+            lambda *a, chunk, **kw: _prefill_layer(*a, **kw)),
+        "retention": _Kind(
+            ("s", "z"), False, _empty_state, _retention_decode_layer,
+            _retention_prefill_layer),
+    }
+    if cfg.attn_kind not in kinds:
+        raise InvalidRequestError(
+            f"attn_kind must be one of {sorted(kinds)}, got "
+            f"{cfg.attn_kind!r}")
+    return kinds[cfg.attn_kind]
 
 
 def init_decode_cache(cfg: TransformerConfig, batch: int,
@@ -121,14 +154,12 @@ def init_decode_cache(cfg: TransformerConfig, batch: int,
     if quantize not in (None, "int8", "fp8_e4m3"):
         raise ValueError(f"quantize must be None, 'int8', or "
                          f"'fp8_e4m3', got {quantize!r}")
-    if cfg.attn_kind == "retention":
-        # A fixed state a row: `max_len` sizes nothing.
-        _refuse_retention(cfg, "quantize", quantize is not None)
-        shape = (cfg.n_layers, batch, cfg.kv_heads,
-                 retention_features(cfg.d_head))
-        return {"s": jnp.zeros(shape + (cfg.d_head,), cfg.state_dtype),
-                "z": jnp.zeros(shape, jnp.float32),
-                "pos": jnp.zeros((), jnp.int32)}
+    _needs_slots(cfg, "quantize", quantize is not None)
+    return {**_kind(cfg).empty(cfg, batch, max_len, quantize),
+            "pos": jnp.zeros((), jnp.int32)}
+
+
+def _empty_ring(cfg, batch, max_len, quantize) -> Dict:
     # Head-major: a layer's slice is what the contractions of
     # `_decode_layer` read, with no copy between (module text).
     shape = (cfg.n_layers, batch, cfg.kv_heads, max_len, cfg.d_head)
@@ -136,13 +167,17 @@ def init_decode_cache(cfg: TransformerConfig, batch: int,
         qdt = jnp.int8 if quantize == "int8" else jnp.float8_e4m3fn
         kv = lambda: {"q": jnp.zeros(shape, qdt),
                       "scale": jnp.zeros(shape[:-1], jnp.float32)}
-        return {"k": kv(), "v": kv(),
-                "pos": jnp.zeros((), jnp.int32)}
-    return {
-        "k": jnp.zeros(shape, cfg.compute_dtype),
-        "v": jnp.zeros(shape, cfg.compute_dtype),
-        "pos": jnp.zeros((), jnp.int32),
-    }
+        return {"k": kv(), "v": kv()}
+    return {"k": jnp.zeros(shape, cfg.compute_dtype),
+            "v": jnp.zeros(shape, cfg.compute_dtype)}
+
+
+def _empty_state(cfg, batch, max_len, quantize) -> Dict:
+    # A fixed state a row: `max_len` sizes nothing.
+    shape = (cfg.n_layers, batch, cfg.kv_heads,
+             retention_features(cfg.d_head))
+    return {"s": jnp.zeros(shape + (cfg.d_head,), cfg.state_dtype),
+            "z": jnp.zeros(shape, jnp.float32)}
 
 
 def _quant_vec(x, qdt):
@@ -373,16 +408,16 @@ def retention_features(d_head: int) -> int:
 def cache_leaves(cfg: TransformerConfig) -> Tuple[str, str]:
     """The cache's two stacked leaves by name: keys and values, or a
     retention model's states and their normalisers."""
-    return ("s", "z") if cfg.attn_kind == "retention" else ("k", "v")
+    return _kind(cfg).leaves
 
 
-def _refuse_retention(cfg: TransformerConfig, what: str,
-                      asked: bool = True) -> None:
-    if asked and cfg.attn_kind == "retention":
+def _needs_slots(cfg: TransformerConfig, what: str,
+                 asked: bool = True) -> None:
+    if asked and not _kind(cfg).slots:
         raise InvalidRequestError(
-            f"{what} is not supported for attn_kind='retention': the "
-            "cache is one state a row, with no slots to quantize, shard, "
-            "snapshot or roll back")
+            f"{what} is not supported for attn_kind={cfg.attn_kind!r}: "
+            "the cache is one state a row, with no slots to quantize, "
+            "shard, snapshot or roll back")
 
 
 @functools.lru_cache(maxsize=None)
@@ -672,13 +707,12 @@ def transformer_decode_step(params: Dict, cache: Dict, tokens,
     dt = cfg.compute_dtype
     x = params["embed"][tokens].astype(dt)[:, None, :]    # [B,1,D]
     pos = cache["pos"]
-    ka, kb = cache_leaves(cfg)
-    layer = (_retention_decode_layer if cfg.attn_kind == "retention"
-             else _decode_layer)
+    kind = _kind(cfg)
+    ka, kb = kind.leaves
 
     x, ck, cv = _layer_walk(
         params, cache[ka], cache[kb], x,
-        functools.partial(layer, pos=pos, cfg=cfg, tp_axis=tp_axis),
+        functools.partial(kind.step, pos=pos, cfg=cfg, tp_axis=tp_axis),
         cfg, tp_axis)
     x = _rmsnorm(params["final_norm"]["scale"], x)
     logits = jnp.einsum("bod,vd->bov", x.astype(dt),
@@ -706,8 +740,8 @@ def transformer_extend(params: Dict, cache: Dict, tokens,
     Use `transformer_decode_step` past max_len instead (its single
     query is exactly the anchor, so no such skew exists).
     """
-    _refuse_retention(cfg, "transformer_extend (a chunk of tokens over "
-                           "a cache; speculative verify)")
+    _needs_slots(cfg, "transformer_extend (a chunk of tokens over "
+                      "a cache; speculative verify)")
     dt = cfg.compute_dtype
     B, c = tokens.shape
     S = cache_slots(cache["k"])
@@ -788,8 +822,8 @@ def transformer_speculative_generate(
     """
     B, T0 = prompt.shape
     for c in (cfg, draft_cfg):
-        _refuse_retention(c, "speculative decoding (rolling back a round "
-                             "needs snapshots of the state)")
+        _needs_slots(c, "speculative decoding (rolling back a round "
+                        "needs snapshots of the state)")
     if cfg.attn_window or draft_cfg.attn_window:
         raise ValueError(
             "speculative decoding does not support attn_window configs")
@@ -1075,16 +1109,11 @@ def transformer_prefill(params: Dict, cache: Dict, prompt,
         raise InvalidRequestError(
             f"prompt must be non-empty, got shape {(B, T0)} (an empty "
             "prefill would silently leave the cache desynced)")
-    ka, kb = cache_leaves(cfg)
-    if cfg.attn_kind == "retention":
-        # a state holds any length
-        layer = functools.partial(_retention_prefill_layer, chunk=chunk)
-    else:
-        layer = _prefill_layer
-        S = cache_slots(cache["k"])
-        if T0 > S:
-            raise InvalidRequestError(
-                f"prompt length {T0} > cache max_len {S}")
+    kind = _kind(cfg)
+    ka, kb = kind.leaves
+    if kind.slots and T0 > (S := cache_slots(cache[ka])):
+        raise InvalidRequestError(
+            f"prompt length {T0} > cache max_len {S}")
     # Prefill writes the prompt at slot 0; a warm cache (pos != 0)
     # would silently desync slot <-> absolute-position bookkeeping.
     # Enforce eagerly whenever pos is concrete (inside jit pos is a
@@ -1097,7 +1126,8 @@ def transformer_prefill(params: Dict, cache: Dict, prompt,
     x = params["embed"][prompt].astype(dt)                # [B,T0,D]
     x, ck, cv = _layer_walk(
         params, cache[ka], cache[kb], x,
-        functools.partial(layer, cfg=cfg, tp_axis=tp_axis),
+        functools.partial(kind.prefill, cfg=cfg, tp_axis=tp_axis,
+                          chunk=chunk),
         cfg, tp_axis)
     x = _rmsnorm(params["final_norm"]["scale"], x[:, -1:])
     logits = jnp.einsum("bod,vd->bov", x.astype(dt),
@@ -1165,7 +1195,7 @@ def transformer_generate(params: Dict, cfg: TransformerConfig, prompt,
         raise InvalidRequestError(
             f"max_new_tokens must be >= 1, got {max_new_tokens} (a "
             "zero-length scan would silently return an empty batch)")
-    if cfg.attn_kind == "retention":
+    if not _kind(cfg).slots:
         max_len = 1                  # a state a row: no ring to size
     else:
         max_len = _resolve_max_len(cfg, T0, max_new_tokens, max_len)
@@ -1278,8 +1308,8 @@ def make_decode_step(mesh, cfg: TransformerConfig, quantize=None):
 
     from .transformer import transformer_pspecs
 
-    _refuse_retention(cfg, "make_decode_step (dp/tp sharding of the "
-                           "state)")
+    _needs_slots(cfg, "make_decode_step (dp/tp sharding of the "
+                      "state)")
     axes = {a: mesh.shape.get(a, 1) > 1 for a in mesh.axis_names}
     if axes.get("ep") and cfg.moe_every:
         raise NotImplementedError(
@@ -1376,8 +1406,8 @@ def transformer_beam_search(params: Dict, cfg: TransformerConfig,
     selects the top-W of the W*V continuations per batch and GATHERS
     the parent beams' cache rows, the standard reorder.  One lax.scan.
     """
-    _refuse_retention(cfg, "beam search (reordering beams copies "
-                           "their states)")
+    _needs_slots(cfg, "beam search (reordering beams copies "
+                      "their states)")
     B, T0 = prompt.shape
     W = int(beam_width)
     if W < 1:

@@ -1,7 +1,7 @@
 """Continuous-batching inference serving over the decode stack.
 
 Layout (docs/SERVING.md):
-  - pool.py      paged KV-cache pool (PagedKVPool, PoolExhaustedError)
+  - pool.py      the decode caches: DecodeCache, PagedKVPool, StateSlots
   - scheduler.py per-step admit/evict scheduler (ContinuousScheduler)
   - slo.py       SLO-aware speculative-decode toggling (SloController)
   - server.py    the decode loop tying them together (InferenceServer)

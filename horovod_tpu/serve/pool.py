@@ -1,13 +1,16 @@
-"""Paged KV-cache pool: the serving engine's memory allocator.
+"""The serving engine's caches: what `InferenceServer` asks of one
+(`DecodeCache`), the two that answer it (`PagedKVPool` for keys and
+values, `StateSlots` for a retention model's state a row), and
+`make_cache`, which picks one from the model's configuration.
 
 A contiguous decode cache ties a sequence's KV bytes to its batch row
 for the whole generation — finished sequences hold pages until the
-batch drains.  The pool breaks that coupling (the vLLM PagedAttention
-idea, applied to this repo's ring-decode cache): one fixed-size page
-table per model, page = ``page_tokens`` tokens x layers x kv-heads,
-carved out of the SAME ``init_decode_cache`` storage (so the int8 /
-fp8_e4m3 quantized layouts ride along unchanged), with per-sequence
-page lists and LIFO alloc/free on admit/evict.
+batch drains.  The paged pool breaks that coupling (the vLLM PagedAttention idea, applied to this repo's
+ring-decode cache): one fixed-size page table per model, page =
+``page_tokens`` tokens x layers x kv-heads, carved out of the SAME
+``init_decode_cache`` storage (so the int8 / fp8_e4m3 quantized layouts
+ride along unchanged), with per-sequence page lists and LIFO alloc/free
+on admit/evict.
 
 The decode kernels never see pages.  ``gather`` materializes the
 active set's pages into a ``[L, B, Hkv, view_tokens, Dh]`` view — the
@@ -26,22 +29,12 @@ shapes (PERF.md, ``pool_scatter_share.*``), after the step and while
 the host fetches its logits.  All of it is pure data movement (no
 arithmetic), which is why pooled decode is BITWISE-equal to
 contiguous-cache decode: the step consumes identical bytes either way
-(tests/test_serve.py::test_pooled_decode_bitwise_equal).  The view is
-UPDATED IN PLACE: the server's step programs take it donated and write
-only the new slots into it (models/decode.py ``_layer_walk``), so a
-server holds the pool and ONE view, and whoever passes a view to a
-step or to ``gather_rows`` rebinds the result and drops the argument.
+(tests/test_serve.py::test_pooled_decode_bitwise_equal).
 
 Amortization contract (see docs/SERVING.md): the view is rebuilt only
 on MEMBERSHIP change (admit/evict); steady-state steps pay one
 written-slot scatter per active row.  The pool stays the source of
 truth, so replica handoff and bitwise replay need no view state.
-
-The page-table bookkeeping (free stack, page lists) is host-side
-Python; the data movement itself runs as small jitted kernels (one
-compiled program per shape signature, pool buffers donated) because
-op-by-op eager dispatch of the per-step scatter dominated the serving
-step on small models.
 """
 
 from __future__ import annotations
@@ -55,13 +48,17 @@ import numpy as np
 from jax import lax
 
 from ..common.exceptions import HorovodTpuError, InvalidRequestError
+from ..metrics import catalog as _met
 from ..models.decode import cache_leaves, cache_slots, init_decode_cache
+from ..utils.timeline import span
 
 
 # -- jitted data-movement kernels -------------------------------------------
-# Each is ONE compiled program per shape signature (the eager op-by-op
-# versions cost 4-8 dispatches per step, which dominated the serving
-# step on small models).  Pool buffers are donated: the caller always
+# The page-table bookkeeping (free stack, page lists) is host-side
+# Python; the data movement is ONE compiled program per shape signature
+# (the eager op-by-op versions cost 4-8 dispatches per step, which
+# dominated the serving step on small models).  Pool buffers are
+# donated: the caller always
 # rebinds self.k/self.v to the result, and serving pools are the
 # biggest buffers on the chip — double-buffering them per step would
 # halve the page budget.
@@ -152,13 +149,56 @@ def _gather_rows_jit(view_kv, pool_kv, idx, rows):
     return one(vk, k), one(vv, v)
 
 
+class DecodeCache:
+    """What `InferenceServer` asks of a model's cache, all of it:
+
+      - ``pages_needed(n_tokens)``, ``can_board(n_tokens)``: what such a
+        request takes, and whether it may board now;
+      - ``board(req_id, row, n_tokens, params, prompt, prefill)``: run
+        the prefill program and leave the row ready for the next step;
+        returns the prompt's last logits, not waited for;
+      - ``release(req_id, row)``; ``refresh()`` the view before a step;
+      - ``lend(pos)`` / ``take_back(cache)``: the ``{leaf, leaf, "pos"}``
+        dict a step program takes, and what it returned;
+      - ``write_through(rows, positions)``: the view's `rows` were
+        stepped at ``positions[row]``; carry that to where it is kept;
+      - ``utilization()``, ``set_gauges()``, ``state_bytes``,
+        ``installs``, and ``on_event``, which a cache with pages calls
+        with (event, req_id, n_pages, pages_free).
+
+    The step programs take the view donated and update it in place
+    (models/decode.py ``_layer_walk``), so a cache holds ONE view and
+    owns it: nobody else keeps an array that a step has consumed.
+    """
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self.leaves = cache_leaves(cfg)
+        #: the two stacked leaves; None while lent, and before a paged
+        #: cache's first gather
+        self.view: Optional[Tuple] = None
+        self.on_event: Optional[Callable] = None
+        self.state_bytes = self.installs = 0
+
+    def lend(self, pos) -> Dict:
+        (ka, a), (kb, b) = zip(self.leaves, self.view)
+        self.view = None
+        return {ka: a, kb: b, "pos": jnp.asarray(pos, jnp.int32)}
+
+    def take_back(self, cache: Dict) -> None:
+        self.view = tuple(cache[n] for n in self.leaves)
+
+    def set_gauges(self) -> None:
+        _met.serve_state_bytes.set(self.state_bytes)
+
+
 class PoolExhaustedError(HorovodTpuError):
     """Admission asked for more KV pages than the pool has free.  The
     scheduler treats this as back-pressure (the request waits in the
     queue), not as a crash."""
 
 
-class PagedKVPool:
+class PagedKVPool(DecodeCache):
     """Fixed-size page table over ``init_decode_cache`` storage.
 
     Storage layout: ``k``/``v`` are the plain decode-cache arrays with
@@ -166,11 +206,15 @@ class PagedKVPool:
     ``[L, total_pages, Hkv, page_tokens, Dh]`` (quantized variants are
     the same ``{"q", "scale"}`` dicts).  A sequence's logical ring of
     ``n`` tokens maps to ``ceil(n / page_tokens)`` pages; slot ``s``
-    lives at ``(pages[s // page_tokens], s % page_tokens)``.
+    lives at ``(pages[s // page_tokens], s % page_tokens)``.  `rows` and
+    `view_pages` size the view `DecodeCache`'s methods keep; a pool
+    driven by ``alloc`` / ``gather`` alone needs neither.
     """
 
     def __init__(self, cfg, total_pages: int, page_tokens: int,
-                 quantize: Optional[str] = None):
+                 quantize: Optional[str] = None, rows: int = 0,
+                 view_pages: int = 0):
+        super().__init__(cfg)
         if total_pages < 1:
             raise InvalidRequestError(
                 f"total_pages must be >= 1, got {total_pages}")
@@ -181,7 +225,6 @@ class PagedKVPool:
                                   quantize=quantize)
         self.k = store["k"]
         self.v = store["v"]
-        self.cfg = cfg
         self.total_pages = total_pages
         self.page_tokens = page_tokens
         self.quantize = quantize
@@ -189,11 +232,44 @@ class PagedKVPool:
         # 0, 1, 2, ... — deterministic reuse order for the tests.
         self._free: List[int] = list(range(total_pages - 1, -1, -1))
         self.pages: Dict[int, List[int]] = {}
-        #: Optional observer called after every alloc/free with
-        #: (event, seq_id, n_pages, pages_free) — the server wires this
-        #: to the flight recorder.  Observational only.
-        self.on_event: Optional[Callable[[str, int, int, int],
-                                         None]] = None
+        self.view_pages = view_pages
+        self._row_seq: List[Optional[int]] = [None] * rows
+        self._boarded: Dict[int, int] = {}   # row -> req_id, to refresh
+
+    # -- the server's contract (DecodeCache) -----------------------------
+
+    def board(self, req_id, row, n_tokens, params, prompt, prefill):
+        pids = self.alloc(req_id, n_tokens)
+        scratch = init_decode_cache(
+            self.cfg, 1, len(pids) * self.page_tokens, self.quantize)
+        lg, scratch = prefill(params, scratch, jnp.asarray(prompt[None]))
+        self.scatter_pages(req_id, scratch["k"], scratch["v"])
+        self._row_seq[row] = self._boarded[row] = req_id
+        return lg
+
+    def release(self, req_id, row) -> None:
+        self.free(req_id)
+        self._row_seq[row] = None
+        self._boarded.pop(row, None)
+
+    def refresh(self) -> None:
+        """A full gather the first time, then only the rows boarded
+        since (evicted rows need none — see `gather_rows`)."""
+        if self.view is None:
+            self.view = self.gather(self._row_seq, self.view_pages)
+        elif self._boarded:
+            self.view = self.gather_rows(
+                *self.view, sorted(self._boarded.items()), self.view_pages)
+        self._boarded.clear()
+
+    def write_through(self, rows, positions) -> None:
+        ring = self.view_pages * self.page_tokens
+        self.scatter_slots(*self.view, [self._row_seq[r] for r in rows],
+                           rows, [int(positions[r]) % ring for r in rows])
+
+    def set_gauges(self) -> None:
+        super().set_gauges()
+        _met.serve_pool_pages_free.set(self.pages_free())
 
     # -- accounting ----------------------------------------------------
 
@@ -206,7 +282,7 @@ class PagedKVPool:
     def utilization(self) -> float:
         return 1.0 - len(self._free) / self.total_pages
 
-    def can_alloc(self, n_tokens: int) -> bool:
+    def can_board(self, n_tokens: int) -> bool:
         return self.pages_needed(n_tokens) <= len(self._free)
 
     # -- alloc / free ---------------------------------------------------
@@ -227,7 +303,8 @@ class PagedKVPool:
                 f"need {need} pages for {n_tokens} tokens, only "
                 f"{len(self._free)}/{self.total_pages} free")
         pids = [self._free.pop() for _ in range(need)]
-        self._zero_pages(pids)
+        self.k, self.v = _zero_pages_jit((self.k, self.v),
+                                         jnp.asarray(pids, jnp.int32))
         self.pages[seq_id] = pids
         if self.on_event is not None:
             self.on_event("alloc", seq_id, len(pids), len(self._free))
@@ -247,10 +324,6 @@ class PagedKVPool:
             self.on_event("free", seq_id, len(pids), len(self._free))
         return pids
 
-    def _zero_pages(self, pids: Sequence[int]) -> None:
-        idx = jnp.asarray(list(pids), jnp.int32)
-        self.k, self.v = _zero_pages_jit((self.k, self.v), idx)
-
     # -- view gather / scatter -----------------------------------------
 
     def gather(self, seq_ids: Sequence[Optional[int]],
@@ -263,6 +336,10 @@ class PagedKVPool:
         the ring's absolute-position mask hides slots past each row's
         ``pos``, and never WRITTEN BACK, because ``scatter_slots`` only
         runs over active rows."""
+        return _gather_jit((self.k, self.v),
+                           self._page_table(seq_ids, view_pages))
+
+    def _page_table(self, seq_ids, view_pages: int) -> jax.Array:
         idx = np.zeros((len(seq_ids), view_pages), np.int32)
         for b, sid in enumerate(seq_ids):
             if sid is None:
@@ -273,7 +350,7 @@ class PagedKVPool:
                     f"sequence {sid} holds {len(pids)} pages > view "
                     f"capacity {view_pages}")
             idx[b, :len(pids)] = pids
-        return _gather_jit((self.k, self.v), jnp.asarray(idx))
+        return jnp.asarray(idx)
 
     def gather_rows(self, view_k, view_v,
                     row_sids: Sequence[Tuple[int, int]],
@@ -286,28 +363,11 @@ class PagedKVPool:
         not a full pool gather per membership change."""
         if not row_sids:
             return view_k, view_v
-        idx = np.zeros((len(row_sids), view_pages), np.int32)
-        rows = []
-        for i, (row, sid) in enumerate(row_sids):
-            rows.append(row)
-            pids = self.pages[sid]
-            if len(pids) > view_pages:
-                raise InvalidRequestError(
-                    f"sequence {sid} holds {len(pids)} pages > view "
-                    f"capacity {view_pages}")
-            idx[i, :len(pids)] = pids
+        rows, sids = zip(*row_sids)
         return _gather_rows_jit(
-            (view_k, view_v), (self.k, self.v), jnp.asarray(idx),
+            (view_k, view_v), (self.k, self.v),
+            self._page_table(sids, view_pages),
             jnp.asarray(rows, jnp.int32))
-
-    def _slot_coords(self, seq_ids: Sequence[int],
-                     slots: Sequence[int]) -> Tuple[jax.Array, jax.Array]:
-        pt = self.page_tokens
-        pids, offs = [], []
-        for sid, s in zip(seq_ids, slots):
-            pids.append(self.pages[sid][s // pt])
-            offs.append(s % pt)
-        return jnp.asarray(pids, jnp.int32), jnp.asarray(offs, jnp.int32)
 
     def scatter_slots(self, view_k, view_v, seq_ids: Sequence[int],
                       rows: Sequence[int],
@@ -318,11 +378,12 @@ class PagedKVPool:
         quantized payload and its scale move together untouched."""
         if not seq_ids:
             return
-        pids, offs = self._slot_coords(seq_ids, slots)
+        pt = self.page_tokens
+        pids = [self.pages[sid][s // pt] for sid, s in zip(seq_ids, slots)]
         self.k, self.v = _scatter_slots_jit(
-            (self.k, self.v), (view_k, view_v), pids, offs,
-            jnp.asarray(list(rows), jnp.int32),
-            jnp.asarray(list(slots), jnp.int32))
+            (self.k, self.v), (view_k, view_v),
+            *(jnp.asarray(list(a), jnp.int32) for a in (
+                pids, [s % pt for s in slots], rows, slots)))
 
     def scatter_pages(self, seq_id: int, cache_k, cache_v) -> None:
         """Install a freshly prefilled contiguous cache (batch 1, ring
@@ -349,49 +410,72 @@ def _state_install(view, state, row):
         for v, s in zip(view, state))
 
 
-class StateSlots:
-    """The cache of a retention model (`cfg.attn_kind == "retention"`,
-    models/decode.py): one fixed state a row, whatever the context
-    length, held ONCE, in the decode view the step programs take donated.
-    It is no pool: there are no pages to count, allocate or free, nothing
-    to gather into the view and nothing to scatter back out of it.  What
+class StateSlots(DecodeCache):
+    """The cache of a retention model (models/decode.py): one fixed state
+    a row, whatever the context length, held ONCE, in the view.  It is no
+    pool: there are no pages to count, allocate or free, nothing to
+    gather into the view and nothing to scatter back out of it.  What
     admission needs is a free row, and the scheduler counts rows
-    (`rows_held` is its count, so that `utilization` answers from the one
-    place that knows).  A prefill's final state is written into its row's
-    slot by `install`, whole, so a row reused after another request
-    carries nothing over."""
+    (`rows_held` is its count, so that `utilization` answers from the
+    one place that knows).  A prefill's final state is written into its
+    row's slot whole, so a row reused after another request carries
+    nothing over.  What needs a slot a token is refused here, and the
+    free-pages gauge is not written (0 there reads as a stall)."""
 
-    def __init__(self, cfg, rows: int, rows_held: Callable[[], int]):
-        self.cfg = cfg
+    def __init__(self, cfg, rows: int, rows_held: Callable[[], int],
+                 quantize: Optional[str] = None, speculative: bool = False):
+        for what, asked in (
+                ("quantize", quantize is not None),
+                ("draft_params (speculative serving: the verify pass "
+                 "needs snapshots of the state)", speculative)):
+            if asked:
+                raise InvalidRequestError(
+                    f"{what} is not supported for a retention model "
+                    "(attn_kind='retention')")
+        super().__init__(cfg)
         self.rows = rows
         self.rows_held = rows_held
-        slot = jax.eval_shape(self.scratch)
-        #: bytes of one row's state and normaliser, and of all rows'
-        self.row_bytes = sum(slot[n].size * slot[n].dtype.itemsize
-                             for n in cache_leaves(cfg))
-        self.state_bytes = rows * self.row_bytes
+        view = init_decode_cache(cfg, rows, 1)
+        self.view = tuple(view[n] for n in self.leaves)
+        #: bytes of all rows' states and normalisers, and of one row's
+        self.state_bytes = sum(a.nbytes for a in self.view)
+        self.row_bytes = self.state_bytes // rows
 
-    def new_view(self) -> Tuple:
-        """An empty view's two leaves, a slot a row.  The server owns
-        them: it rebinds what its programs and `install` return."""
-        view = init_decode_cache(self.cfg, self.rows, 1)
-        return tuple(view[n] for n in cache_leaves(self.cfg))
+    def pages_needed(self, n_tokens: int) -> int:
+        return 0
+
+    def can_board(self, n_tokens: int) -> bool:
+        return True     # the scheduler asks only while it has a free row
+
+    def board(self, req_id, row, n_tokens, params, prompt, prefill):
+        lg, scratch = prefill(params, init_decode_cache(self.cfg, 1, 1),
+                              jnp.asarray(prompt[None]))
+        with span("state_install", "serve",
+                  {"req": req_id, "row": row, "bytes": self.row_bytes}):
+            self.view = _state_install(
+                self.view, tuple(scratch[n] for n in self.leaves),
+                jnp.int32(row))
+        self.installs += 1
+        return lg
+
+    # the row given back is all there is, and the view the only copy
+    release = refresh = write_through = lambda self, *a: None
 
     def utilization(self) -> float:
         """Rows held over rows: what the pool's page share is for a
         paged model (the autoscaler's signal)."""
         return self.rows_held() / self.rows
 
-    def scratch(self) -> Dict:
-        """An empty batch-1 cache for one prefill to fill."""
-        return init_decode_cache(self.cfg, 1, 1)
 
-    def install(self, view: Tuple, scratch: Dict, row: int) -> Tuple:
-        """`view` with slot `row` holding `scratch`'s state; consumes
-        `view` (donated), so rebind the result."""
-        return _state_install(
-            view, tuple(scratch[n] for n in cache_leaves(self.cfg)),
-            jnp.int32(row))
+def make_cache(cfg, *, rows, view_pages, page_tokens, pool_pages,
+               quantize, speculative, rows_held) -> DecodeCache:
+    """The `DecodeCache` of a model of this configuration: the one place
+    under serve/ that knows which kind of attention has which cache."""
+    if cfg.attn_kind == "retention":
+        return StateSlots(cfg, rows, rows_held, quantize, speculative)
+    return PagedKVPool(cfg, pool_pages, page_tokens, quantize, rows,
+                       view_pages)
 
 
-__all__ = ["PagedKVPool", "PoolExhaustedError", "StateSlots"]
+__all__ = ["DecodeCache", "PagedKVPool", "PoolExhaustedError",
+           "StateSlots", "make_cache"]

@@ -1,38 +1,30 @@
-"""Continuous-batching inference server over the paged KV pool.
+"""Continuous-batching inference server over a model's decode cache.
 
 One compiled decode step of fixed ``max_batch`` rows serves every
 in-flight sequence; admission/eviction happens BETWEEN steps (the
-scheduler), and sequence KV state lives in the pool (pool.py).  The
-decode kernels run UNCHANGED — the only model-side addition is the
+scheduler), and sequence state lives in the cache (pool.py: pages, or
+a state a row), which the server knows as a ``DecodeCache`` and no
+closer, the draft's like the target's.  The decode kernels run UNCHANGED — the only model-side addition is the
 vector-``pos`` path in ``models/decode.py``, because continuously
 batched rows sit at different depths of the same step.
 
-Step anatomy (``step()``):
+Step anatomy (``step()``; in brackets what a paged cache does):
 
-  1. admit   — queued requests board free rows; prefill-on-admit runs
-               ``transformer_prefill`` into a scratch cache sized
-               exactly to the request's page budget, then bulk-writes
-               the pages (``scatter_pages``).
+  1. admit   — queued requests ``board`` free rows: prefill-on-admit
+               runs ``transformer_prefill`` into a scratch cache [of the
+               request's page budget, then bulk-written into its pages].
   2. emit    — each active row's next token is decided HOST-side from
                its pending logits (greedy serving); finished rows
-               (max_new / EOS) evict and free their pages BEFORE any
-               device work, so the last token costs no decode step.
-  3. gather  — only if membership changed: rebuild the pooled view.
+               (max_new / EOS) evict and ``release`` their row BEFORE
+               any device work, so the last token costs no decode step.
+  3. refresh — [only if membership changed: rebuild the pooled view.]
   4. decode  — one vector-pos ``transformer_decode_step`` (plain), or
                one speculative round (draft chain + chunked verify)
                when the SLO controller has flipped speculation on.
                The programs CONSUME the view (donated, updated in
-               place): ``view_k`` / ``view_v`` are rebound to what
-               they return, and the arrays passed in are gone.
-  5. scatter — copy each active row's written ring slot(s) back into
-               its pages; the pool stays the source of truth.
-
-A retention model (``cfg.attn_kind == "retention"``) has no pages: its
-cache is one fixed state a row, held once, in the view (``StateSlots``,
-pool.py).  Admission then needs a free row and nothing else, the
-prefill's final state is written into the row's slot of the view
-(``state_install``, inside ``admit``), and steps 3 and 5 have nothing to
-copy.  Everything else of the step is the same code.
+               place): the cache lends it and takes back the result.
+  5. write through — [copy each active row's written ring slot(s)
+               into its pages; the pool stays the source of truth.]
 
 Speculative rounds keep the greedy target chain EXACT: every decided
 token is the argmax of target logits computed over a correct prefix
@@ -70,17 +62,12 @@ import numpy as np
 from ..common import util
 from ..common.exceptions import InvalidRequestError
 from ..metrics import catalog as _met
-from ..models.decode import (
-    _spec_extend_fn,
-    _spec_step_fn,
-    cache_leaves,
-    init_decode_cache,
-    transformer_prefill,
-)
+from ..models.decode import (_spec_extend_fn, _spec_step_fn,
+                             transformer_prefill)
 from ..utils import autotune
 from ..utils.timeline import get_timeline, span
 from .flightrec import FlightRecorder
-from .pool import PagedKVPool, PoolExhaustedError, StateSlots
+from .pool import PoolExhaustedError, make_cache
 from .scheduler import ActiveSeq, ContinuousScheduler, Request
 from .slo import SloController
 
@@ -88,7 +75,7 @@ from .slo import SloController
 @functools.lru_cache(maxsize=None)
 def _prefill_fn(cfg):
     # Consumes the scratch cache like the step programs consume the
-    # view (models/decode.py): `_prefill_into` makes it and rebinds it.
+    # view (models/decode.py): a cache's `board` makes it and rebinds it.
     return jax.jit(lambda p, c, t: transformer_prefill(p, c, t, cfg),
                    donate_argnums=(1,))
 
@@ -137,38 +124,28 @@ class InferenceServer:
             raise InvalidRequestError(
                 "speculative serving does not support attn_window "
                 "configs (chunked verify over a rolling ring)")
-        self.retention = cfg.attn_kind == "retention"
-        if self.retention:
-            for what, asked in (
-                    ("quantize", quantize is not None),
-                    ("draft_params (speculative serving: the verify "
-                     "pass needs snapshots of the state)",
-                     draft_params is not None)):
-                if asked:
-                    raise InvalidRequestError(
-                        f"{what} is not supported for a retention model "
-                        "(attn_kind='retention')")
         # Per-sequence budget: the full ring a request may need.  The
         # gamma headroom mirrors transformer_speculative_generate — a
         # round writes up to gamma slots past the accepted frontier.
-        headroom = self.gamma if draft_params is not None else 0
-        self.max_seq_tokens = max_seq_tokens + headroom
+        self._headroom = self.gamma if draft_params is not None else 0
+        self.max_seq_tokens = max_seq_tokens + self._headroom
         self.view_pages = -(-self.max_seq_tokens // self.page_tokens)
-        self.view_tokens = self.view_pages * self.page_tokens
         pool_pages = pool_pages or autotune.current_serve_pool_pages() \
             or self.max_batch * self.view_pages
         self.sched = ContinuousScheduler(self.max_batch, policy=policy,
                                          seed=seed)
-        if self.retention:
-            self.pool = StateSlots(cfg, self.max_batch,
-                                   lambda: len(self.sched.active))
-        else:
-            self.pool = PagedKVPool(cfg, pool_pages, self.page_tokens,
-                                    quantize=quantize)
+        # The target's cache and the draft's (or None), and each with
+        # the weights that fill it: what both need is one loop.
+        shape = dict(rows=self.max_batch, view_pages=self.view_pages,
+                     page_tokens=self.page_tokens, pool_pages=pool_pages,
+                     speculative=draft_params is not None,
+                     rows_held=lambda: len(self.sched.active))
+        self.pool = make_cache(cfg, quantize=quantize, **shape)
+        self._caches = [(self.pool, params)]
         self.dpool = None
         if draft_params is not None:
-            self.dpool = PagedKVPool(draft_cfg, pool_pages,
-                                     self.page_tokens)
+            self.dpool = make_cache(draft_cfg, quantize=None, **shape)
+            self._caches.append((self.dpool, draft_params))
         if slo_ms is None:                 # HOROVOD_SERVE_SLO_MS
             slo_ms = util.env_float("SERVE_SLO_MS", 0.0)
         self.slo = SloController(slo_ms)
@@ -192,15 +169,10 @@ class InferenceServer:
             self.sched.observer = lambda step, event, req, row: \
                 rec.record("sched", {"event": event, "req": req,
                                      "row": row}, step=step)
-            if not self.retention:     # rows are the scheduler's events
-                self.pool.on_event = lambda ev, sid, n, free: \
-                    rec.record("pool", {"event": ev, "req": sid,
-                                        "pages": n, "free": free},
-                               step=self.step_no)
-            if self.dpool is not None:
-                self.dpool.on_event = lambda ev, sid, n, free: \
-                    rec.record("dpool", {"event": ev, "req": sid,
-                                         "pages": n, "free": free},
+            for name, (cache, _) in zip(("pool", "dpool"), self._caches):
+                cache.on_event = lambda ev, sid, n, free, name=name: \
+                    rec.record(name, {"event": ev, "req": sid,
+                                      "pages": n, "free": free},
                                step=self.step_no)
         self.slo.on_flip = self._on_slo_flip
         # Per-request lifecycle state feeding the timeline spans, the
@@ -214,15 +186,6 @@ class InferenceServer:
         V = cfg.vocab_size
         self.row_pos = np.zeros(self.max_batch, np.int64)
         self.last_logits = np.zeros((self.max_batch, V), np.float32)
-        self.row_seq: List[Optional[int]] = [None] * self.max_batch
-        # The decode view's two stacked leaves: keys and values gathered
-        # from the pool, or a retention model's states and normalisers,
-        # which live nowhere else.
-        self.view_k = self.view_v = None
-        if self.retention:
-            self.view_k, self.view_v = self.pool.new_view()
-        self.dview_k = self.dview_v = None
-        self._dirty_rows: Dict[int, int] = {}    # row -> seq_id to refresh
         self.step_no = 0
         self._next_req_id = 0
         self._submit_wall: Dict[int, float] = {}
@@ -231,11 +194,18 @@ class InferenceServer:
         self.device_steps = 0
         self.spec_steps = 0
         self.occupancy_sum = 0.0
-        self.state_installs = 0
-        #: bytes the state view holds (0 for a paged model)
-        self.state_bytes = self.pool.state_bytes if self.retention else 0
         self.token_latencies_ms: List[float] = []
         self.request_latencies_ms: List[float] = []
+
+    # What tests and the benchmark read of the caches (`retention`: the
+    # cache is a state); the server's own code asks the caches.
+    state_bytes = property(lambda self: self.pool.state_bytes)
+    state_installs = property(lambda self: self.pool.installs)
+    retention = property(lambda self: self.pool.state_bytes > 0)
+    view_k, view_v, dview_k, dview_v = (
+        property(lambda self, c=c, i=i: (
+            getattr(getattr(self, c), "view", None) or (None, None))[i])
+        for c in ("pool", "dpool") for i in (0, 1))
 
     # -- request intake ------------------------------------------------
 
@@ -270,44 +240,11 @@ class InferenceServer:
     # -- admission -----------------------------------------------------
 
     def _budget_tokens(self, req: Request) -> int:
-        n = int(req.prompt.size) + req.max_new_tokens
-        if self.draft_params is not None:
-            n += self.gamma
-        return n
+        return int(req.prompt.size) + req.max_new_tokens + self._headroom
 
     def _can_admit(self, req: Request) -> bool:
-        if self.retention:
-            # A free row is all a state needs, and the scheduler asks
-            # only while it has one.
-            return True
         n = self._budget_tokens(req)
-        if not self.pool.can_alloc(n):
-            return False
-        return self.dpool is None or self.dpool.can_alloc(n)
-
-    def _prefill_into(self, pool: PagedKVPool, params, cfg, seq,
-                      npages: int):
-        scratch = init_decode_cache(cfg, 1, npages * self.page_tokens,
-                                    quantize=pool.quantize)
-        lg, scratch = _prefill_fn(cfg)(
-            params, scratch, jnp.asarray(seq.req.prompt[None]))
-        pool.scatter_pages(seq.req.req_id, scratch["k"], scratch["v"])
-        return lg
-
-    def _prefill_state(self, seq):
-        """A retention model's prefill: the prompt's final state goes
-        into the row's slot of the view, whole, where the decode steps
-        will update it; no page is written."""
-        lg, scratch = _prefill_fn(self.cfg)(
-            self.params, self.pool.scratch(),
-            jnp.asarray(seq.req.prompt[None]))
-        with span("state_install", "serve",
-                  {"req": seq.req.req_id, "row": seq.row,
-                   "bytes": self.pool.row_bytes}):
-            self.view_k, self.view_v = self.pool.install(
-                (self.view_k, self.view_v), scratch, seq.row)
-        self.state_installs += 1
-        return lg
+        return all(c.can_board(n) for c, _ in self._caches)
 
     def _admit(self) -> int:
         """Board what the scheduler admits; returns how many.  Each
@@ -334,22 +271,14 @@ class InferenceServer:
                             args={"req": rid}, tid=f"req/{rid}")
             budget = self._budget_tokens(seq.req)
             T0 = int(seq.req.prompt.size)
-            pages = 0 if self.retention else self.pool.pages_needed(budget)
             with span("prefill", "serve",
                       {"req": rid, "prompt_tokens": T0, "row": seq.row,
-                       "pages": pages,
+                       "pages": self.pool.pages_needed(budget),
                        "queue_wait_us": round(queue_wait * 1e6, 1)},
                       tid=f"req/{rid}"):
-                if self.retention:
-                    lg = self._prefill_state(seq)
-                else:
-                    pids = self.pool.alloc(rid, budget)
-                    lg = self._prefill_into(self.pool, self.params,
-                                            self.cfg, seq, len(pids))
-                if self.dpool is not None:
-                    dpids = self.dpool.alloc(rid, budget)
-                    self._prefill_into(self.dpool, self.draft_params,
-                                       self.draft_cfg, seq, len(dpids))
+                lg = [c.board(rid, seq.row, budget, p, seq.req.prompt,
+                              _prefill_fn(c.cfg))
+                      for c, p in self._caches][0]
                 first_logits = np.asarray(lg)[0]   # waits for the prefill
             t_end = time.perf_counter()
             if obs is not None:
@@ -364,8 +293,6 @@ class InferenceServer:
             seq.pos = T0
             self.row_pos[seq.row] = T0
             self.last_logits[seq.row] = first_logits
-            self.row_seq[seq.row] = rid
-            self._dirty_rows[seq.row] = rid
         return admitted
 
     def _first_token(self, seq: ActiveSeq) -> None:
@@ -392,13 +319,9 @@ class InferenceServer:
     def _finish(self, seq: ActiveSeq) -> None:
         rid = seq.req.req_id
         self.sched.evict(self.step_no, seq.row)
-        if not self.retention:       # a row given back is all there is
-            self.pool.free(rid)
-        if self.dpool is not None:
-            self.dpool.free(rid)
-        self.row_seq[seq.row] = None
+        for cache, _ in self._caches:
+            cache.release(rid, seq.row)
         self.row_pos[seq.row] = 0
-        self._dirty_rows.pop(seq.row, None)
         now = time.perf_counter()
         t0 = self._submit_wall.pop(rid, None)
         if t0 is not None:
@@ -427,29 +350,6 @@ class InferenceServer:
                 step=self.step_no,
                 ts_us=self.flightrec.now_us(t_decode),
                 dur_us=(now - t_decode) * 1e6)
-
-    def _refresh_views(self) -> None:
-        """Bring the pooled decode view up to date: a full gather the
-        first time, then per-admitted-row updates (evicted rows need
-        none — see PagedKVPool.gather_rows).  A retention model's view
-        is the only copy and `_prefill_state` has already written it."""
-        if self.retention:
-            self._dirty_rows.clear()
-            return
-        if self.view_k is None:
-            self.view_k, self.view_v = self.pool.gather(
-                self.row_seq, self.view_pages)
-            if self.dpool is not None:
-                self.dview_k, self.dview_v = self.dpool.gather(
-                    self.row_seq, self.view_pages)
-        elif self._dirty_rows:
-            pairs = sorted(self._dirty_rows.items())
-            self.view_k, self.view_v = self.pool.gather_rows(
-                self.view_k, self.view_v, pairs, self.view_pages)
-            if self.dpool is not None:
-                self.dview_k, self.dview_v = self.dpool.gather_rows(
-                    self.dview_k, self.dview_v, pairs, self.view_pages)
-        self._dirty_rows.clear()
 
     # -- the step ------------------------------------------------------
 
@@ -509,13 +409,12 @@ class InferenceServer:
             if spec:
                 t_spec = time.perf_counter()
                 with span("launch", "serve"):
-                    self._refresh_views()
+                    for cache, _ in self._caches:
+                        cache.refresh()
                     decided = self._spec_round(rows, feed)
                 spec_ms = (time.perf_counter() - t_spec) * 1e3
                 for r in rows:
-                    sid = self.row_seq[r]
-                    ob = (self._req_obs.get(sid)
-                          if sid is not None else None)
+                    ob = self._req_obs.get(self.sched.active[r].req.req_id)
                     if ob is not None:
                         ob["spec_ms"] += spec_ms
                 self.spec_steps += 1
@@ -540,19 +439,14 @@ class InferenceServer:
 
     def _plain_step(self, rows: Sequence[int], feed: np.ndarray) -> None:
         with span("launch", "serve"):      # dispatches only, no wait
-            self._refresh_views()
+            for cache, _ in self._caches:
+                cache.refresh()
             base = self.row_pos.copy()
-            ka, kb = cache_leaves(self.cfg)
-            cache = {ka: self.view_k, kb: self.view_v,
-                     "pos": jnp.asarray(base, jnp.int32)}
             lg, cache = _spec_step_fn(self.cfg)(
-                self.params, cache, jnp.asarray(feed, jnp.int32))
-            self.view_k, self.view_v = cache[ka], cache[kb]
-            if not self.retention:      # a state has no page to copy to
-                sids = [self.row_seq[r] for r in rows]
-                slots = [int(base[r]) % self.view_tokens for r in rows]
-                self.pool.scatter_slots(self.view_k, self.view_v, sids,
-                                        rows, slots)
+                self.params, self.pool.lend(base),
+                jnp.asarray(feed, jnp.int32))
+            self.pool.take_back(cache)
+            self.pool.write_through(rows, base)
         with span("fetch", "serve"):       # the step's one sync
             self.last_logits = np.array(lg)    # copy: row writes on admit
         for r in rows:
@@ -565,8 +459,7 @@ class InferenceServer:
         gamma = self.gamma
         base = self.row_pos.copy()
         dstep = _spec_step_fn(self.draft_cfg)
-        dcache = {"k": self.dview_k, "v": self.dview_v,
-                  "pos": jnp.asarray(base, jnp.int32)}
+        dcache = self.dpool.lend(base)
         drafts: List[np.ndarray] = []     # d_1 .. d_gamma, each [B]
         cur = feed
         for _ in range(gamma):
@@ -574,14 +467,13 @@ class InferenceServer:
                                 jnp.asarray(cur, jnp.int32))
             cur = np.asarray(jnp.argmax(dlg, -1))
             drafts.append(cur)
-        self.dview_k, self.dview_v = dcache["k"], dcache["v"]
+        self.dpool.take_back(dcache)
 
         chunk = np.stack([feed] + drafts[:-1], axis=1)     # [B, gamma]
-        tcache = {"k": self.view_k, "v": self.view_v,
-                  "pos": jnp.asarray(base, jnp.int32)}
         tlg, tcache = _spec_extend_fn(self.cfg)(
-            self.params, tcache, jnp.asarray(chunk, jnp.int32))
-        self.view_k, self.view_v = tcache["k"], tcache["v"]
+            self.params, self.pool.lend(base),
+            jnp.asarray(chunk, jnp.int32))
+        self.pool.take_back(tcache)
         tlogits = np.asarray(tlg)                          # [B, g, V]
 
         # Accepted prefix per row, capped at gamma-1 so the round
@@ -607,17 +499,11 @@ class InferenceServer:
             self.last_logits[r] = tlogits[r, n_acc]
             self.row_pos[r] = int(base[r]) + n_acc + 1
             seq.pos = int(self.row_pos[r])
-        # Scatter the verified slots (emit token + accepted drafts):
-        # ring positions base .. base + n_acc per row.
-        sids = [self.row_seq[r] for r in rows]
+        # Carry the verified slots through (emit token + accepted
+        # drafts): positions base .. base + n_acc per row.
         for off in range(n_acc + 1):
-            slots = [(int(base[r]) + off) % self.view_tokens
-                     for r in rows]
-            self.pool.scatter_slots(self.view_k, self.view_v, sids,
-                                    rows, slots)
-            if self.dpool is not None:
-                self.dpool.scatter_slots(self.dview_k, self.dview_v,
-                                         sids, rows, slots)
+            for cache, _ in self._caches:
+                cache.write_through(rows, base + off)
         return n_acc
 
     # -- loops / observability -----------------------------------------
@@ -682,9 +568,7 @@ class InferenceServer:
     def _set_gauges(self) -> None:
         _met.serve_queue_depth.set(self.sched.queue_depth())
         _met.serve_batch_occupancy.set(self.sched.occupancy())
-        if not self.retention:      # a state has no pages: not exported
-            _met.serve_pool_pages_free.set(self.pool.pages_free())
-        _met.serve_state_bytes.set(self.state_bytes)
+        self.pool.set_gauges()
         p99 = self.slo.p99_ms()
         if p99:
             _met.serve_p99_ms.set(p99)

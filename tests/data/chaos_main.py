@@ -11,7 +11,10 @@ autotune best non-worsening, final params bitwise-identical).
 Soak shape comes from the standard env knobs
 (HOROVOD_CHAOS_GENERATIONS / HOROVOD_CHAOS_STEPS_PER_GEN /
 HOROVOD_STRAGGLER_*) plus HVD_CHAOS_SEED, so the launching test
-controls the plan deterministically.
+controls the plan deterministically.  HVD_CHAOS_STALL_MS and
+HVD_CHAOS_STRAGGLER_DELAY_MS (this worker's own, like the seed) size
+the injected delays: a test that runs beside others gives them a margin
+over the machine's noise.
 """
 
 import json
@@ -27,7 +30,12 @@ from horovod_tpu.faults.chaos import ChaosSoak  # noqa: E402
 
 def main():
     hvd.init()
-    soak = ChaosSoak(seed=int(os.environ.get("HVD_CHAOS_SEED", "7")))
+    delays = {arg: int(os.environ[var]) for arg, var in (
+        ("stall_ms", "HVD_CHAOS_STALL_MS"),
+        ("straggler_delay_ms", "HVD_CHAOS_STRAGGLER_DELAY_MS"))
+        if var in os.environ}
+    soak = ChaosSoak(seed=int(os.environ.get("HVD_CHAOS_SEED", "7")),
+                     **delays)
     res = soak.run()
     out_dir = os.environ["HVD_TEST_OUT"]
     with open(os.path.join(out_dir, f"rank{hvd.rank()}.json"), "w") as f:

@@ -600,8 +600,24 @@ class TestChaosSoakFast:
     injections, per-generation merged-trace windows feeding the online
     autotuner — small enough for tier-1."""
 
+    # This soak runs beside five other workers' tests (tier 1 is `-n 6`),
+    # and three of its assertions read the wall clock: who the merged
+    # trace blames, whether the stall stands out of the step times, and
+    # that no clean step does.  At the library's defaults (20 ms a
+    # delayed bucket, a 250 ms stall, z > 4) the stall scored 7.4 to 8.6
+    # on a busy machine and, in one whole run of three, under 4.  So the
+    # injected delays get a margin over the machine's noise, the bar a
+    # clean step must clear goes up with them, and what is asserted is
+    # the injected fault by name.  Its own time limit: ten times what it
+    # takes (12 s alone), not the launcher's 420 s.
+    NOISE_MARGIN = {"HVD_CHAOS_STALL_MS": "1500",
+                    "HOROVOD_ANOMALY_Z": "6",
+                    "HVD_CHAOS_STRAGGLER_DELAY_MS": "40",
+                    "HOROVOD_STRAGGLER_SKEW_THRESHOLD": "0.95"}
+
     def test_two_process_soak(self, tmp_path):
-        res = _launch_chaos(2, tmp_path, generations=5, steps_per_gen=4)
+        res = _launch_chaos(2, tmp_path, generations=5, steps_per_gen=4,
+                            extra_env=self.NOISE_MARGIN, timeout=150)
         _assert_soak_invariants(res, 2)
         out = res[0]
         # The straggler block armed and the blame stream fired a
@@ -622,13 +638,13 @@ class TestChaosSoakFast:
         # Reactions were computed in lockstep on every rank.
         assert res[1]["reactions"] == out["reactions"]
         # Anomaly detectors (docs/TELEMETRY.md): the injected faults
-        # are ground truth — at least one injected kind must be
-        # flagged by the step-time / step-counter monitors, every trip
+        # are ground truth — the stall (the one whose size this test
+        # sets) must be flagged by the step-time monitor, every trip
         # must attribute to an injection (zero false positives on
         # clean steps), and trips name the offending series.
         anom = out["anomaly"]
         assert anom["false_positives"] == 0, anom["events"]
-        assert len(anom["detected_kinds"]) >= 1, anom
+        assert "worker_stall" in anom["detected_kinds"], anom
         assert set(anom["detected_kinds"]) <= set(anom["injected_kinds"])
         for ev in anom["events"]:
             assert ev["series"] in ("hvd_critical_path_ms",

@@ -276,9 +276,12 @@ def test_server_serves_what_generate_gives_alone(model):
     assert srv.state_bytes == srv.view_k.nbytes + srv.view_v.nbytes \
         == 3 * srv.pool.row_bytes
     assert srv.view_k.shape[1] == 3          # a slot a row
-    assert not hasattr(srv.pool, "alloc")    # no page is ever asked for
+    assert srv.pool.pages_needed(10 ** 6) == 0   # no page is counted
     assert srv.pool.utilization() == 0.0     # every row given back
-    assert not hasattr(srv.pool, "k")        # no second copy anywhere
+    on_device = [a for a in jax.tree_util.tree_leaves(vars(srv.pool))
+                 if isinstance(a, jax.Array)]
+    assert sum(a.nbytes for a in on_device) == srv.state_bytes \
+        and len(on_device) == 2              # no second copy anywhere
 
 
 def test_server_prompt_longer_than_a_chunk(model):
@@ -303,15 +306,17 @@ def test_server_prompt_longer_than_a_chunk(model):
 
 
 def test_admission_counts_rows_and_no_pages(model):
-    """`StateSlots` has nothing of a page pool; what is held is what the
-    scheduler holds, and the free-pages gauge is not written (0 there
-    reads as a stall)."""
+    """`StateSlots` counts no page and has no page event, whatever a
+    request's budget; what is held is what the scheduler holds, and the
+    free-pages gauge is not written (0 there reads as a stall)."""
     from horovod_tpu.metrics import catalog as _met
     cfg, params = model
     srv = InferenceServer(params, cfg, max_seq_tokens=40, max_batch=3)
-    for name in ("alloc", "free", "can_alloc", "pages_needed",
-                 "pages_free", "total_pages", "gather", "scatter_slots"):
-        assert not hasattr(srv.pool, name), name
+    for budget in (1, 40, 10 ** 9):
+        assert srv.pool.pages_needed(budget) == 0, budget
+        assert srv.pool.can_board(budget), budget
+    page_events = []
+    srv.pool.on_event = lambda *ev: page_events.append(ev)
     _met.serve_pool_pages_free.set(123.0)
     for n in (4, 6, 5, 3):
         srv.submit(tokens_of(n, seed=n), 4)
@@ -319,7 +324,7 @@ def test_admission_counts_rows_and_no_pages(model):
     assert len(srv.sched.active) == 3 and srv.sched.queue_depth() == 1
     assert srv.pool.utilization() == 1.0
     srv.run()                               # flushes the gauges
-    assert srv.pool.utilization() == 0.0
+    assert srv.pool.utilization() == 0.0 and page_events == []
     assert _met.serve_pool_pages_free._solo()._value == 123.0
     assert _met.serve_state_bytes._solo()._value == srv.state_bytes > 0
 

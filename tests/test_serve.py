@@ -79,9 +79,9 @@ class TestPagedKVPool:
             pool.alloc(1, 4)
         with pytest.raises(HorovodTpuError, match="holds no pages"):
             pool.free(99)
-        assert pool.can_alloc(4) is False
+        assert pool.can_board(4) is False
         pool.free(1)
-        assert pool.can_alloc(8) is True
+        assert pool.can_board(8) is True
 
     @pytest.mark.parametrize("quantize", [None, "int8"])
     def test_pooled_decode_bitwise_equal(self, model, quantize):
@@ -363,6 +363,46 @@ class TestInferenceServer:
             ref, _ = transformer_generate(
                 params, cfg, jnp.asarray(p[None], jnp.int32), 9)
             assert by_id[rid] == np.asarray(ref)[0].tolist()
+
+    @pytest.mark.parametrize("kind", ["softmax-bf16", "softmax-int8",
+                                      "retention"])
+    def test_cache_contract(self, kind):
+        """What the server asks of its cache (pool.py `DecodeCache`),
+        for each cache there is: board, step, give back; a full paged
+        cache holds a request back where a state never does; nothing is
+        held after the drain; a row reused carries nothing over."""
+        paged = kind != "retention"
+        cfg = _cfg(compute_dtype=jnp.bfloat16) if paged else \
+            _cfg(attn_kind="retention")
+        params = transformer_init(jax.random.PRNGKey(4), cfg)
+        # two rows, and pages for ONE request of the full budget
+        kw = dict(max_seq_tokens=16, max_batch=2, page_tokens=4,
+                  pool_pages=4,
+                  quantize="int8" if kind == "softmax-int8" else None)
+        rng = np.random.RandomState(5)
+        a, b, c = (rng.randint(0, 64, size=10) for _ in range(3))
+
+        def tokens(srv, prompt):
+            rid = srv.submit(prompt, 6)
+            return {s.req.req_id: s.generated for s in srv.run()}[rid]
+
+        srv = InferenceServer(params, cfg, **kw)
+        srv.submit(a, 6)
+        srv.step()
+        srv.submit(b, 6)                # a free row, and no free page
+        srv.step()
+        assert srv.sched.queue_depth() == (1 if paged else 0)
+        assert srv.pool.utilization() == 1.0
+        assert len(srv.run()) == 2
+        assert srv.pool.utilization() == 0.0
+        assert srv.retention != paged
+        assert srv.state_installs == (0 if paged else 2)
+        assert srv.state_bytes == (
+            0 if paged else srv.view_k.nbytes + srv.view_v.nbytes)
+        # c boards a row (and pages) that a and b have used
+        assert tokens(srv, c) == tokens(InferenceServer(params, cfg, **kw),
+                                        c)
+        assert srv.pool.utilization() == 0.0
 
     def test_eos_stops_row(self, model):
         cfg, params = model
