@@ -1030,6 +1030,29 @@ def _spec_step_fn(cfg: TransformerConfig):
                    donate_argnums=(1,))
 
 
+def _serve_step_fn(cfg: TransformerConfig):
+    """The server's step: `_spec_step_fn(cfg)`'s program with the greedy
+    pick in it, returning (logits [B, V] f32, ids [B] int32, cache), so a
+    server step syncs on the ids and leaves the logits on the device.
+    ONE program, and a lambda like its siblings: a pick dispatched after
+    the step would run as often as the step, and the trace readers take
+    the `jit__lambda(` run most often for the decode step.  It is kept by
+    the step it is built over, not by `cfg`: whoever clears
+    `_spec_step_fn` to have the step traced again gets this one traced
+    again too."""
+    return _with_greedy_ids(_spec_step_fn(cfg))
+
+
+@functools.lru_cache(maxsize=None)
+def _with_greedy_ids(step):
+    def picked(logits, cache):
+        # argmax takes the first of equal maxima, as np.argmax does
+        return logits, jnp.argmax(logits, -1).astype(jnp.int32), cache
+
+    return jax.jit(lambda p, c, t: picked(*step(p, c, t)),
+                   donate_argnums=(1,))
+
+
 @functools.lru_cache(maxsize=None)
 def _spec_draft_scan(cfg: TransformerConfig, n: int, sampled: bool):
     """One compiled program proposing n draft tokens per row: scan of

@@ -13,8 +13,10 @@ Step anatomy (``step()``; in brackets what a paged cache does):
   1. admit   — queued requests ``board`` free rows: prefill-on-admit
                runs ``transformer_prefill`` into a scratch cache [of the
                request's page budget, then bulk-written into its pages].
-  2. emit    — each active row's next token is decided HOST-side from
-               its pending logits (greedy serving); finished rows
+  2. emit    — each active row emits its pending id (greedy serving):
+               the argmax of its logits, picked INSIDE the decode
+               program that computed them (a row just admitted: of its
+               prefill's row of logits, on the host); finished rows
                (max_new / EOS) evict and ``release`` their row BEFORE
                any device work, so the last token costs no decode step.
   3. refresh — [only if membership changed: rebuild the pooled view.]
@@ -23,6 +25,9 @@ Step anatomy (``step()``; in brackets what a paged cache does):
                when the SLO controller has flipped speculation on.
                The programs CONSUME the view (donated, updated in
                place): the cache lends it and takes back the result.
+               The step's one sync fetches the ``[max_batch]`` ids; the
+               ``[max_batch, vocab]`` logits stay on the device and
+               come to the host only for who reads ``last_logits``.
   5. write through — [copy each active row's written ring slot(s)
                into its pages; the pool stays the source of truth.]
 
@@ -41,7 +46,8 @@ which of them the device waits for.
 All host orchestration (clocks, metrics, env) stays OUTSIDE the jitted
 programs; the compiled pieces are the same module-cached
 ``_spec_step_fn`` / ``_spec_extend_fn`` programs the speculative
-decoder uses, plus one prefill jit — shapes (max_batch, view ring,
+decoder uses, ``_serve_step_fn`` (the step with the greedy pick in it)
+and one prefill jit — shapes (max_batch, view ring,
 gamma) key the program cache through tracing, which is why the
 ``serve_page_tokens`` / ``serve_max_batch`` / ``serve_spec_gamma``
 autotuner knobs are part of the compiled-shape key (docs/AUTOTUNE.md).
@@ -62,8 +68,8 @@ import numpy as np
 from ..common import util
 from ..common.exceptions import InvalidRequestError
 from ..metrics import catalog as _met
-from ..models.decode import (_spec_extend_fn, _spec_step_fn,
-                             transformer_prefill)
+from ..models.decode import (_serve_step_fn, _spec_extend_fn,
+                             _spec_step_fn, transformer_prefill)
 from ..utils import autotune
 from ..utils.timeline import get_timeline, span
 from .flightrec import FlightRecorder
@@ -185,7 +191,14 @@ class InferenceServer:
 
         V = cfg.vocab_size
         self.row_pos = np.zeros(self.max_batch, np.int64)
-        self.last_logits = np.zeros((self.max_batch, V), np.float32)
+        # Each row's pending decision is an id.  The logits behind the
+        # ids are the last step's, left on the device, under the rows
+        # decided on the host since (`_fresh`: a prefill's row, a
+        # speculative round's); `last_logits` puts the two together.
+        self._next_ids = np.zeros(self.max_batch, np.int32)
+        self._logits = np.zeros((self.max_batch, V), np.float32)
+        self._fresh: Dict[int, np.ndarray] = {}
+        self.logit_fetches = 0      # whole logits pulled to the host
         self.step_no = 0
         self._next_req_id = 0
         self._submit_wall: Dict[int, float] = {}
@@ -206,6 +219,41 @@ class InferenceServer:
         property(lambda self, c=c, i=i: (
             getattr(getattr(self, c), "view", None) or (None, None))[i])
         for c in ("pool", "dpool") for i in (0, 1))
+
+    @property
+    def last_logits(self) -> np.ndarray:
+        """The `[max_batch, vocab]` float32 logits the pending ids were
+        picked from: the last step's, with a row admitted since holding
+        its prefill's.  Fetched from the device when asked (counted in
+        `logit_fetches`) and kept until the next step; read-only, since
+        a row written in place would move no id.  Assigning an array
+        picks every pending id again from what was assigned."""
+        if not isinstance(self._logits, np.ndarray):
+            self._logits = np.array(self._logits)
+            self.logit_fetches += 1
+        for row, logits in self._fresh.items():
+            self._logits[row] = logits
+        self._fresh.clear()
+        view = self._logits.view()
+        view.flags.writeable = False
+        return view
+
+    @last_logits.setter
+    def last_logits(self, logits) -> None:
+        logits = np.array(logits, np.float32)
+        if logits.shape != (self.max_batch, self.cfg.vocab_size):
+            raise InvalidRequestError(
+                f"last_logits is [max_batch, vocab] = "
+                f"{(self.max_batch, self.cfg.vocab_size)}, got "
+                f"{logits.shape}")
+        self._logits = logits
+        self._fresh.clear()
+        self._next_ids = np.argmax(logits, -1).astype(np.int32)
+
+    def _decide_on_host(self, row: int, logits: np.ndarray) -> None:
+        """`row`'s pending id from logits the host already holds."""
+        self._fresh[row] = logits
+        self._next_ids[row] = np.argmax(logits)
 
     # -- request intake ------------------------------------------------
 
@@ -292,7 +340,7 @@ class InferenceServer:
                     dur_us=(t_end - t_start) * 1e6)
             seq.pos = T0
             self.row_pos[seq.row] = T0
-            self.last_logits[seq.row] = first_logits
+            self._decide_on_host(seq.row, first_logits)
         return admitted
 
     def _first_token(self, seq: ActiveSeq) -> None:
@@ -392,7 +440,7 @@ class InferenceServer:
             for row in sorted(self.sched.active):
                 seq = self.sched.active[row]
                 if not seq.done:
-                    tok = int(np.argmax(self.last_logits[row]))
+                    tok = int(self._next_ids[row])
                     seq.generated.append(tok)
                     self.tokens_out += 1
                     feed[row] = tok
@@ -442,13 +490,15 @@ class InferenceServer:
             for cache, _ in self._caches:
                 cache.refresh()
             base = self.row_pos.copy()
-            lg, cache = _spec_step_fn(self.cfg)(
+            self._logits, ids, cache = _serve_step_fn(self.cfg)(
                 self.params, self.pool.lend(base),
                 jnp.asarray(feed, jnp.int32))
+            self._fresh.clear()
             self.pool.take_back(cache)
             self.pool.write_through(rows, base)
-        with span("fetch", "serve"):       # the step's one sync
-            self.last_logits = np.array(lg)    # copy: row writes on admit
+        # the step's one sync: the ids, not the logits they came from
+        with span("fetch", "serve", {"bytes": ids.nbytes}):
+            self._next_ids = np.array(ids)     # copy: row writes on admit
         for r in rows:
             self.row_pos[r] += 1
             self.sched.active[r].pos = int(self.row_pos[r])
@@ -496,7 +546,7 @@ class InferenceServer:
                     break
                 seq.generated.append(int(drafts[i][r]))
                 self.tokens_out += 1
-            self.last_logits[r] = tlogits[r, n_acc]
+            self._decide_on_host(r, tlogits[r, n_acc])
             self.row_pos[r] = int(base[r]) + n_acc + 1
             seq.pos = int(self.row_pos[r])
         # Carry the verified slots through (emit token + accepted

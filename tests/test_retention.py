@@ -305,6 +305,33 @@ def test_server_prompt_longer_than_a_chunk(model):
             assert tok == int(row.argmax())
 
 
+def test_logits_read_between_steps_are_the_states_read_out(model):
+    """What the benchmark's `state_error` rests on (`served_again`,
+    benchmark/runners/retention_serve.py): the request's row of
+    `last_logits` between two steps is what the decode step read out of
+    the row's state.  Beside two other rows: the rows it returns chose
+    the tokens it returns, they are the reference's logits at the same
+    positions, and each read pulled the logits from the device once."""
+    from benchmark.runners.retention_serve import served_again
+
+    cfg, params = model
+    srv = InferenceServer(params, cfg, max_seq_tokens=40, max_batch=3)
+    for seed, n in ((31, 20), (32, 9)):
+        srv.submit(tokens_of(7, seed=seed), n)
+    srv.step()
+    assert srv.logit_fetches == 0
+    prompt = tokens_of(14, seed=33)
+    tokens, logits = served_again(srv, prompt, 12)
+    assert logits.shape == (11, V) and srv.logit_fetches == 11
+    assert tokens[1:] == logits.argmax(-1).tolist()
+    want, _ = transformer_generate(params, cfg, jnp.asarray(prompt)[None], 12)
+    assert tokens == np.asarray(want)[0].tolist()
+    full = np.concatenate([prompt, tokens])
+    np.testing.assert_allclose(
+        logits, reference(cfg, params, full[:-1])[len(prompt):],
+        rtol=TOL, atol=TOL)
+
+
 def test_admission_counts_rows_and_no_pages(model):
     """`StateSlots` counts no page and has no page event, whatever a
     request's budget; what is held is what the scheduler holds, and the

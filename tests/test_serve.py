@@ -419,6 +419,181 @@ class TestInferenceServer:
             0, :len(seq.generated)].tolist()
 
 
+# -- the greedy pick inside the step program -------------------------------
+
+class DecidedOnHost(InferenceServer):
+    """The rule the server had while the whole logits came to the host
+    every step: a row's next token is `np.argmax` of its row of
+    `last_logits`, taken when the token is emitted.  The ids the step
+    program picked are thrown away."""
+
+    @property
+    def _next_ids(self):
+        return [int(np.argmax(row)) for row in self.last_logits]
+
+    @_next_ids.setter
+    def _next_ids(self, ids):
+        pass
+
+
+def _kind_model(kind):
+    cfg = _cfg(attn_kind="retention") if kind == "state" else _cfg()
+    return cfg, transformer_init(jax.random.PRNGKey(6), cfg)
+
+
+def _spy_on_logits(monkeypatch, name):
+    """Every output [0] (the logits) of the server's program `name`, as
+    the device computed it."""
+    from horovod_tpu.serve import server as server_mod
+
+    seen, real = [], getattr(server_mod, name)
+
+    def build(cfg):
+        def call(*args):
+            out = real(cfg)(*args)
+            seen.append(np.array(out[0]))
+            return out
+        return call
+
+    monkeypatch.setattr(server_mod, name, build)
+    return seen
+
+
+@pytest.mark.parametrize("kind", ["paged", "state"])
+class TestGreedyIdsOnDevice:
+    KW = dict(max_seq_tokens=24, max_batch=3, page_tokens=4)
+
+    def _drive(self, srv, steps=40):
+        """Three requests at once, four more boarding in mid-flight as
+        rows come free (every row is re-used), outputs of 1 to 9."""
+        rng = np.random.RandomState(8)
+        plan = [(0, 5), (0, 2), (0, 9), (2, 4), (3, 1), (5, 7), (9, 3)]
+        tokens = {}
+        for step in range(steps):
+            for at, n in plan:
+                if at == step:
+                    srv.submit(rng.randint(0, 64, size=rng.randint(3, 9)),
+                               n)
+            for seq in srv.step():
+                tokens[seq.req.req_id] = list(seq.generated)
+        assert srv.sched.drained() and len(tokens) == len(plan)
+        return tokens
+
+    def test_tokens_are_those_decided_on_the_host(self, kind):
+        cfg, params = _kind_model(kind)
+        srv = InferenceServer(params, cfg, **self.KW)
+        host = DecidedOnHost(params, cfg, **self.KW)
+        assert self._drive(srv) == self._drive(host)
+        assert srv.device_steps == host.device_steps > 10
+        # nobody asked for the logits; the host's rule asks every token
+        assert srv.logit_fetches == 0
+        assert host.logit_fetches == host.device_steps
+
+    def test_last_logits_is_what_the_ids_were_picked_from(
+            self, kind, monkeypatch):
+        """Before a step's program runs, `last_logits` holds the last
+        program's logits, and the prefill's in a row admitted since;
+        after it, the program's own, for every row; it comes from the
+        device once a step at most, and only when read."""
+        cfg, params = _kind_model(kind)
+        steps = _spy_on_logits(monkeypatch, "_serve_step_fn")
+        prefills = _spy_on_logits(monkeypatch, "_prefill_fn")
+        held = []
+
+        class Watched(InferenceServer):
+            def _plain_step(self, rows, feed):
+                held.append((list(rows), self.last_logits.copy()))
+                super()._plain_step(rows, feed)
+
+        srv = Watched(params, cfg, **self.KW)
+        rng = np.random.RandomState(9)
+        seen, n_prefills, boarded = set(), 0, 0
+        for step, submit in enumerate([2, 0, 0, 1, 1] + [0] * 7):
+            for _ in range(submit):
+                srv.submit(rng.randint(0, 64, size=5), 4 + step)
+            n_steps = len(steps)
+            srv.step()
+            if len(steps) == n_steps:
+                continue
+            rows, at_launch = held[-1]
+            # rows admitted in this step, in the order of their prefills
+            new = [s.row for s in sorted(srv.sched.active.values(),
+                                         key=lambda s: s.req.req_id)
+                   if s.req.req_id not in seen]
+            seen.update(s.req.req_id for s in srv.sched.active.values())
+            for r in rows:
+                want = prefills[n_prefills + new.index(r)][0] \
+                    if r in new else steps[-2][r]
+                np.testing.assert_array_equal(at_launch[r], want)
+            n_prefills = len(prefills)
+            boarded += len(new)
+            # two reads after the step and one at the next launch: one
+            # fetch from the device
+            assert srv.logit_fetches == len(steps) - 1
+            np.testing.assert_array_equal(srv.last_logits, steps[-1])
+            np.testing.assert_array_equal(srv.last_logits, steps[-1])
+            assert srv.logit_fetches == len(steps)
+            with pytest.raises(ValueError, match="read-only"):
+                srv.last_logits[0] = 0.0
+        assert boarded == n_prefills == 4 and len(steps) > 8
+        # a request of one token runs no step: its row keeps the prefill's
+        srv.run()
+        srv.submit(rng.randint(0, 64, size=6), 1)
+        (seq,) = srv.step()
+        assert len(steps) == len(held)
+        np.testing.assert_array_equal(srv.last_logits[seq.row],
+                                      prefills[-1][0])
+        assert seq.generated == [int(np.argmax(prefills[-1][0]))]
+
+    def test_assigned_logits_move_the_tokens(self, kind):
+        cfg, params = _kind_model(kind)
+        srv = InferenceServer(params, cfg, **self.KW)
+        rng = np.random.RandomState(10)
+        for _ in range(3):
+            srv.submit(rng.randint(0, 64, size=5), 8)
+        srv.step()
+        srv.step()
+        logits = srv.last_logits.copy()
+        n = {r: len(s.generated) for r, s in srv.sched.active.items()}
+        srv.last_logits = -logits        # whole, as the rehearsal does
+        np.testing.assert_array_equal(srv.last_logits, -logits)
+        srv.step()
+        assert len(n) == 3
+        for r, s in srv.sched.active.items():
+            assert s.generated[n[r]] == int(np.argmin(logits[r])) \
+                != int(np.argmax(logits[r]))
+        with pytest.raises(HorovodTpuError, match="max_batch, vocab"):
+            srv.last_logits = logits[:2]
+
+
+@pytest.mark.parametrize("rounds", ["SSPPPP", "PPSSPP", "SPSPSP"])
+def test_speculative_rounds_among_plain_steps(model, rounds):
+    """A speculative round leaves its rows' ids decided on the host, a
+    plain step the program's: in any order the chain is the greedy
+    one."""
+    cfg, params = model
+    srv = InferenceServer(params, cfg, max_seq_tokens=40, max_batch=2,
+                          page_tokens=4, gamma=3,
+                          draft_params=transformer_init(
+                              jax.random.PRNGKey(9), cfg), draft_cfg=cfg)
+    rng = np.random.RandomState(11)
+    reqs = {}
+    for _ in range(3):
+        prompt = rng.randint(0, 64, size=4)
+        reqs[srv.submit(prompt, 14)] = prompt
+    got = {}
+    for i in range(60):
+        srv.force_spec = rounds[i % len(rounds)] == "S"
+        for seq in srv.step():
+            got[seq.req.req_id] = seq.generated
+    assert srv.sched.drained()
+    assert 0 < srv.spec_steps < srv.device_steps
+    for rid, prompt in reqs.items():
+        ref, _ = transformer_generate(
+            params, cfg, jnp.asarray(prompt[None], jnp.int32), 14)
+        assert got[rid] == np.asarray(ref)[0].tolist()
+
+
 class TestBenchRecords:
     def test_append_and_stale_gate(self, tmp_path, caplog):
         path = str(tmp_path / "BENCH_serve.json")

@@ -231,6 +231,21 @@ def test_timeline_prefill_event_keeps_its_shape(traced):
     assert report["summary"]["completed"] == len(OUTPUTS)
 
 
+def test_fetch_carries_the_bytes_of_the_steps_one_sync(traced):
+    """A plain step syncs on `[max_batch]` int32 ids (max_batch 2), and
+    the whole logits come to the host only for who reads `last_logits`,
+    which `logit_fetches` counts."""
+    fetches = [s for s in traced["spans"] if s[0] == "hvd.serve.fetch"]
+    assert len(fetches) == traced["srv"].device_steps
+    assert all(f[3] == {"bytes": 4 * 2} for f in fetches)
+    fetch_events = [e for e in traced["events"] if e["name"] == "fetch"]
+    assert [e["args"] for e in fetch_events] == [{"bytes": 8}] * len(fetches)
+    srv = traced["srv"]
+    assert srv.logit_fetches == 0
+    srv.last_logits, srv.last_logits
+    assert srv.logit_fetches == 1           # kept until the next step
+
+
 def test_speculative_round_is_one_launch(model, tmp_path):
     cfg, params = model
     (srv, _, _), spans = _profiled(

@@ -63,6 +63,19 @@ def _leaf_bytes(leaf):
     return a.size // a.shape[0] * a.dtype.itemsize
 
 
+def _step_args(one_chip, cfg, rows, slots, quantize=None):
+    """(params, cache, tokens) of a served step (vector `pos`), as shapes
+    on the described chip."""
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(cfg.compute_dtype),
+        transformer_init(jax.random.PRNGKey(0), cfg)))
+    cache = jax.eval_shape(
+        lambda: init_decode_cache(cfg, rows, slots, quantize=quantize))
+    cache["pos"] = jax.ShapeDtypeStruct((rows,), jnp.int32)
+    tokens = jax.ShapeDtypeStruct((rows,), jnp.int32)
+    return one_chip(params), one_chip(cache), one_chip(tokens)
+
+
 @pytest.mark.parametrize("quantize", [None, "int8"], ids=["bf16", "int8"])
 def test_decode_step_reads_the_view_where_it_lies(one_chip, quantize):
     """The served step (vector `pos`): the program's temporaries stay
@@ -73,19 +86,54 @@ def test_decode_step_reads_the_view_where_it_lies(one_chip, quantize):
     from horovod_tpu.models.decode import _spec_step_fn
 
     cfg = TransformerConfig(**WIDTHS)
-    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
-        lambda a: a.astype(cfg.compute_dtype),
-        transformer_init(jax.random.PRNGKey(0), cfg)))
-    cache = jax.eval_shape(
-        lambda: init_decode_cache(cfg, ROWS, SLOTS, quantize=quantize))
-    cache["pos"] = jax.ShapeDtypeStruct((ROWS,), jnp.int32)
-    tokens = jax.ShapeDtypeStruct((ROWS,), jnp.int32)
-    compiled = _spec_step_fn(cfg).lower(
-        one_chip(params), one_chip(cache), one_chip(tokens)).compile()
+    args = _step_args(one_chip, cfg, ROWS, SLOTS, quantize)
+    cache = args[1]
+    compiled = _spec_step_fn(cfg).lower(*args).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     k_slice = _leaf_bytes(cache["k"])
     assert k_slice == ROWS * 8 * SLOTS * 128 * (1 if quantize else 2)
     assert temp < k_slice // 8, (temp, k_slice)
+
+
+# brumby14b_longdoc_steady's decode view (benchmark/traffic/
+# longdoc_steady.json, benchmark/configs/brumby-14b-serve.json): a state
+# a row, 16 rows, the whole vocabulary of 151,936.
+STATE_ROWS = 16
+STATE_WIDTHS = dict(vocab_size=151936, d_model=5120, n_heads=40, d_head=128,
+                    d_ff=17408, n_kv_heads=8, rope_theta=1e6, n_layers=2,
+                    compute_dtype=jnp.bfloat16, attn_kind="retention")
+
+
+@pytest.mark.parametrize("widths,rows,slots,leaf", [
+    (WIDTHS, ROWS, SLOTS, "k"), (STATE_WIDTHS, STATE_ROWS, 1, "s")],
+    ids=["chat-32x32000", "longdoc-16x151936"])
+def test_server_step_picks_its_ids_in_the_one_program(one_chip, widths,
+                                                      rows, slots, leaf):
+    """The server's own step (`_serve_step_fn`: the decode step with the
+    greedy pick in it) is ONE program, named as the benchmark's trace
+    readers look for the step, which hands back the logits, `[rows]`
+    int32 ids and the cache; the pick costs no temporaries to speak of
+    (under the logits' own size) and the cache is still read and
+    written where it lies."""
+    from horovod_tpu.models.decode import _serve_step_fn, _spec_step_fn
+
+    cfg = TransformerConfig(**widths)
+    args = _step_args(one_chip, cfg, rows, slots)
+    cache = args[1]
+    bare = _spec_step_fn(cfg).lower(*args).compile()
+    lowered = _serve_step_fn(cfg).lower(*args)
+    logits, ids, out_cache = lowered.out_info
+    assert (logits.shape, logits.dtype) == ((rows, cfg.vocab_size),
+                                            jnp.float32)
+    assert (ids.shape, ids.dtype) == ((rows,), jnp.int32)
+    assert {n: a.shape for n, a in out_cache.items()} == \
+        {n: a.shape for n, a in cache.items()}
+    picked = lowered.compile()
+    assert picked.as_text().startswith("HloModule jit__lambda,")
+    temp = picked.memory_analysis().temp_size_in_bytes
+    logits_bytes = rows * cfg.vocab_size * 4
+    assert temp <= bare.memory_analysis().temp_size_in_bytes + logits_bytes
+    assert temp < _leaf_bytes(cache[leaf]) // 8, temp
 
 
 def test_pool_write_back_moves_slots_only(one_chip):
