@@ -247,6 +247,13 @@ serve_state_bytes = _REG.gauge(
     "Bytes the decode view holds as recurrent state: every row's state "
     "and normaliser of a retention model, held once (0 for a model "
     "whose cache is paged).")
+serve_cache_bytes = _REG.gauge(
+    "hvd_serve_cache_bytes",
+    "Bytes a patterned model's cache holds, by kind: 'pages' (the pool of "
+    "the layers that see the whole context, and the decode view gathered "
+    "from it) and 'rings' (one ring of `window` slots a row for the "
+    "layers that see a window, held once).",
+    labelnames=("kind",))
 serve_p99_ms = _REG.gauge(
     "hvd_serve_p99_ms",
     "Observed p99 per-token decode latency over the SLO controller's "

@@ -69,6 +69,21 @@ the state carried across (`_retention_prefill_layer`).  Both ride
 ride for a softmax layer.  What needs snapshots or shards of a state
 (`transformer_extend`, speculation, beam search, `make_decode_step`) and
 the quantized layouts refuse the kind by name.
+
+Patterned models (`cfg.layer_attn`: a kind of attention a layer, an MLP
+kind a layer): the layers are softmax layers, `_decode_layer` and
+`_prefill_layer` themselves, each handed its kind's uniform
+configuration (`cfg.kind_cfg(kind)`: that kind's heads, window and
+rotary form, `_rotate`; a gate a head, `_head_gate`), over parameters
+stacked by KIND of layer and a cache that holds, under each of the two
+leaves, one stacked ring a kind (`_empty_pattern`): a kind with a window
+keeps `window` slots a row whatever `max_len`, and a prompt longer than
+that leaves its last `window` tokens there, each at `pos % window`.
+`_pattern_walk` walks the pattern unrolled under `_layer_walk`'s
+contract; a layer's MLP is `_mlp_block` or the routed experts of
+models/experts.py, whose counts a pass ride in the cache as `routed`.
+What extends a ring by a chunk, rolls back, shards or quantizes refuses
+such a model by name (`_needs_uniform`).
 """
 
 from __future__ import annotations
@@ -83,6 +98,7 @@ from jax import lax
 
 from ..common.exceptions import InvalidRequestError
 from ..parallel import sequence as seq_mod
+from . import experts as experts_mod
 from .transformer import (
     TransformerConfig,
     _is_moe_layer,
@@ -109,11 +125,16 @@ def _kind(cfg: TransformerConfig) -> _Kind:
     contract) and, to be served, one cache class in serve/pool.py.  Made
     when asked for, so that a layer a test has replaced in this module
     is the one the next program traces."""
+    softmax = _Kind(
+        ("k", "v"), True, _empty_ring, _decode_layer,
+        # a ring is filled in one pass: no chunks
+        lambda *a, chunk, **kw: _prefill_layer(*a, **kw))
+    if cfg.patterned:
+        # the layers are softmax layers, each handed its kind's uniform
+        # configuration by `_pattern_walk`; the cache is a ring a kind
+        return softmax._replace(empty=_empty_pattern)
     kinds = {
-        "softmax": _Kind(
-            ("k", "v"), True, _empty_ring, _decode_layer,
-            # a ring is filled in one pass: no chunks
-            lambda *a, chunk, **kw: _prefill_layer(*a, **kw)),
+        "softmax": softmax,
         "retention": _Kind(
             ("s", "z"), False, _empty_state, _retention_decode_layer,
             _retention_prefill_layer),
@@ -155,6 +176,7 @@ def init_decode_cache(cfg: TransformerConfig, batch: int,
         raise ValueError(f"quantize must be None, 'int8', or "
                          f"'fp8_e4m3', got {quantize!r}")
     _needs_slots(cfg, "quantize", quantize is not None)
+    _needs_uniform(cfg, "quantize", quantize is not None)
     return {**_kind(cfg).empty(cfg, batch, max_len, quantize),
             "pos": jnp.zeros((), jnp.int32)}
 
@@ -170,6 +192,20 @@ def _empty_ring(cfg, batch, max_len, quantize) -> Dict:
         return {"k": kv(), "v": kv()}
     return {"k": jnp.zeros(shape, cfg.compute_dtype),
             "v": jnp.zeros(shape, cfg.compute_dtype)}
+
+
+def _empty_pattern(cfg, batch, max_len, quantize) -> Dict:
+    """A patterned model's cache: under each leaf one stacked ring a KIND
+    of attention layer, [L_kind, B, Hkv, slots, Dh] as `_empty_ring` lays
+    it.  A kind with a window holds `window` slots a row whatever
+    `max_len` (written at `pos % window`, never more), the others
+    `max_len`.  `routed` is what the last pass's expert layers counted
+    (`experts.ROUTED`), there so that a scan can carry the cache."""
+    rings = {t: _empty_ring(kc, batch, kc.attn_window or max_len, None)
+             for t in cfg.attn_kinds() for kc in (cfg.kind_cfg(t),)}
+    return {"k": {t: r["k"] for t, r in rings.items()},
+            "v": {t: r["v"] for t, r in rings.items()},
+            "routed": experts_mod.no_counts(cfg)}
 
 
 def _empty_state(cfg, batch, max_len, quantize) -> Dict:
@@ -195,7 +231,10 @@ def _quant_vec(x, qdt):
 
 def cache_slots(c) -> int:
     """Ring slots a row holds, off a stacked K or V leaf (plain, or the
-    {"q", "scale"} dict of a quantized cache)."""
+    {"q", "scale"} dict of a quantized cache; of a patterned model's
+    rings a kind, the longest)."""
+    if isinstance(c, dict) and "q" not in c:
+        return max(cache_slots(a) for a in c.values())
     return (c["q"] if isinstance(c, dict) else c).shape[3]
 
 
@@ -272,6 +311,37 @@ def _rope_rows(x, positions, theta: float):
     return out.reshape(x.shape)
 
 
+def _rotate(x, positions, cfg: TransformerConfig):
+    """The layer's rotary embedding of x [B, c, H, Dh] at `positions`
+    ([c], or [B, c] for rows at their own depths).  A plain form
+    (`cfg.rotary` None) is `_rope` / `_rope_rows` at `cfg.rope_theta`,
+    as ever; otherwise `cfg.rotary` says which share of the head
+    rotates, at which frequencies and how scaled (`Rotary`)."""
+    if cfg.rotary is None:
+        rope = _rope_rows if positions.ndim == 2 else _rope
+        return rope(x, positions, cfg.rope_theta)
+    freqs, n = cfg.rotary.tables(x.shape[-1])
+    angles = positions[..., None].astype(jnp.float32) \
+        * jnp.asarray(freqs, jnp.float32)
+    if positions.ndim == 1:
+        angles = angles[None]                          # [1|B, c, n]
+    cos = (jnp.cos(angles) * cfg.rotary.attention_factor)[:, :, None, :]
+    sin = (jnp.sin(angles) * cfg.rotary.attention_factor)[:, :, None, :]
+    x1, x2 = x[..., 0:2 * n:2], x[..., 1:2 * n:2]
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return jnp.concatenate(
+        [out.reshape(x.shape[:-1] + (2 * n,)).astype(x.dtype),
+         x[..., 2 * n:]], axis=-1)
+
+
+def _head_gate(lp, h, o, cfg: TransformerConfig):
+    """A patterned model's gate on the attention output: one sigmoid a
+    head from the normed hidden vector, o [B, c, H, Dh] float32."""
+    g = jnp.einsum("bod,dh->boh", h, lp["w_gate"].astype(cfg.compute_dtype),
+                   preferred_element_type=jnp.float32)
+    return o * jax.nn.sigmoid(g)[..., None]
+
+
 def _slot_positions(pos, S):
     """Absolute position held by each ring slot after the write at
     `pos`: slot j holds pos - ((pos - j) mod S); negative = never
@@ -319,14 +389,14 @@ def _decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
     vec = pos.ndim == 1
     if vec:
         positions = pos[:, None] + jnp.arange(c)[None, :]   # [B, c]
-        q = _rope_rows(q, positions, cfg.rope_theta).astype(dt)
-        k = _rope_rows(k, positions, cfg.rope_theta).astype(dt)
+        q = _rotate(q, positions, cfg).astype(dt)
+        k = _rotate(k, positions, cfg).astype(dt)
         ck = _cache_write_rows(ck, i, k, pos % S)
         cv = _cache_write_rows(cv, i, v, pos % S)
     else:
         positions = pos + jnp.arange(c)                # [c]
-        q = _rope(q, positions, cfg.rope_theta).astype(dt)
-        k = _rope(k, positions, cfg.rope_theta).astype(dt)
+        q = _rotate(q, positions, cfg).astype(dt)
+        k = _rotate(k, positions, cfg).astype(dt)
         slot = pos % S
         ck = _cache_write(ck, i, k, slot)
         cv = _cache_write(cv, i, v, slot)
@@ -380,7 +450,10 @@ def _decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
     else:
         o = jnp.einsum("bhgqk,bhkd->bqhgd", p,
                        lv.astype(jnp.float32))
-    o = o.reshape(B, c, Hq, Dh).astype(dt)
+    o = o.reshape(B, c, Hq, Dh)
+    if "w_gate" in lp:
+        o = _head_gate(lp, h, o, cfg)
+    o = o.astype(dt)
     out = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(dt))
     if tp_axis is not None:
         out = lax.psum(out, tp_axis)   # row-parallel wo
@@ -418,6 +491,16 @@ def _needs_slots(cfg: TransformerConfig, what: str,
             f"{what} is not supported for attn_kind={cfg.attn_kind!r}: "
             "the cache is one state a row, with no slots to quantize, "
             "shard, snapshot or roll back")
+
+
+def _needs_uniform(cfg: TransformerConfig, what: str,
+                   asked: bool = True) -> None:
+    if asked and cfg.patterned:
+        raise InvalidRequestError(
+            f"{what} is not supported for a model with a layer pattern "
+            "(layer_attn): its cache is a ring a kind of layer, window "
+            "layers' of `window` slots, with nothing yet to quantize, "
+            "shard, extend by a chunk or roll back")
 
 
 @functools.lru_cache(maxsize=None)
@@ -643,7 +726,43 @@ def _moe_tokens(mp, scale, x, cfg: TransformerConfig):
     return x + out.reshape(B, T, D).astype(x.dtype)
 
 
-def _layer_walk(params, ck, cv, x, attn_fn, cfg, tp_axis=None):
+def _pattern_walk(params, ck, cv, x, attn_fn, cfg, live, routed):
+    """`_layer_walk` over a layer pattern: the layers unrolled, layer l
+    taking the j-th slice of its kinds' stacks (`params["attn"][t]`,
+    `params["mlp"][m]`; static indices, so a slice is read where it
+    lies) and the j-th layer of its kind's ring, `ck[t]` / `cv[t]`,
+    under the same in-place contract.  `attn_fn` is handed the kind's
+    uniform configuration in `cfg`'s place.  An expert layer's counts
+    are appended to `routed`; `live` [B] marks the rows that are
+    anybody's (None: all)."""
+    dt = cfg.compute_dtype
+    ck, cv = dict(ck), dict(cv)
+    seen = {}
+    B, T, D = x.shape
+    tokens_live = None if live is None else jnp.repeat(live, T)
+    for t, m in zip(cfg.layer_attn, cfg.layer_mlp):
+        j, jm = seen.get(t, 0), seen.get(m, 0)
+        seen[t], seen[m] = j + 1, jm + 1
+        lp = jax.tree_util.tree_map(lambda p: p[j], params["attn"][t])
+        x, ck[t], cv[t] = attn_fn(lp, ck[t], cv[t], j, x,
+                                  cfg=cfg.kind_cfg(t))
+        # the experts' stack is not sliced: `experts._grouped` has why
+        mp = jax.tree_util.tree_map(
+            lambda p: p[jm], {n: p for n, p in params["mlp"][m].items()
+                              if n != "experts"})
+        if m == "dense":
+            x = _mlp_block(mp, x, cfg, None)
+            continue
+        h = _rmsnorm(mp["ln2"]["scale"], x).reshape(B * T, D).astype(dt)
+        out, counts = experts_mod.expert_layer(
+            mp, params["mlp"][m]["experts"], jm, h, cfg, tokens_live)
+        x = x + out.reshape(B, T, D).astype(x.dtype)
+        routed.append(counts)
+    return x, ck, cv
+
+
+def _layer_walk(params, ck, cv, x, attn_fn, cfg, tp_axis=None,
+                live=None, routed=None):
     """Layer walk shared by decode, chunked extend and prefill, ONE
     contract for every caller: `ck`/`cv` are the whole stacked cache
     (arrays, or the {"q", "scale"} dicts of the quantized layouts) and
@@ -658,7 +777,12 @@ def _layer_walk(params, ck, cv, x, attn_fn, cfg, tp_axis=None):
     (PERF.md, PR 27).  Mixed dense/MoE configs walk the layers
     unrolled, with static indices and the same contract.  A caller
     that donates the cache to its jit (the server's programs do) gets
-    the result in the argument's own buffer."""
+    the result in the argument's own buffer.  A patterned model walks
+    its pattern (`_pattern_walk`, which alone reads `live` and fills
+    `routed`)."""
+    if cfg.patterned:
+        return _pattern_walk(params, ck, cv, x, attn_fn, cfg, live,
+                             [] if routed is None else routed)
     if not cfg.moe_every:
         def layer_step(carry, inputs):
             x, ck, cv = carry
@@ -710,15 +834,29 @@ def transformer_decode_step(params: Dict, cache: Dict, tokens,
     kind = _kind(cfg)
     ka, kb = kind.leaves
 
+    # A served batch's idle rows sit at depth 0, where no sequence is (a
+    # prompt is never empty): a patterned model routes them nowhere.
+    live = pos > 0 if jnp.ndim(pos) == 1 else None
+    routed = []
     x, ck, cv = _layer_walk(
         params, cache[ka], cache[kb], x,
         functools.partial(kind.step, pos=pos, cfg=cfg, tp_axis=tp_axis),
-        cfg, tp_axis)
+        cfg, tp_axis, live, routed)
     x = _rmsnorm(params["final_norm"]["scale"], x)
     logits = jnp.einsum("bod,vd->bov", x.astype(dt),
                         params["embed"].astype(dt),
                         preferred_element_type=jnp.float32)
-    return logits[:, 0], {ka: ck, kb: cv, "pos": pos + 1}
+    return logits[:, 0], {ka: ck, kb: cv, "pos": pos + 1,
+                          **_routed(cfg, routed)}
+
+
+def _routed(cfg: TransformerConfig, routed) -> Dict:
+    """What a patterned model's cache says of the pass that made it:
+    `routed` [sparse layers, len(experts.ROUTED)]; nothing otherwise."""
+    if not cfg.patterned:
+        return {}
+    return {"routed": jnp.stack(routed) if routed
+            else experts_mod.no_counts(cfg)}
 
 
 def transformer_extend(params: Dict, cache: Dict, tokens,
@@ -742,6 +880,8 @@ def transformer_extend(params: Dict, cache: Dict, tokens,
     """
     _needs_slots(cfg, "transformer_extend (a chunk of tokens over "
                       "a cache; speculative verify)")
+    _needs_uniform(cfg, "transformer_extend (a chunk of tokens over a "
+                        "cache; speculative verify)")
     dt = cfg.compute_dtype
     B, c = tokens.shape
     S = cache_slots(cache["k"])
@@ -824,6 +964,7 @@ def transformer_speculative_generate(
     for c in (cfg, draft_cfg):
         _needs_slots(c, "speculative decoding (rolling back a round "
                         "needs snapshots of the state)")
+        _needs_uniform(c, "speculative decoding")
     if cfg.attn_window or draft_cfg.attn_window:
         raise ValueError(
             "speculative decoding does not support attn_window configs")
@@ -1047,7 +1188,13 @@ def _serve_step_fn(cfg: TransformerConfig):
 def _with_greedy_ids(step):
     def picked(logits, cache):
         # argmax takes the first of equal maxima, as np.argmax does
-        return logits, jnp.argmax(logits, -1).astype(jnp.int32), cache
+        ids = jnp.argmax(logits, -1).astype(jnp.int32)
+        if "routed" in cache:
+            # a patterned model's expert layers' counts ride behind the
+            # ids, [max_batch + sparse layers x len(experts.ROUTED)], so
+            # that the step's one sync brings both
+            ids = jnp.concatenate([ids, cache["routed"].reshape(-1)])
+        return logits, ids, cache
 
     return jax.jit(lambda p, c, t: picked(*step(p, c, t)),
                    donate_argnums=(1,))
@@ -1087,6 +1234,20 @@ def _spec_draft_scan(cfg: TransformerConfig, n: int, sampled: bool):
     return jax.jit(run, donate_argnums=(1,))
 
 
+def _flash_prompt(q, k, v, window):
+    """Causal attention of a whole prompt through the flash kernel
+    (ops/flash_attention.py), the prompt padded to the kernel's tile of
+    128: behind a causal mask what is appended changes nothing before
+    it.  No [H, T, T] scores are kept at any length."""
+    from ..ops.flash_attention import flash_attention
+    T = q.shape[1]
+    pad = -T % 128
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for a in (q, k, v))
+    return flash_attention(q, k, v, causal=True, window=window)[:, :T]
+
+
 def _prefill_layer(lp, ck, cv, i, x, cfg: TransformerConfig,
                    tp_axis=None):
     """Layer `i`'s attention over a whole prompt x [B, T0, D] (the
@@ -1101,15 +1262,28 @@ def _prefill_layer(lp, ck, cv, i, x, cfg: TransformerConfig,
     q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dt))
     k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dt))
     v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dt))
-    q = _rope(q, positions, cfg.rope_theta).astype(dt)
-    k = _rope(k, positions, cfg.rope_theta).astype(dt)
+    q = _rotate(q, positions, cfg).astype(dt)
+    k = _rotate(k, positions, cfg).astype(dt)
 
     # The prompt pass itself attends at full precision; decode
     # steps read the quantized store (documented lossy boundary).
-    ck = _cache_write(ck, i, k, 0)
-    cv = _cache_write(cv, i, v, 0)
-    o = seq_mod.full_attention(q, k, v, causal=True,
-                               window=cfg.attn_window or None)
+    T0, S = x.shape[1], cache_slots(ck)
+    if T0 > S:
+        # A window layer's ring of a patterned model, shorter than the
+        # prompt: it keeps the last S tokens, each at slot pos % S.
+        ring = lambda a: jnp.roll(a[:, T0 - S:], (T0 - S) % S, axis=1)
+        ck = _cache_write(ck, i, ring(k), 0)
+        cv = _cache_write(cv, i, ring(v), 0)
+    else:
+        ck = _cache_write(ck, i, k, 0)
+        cv = _cache_write(cv, i, v, 0)
+    if cfg.prompt_attention == "flash" and T0 >= 128:
+        o = _flash_prompt(q, k, v, cfg.attn_window or None)
+    else:
+        o = seq_mod.full_attention(q, k, v, causal=True,
+                                   window=cfg.attn_window or None)
+    if "w_gate" in lp:
+        o = _head_gate(lp, h, o.astype(jnp.float32), cfg)
     out = jnp.einsum("bthk,hkd->btd", o.astype(dt),
                      lp["wo"].astype(dt))
     if tp_axis is not None:
@@ -1135,6 +1309,8 @@ def transformer_prefill(params: Dict, cache: Dict, prompt,
     kind = _kind(cfg)
     ka, kb = kind.leaves
     if kind.slots and T0 > (S := cache_slots(cache[ka])):
+        # (a patterned model's longest ring; its window layers' rings
+        # keep a prompt's last `window` tokens)
         raise InvalidRequestError(
             f"prompt length {T0} > cache max_len {S}")
     # Prefill writes the prompt at slot 0; a warm cache (pos != 0)
@@ -1147,17 +1323,19 @@ def transformer_prefill(params: Dict, cache: Dict, prompt,
                 f"transformer_prefill requires a fresh cache "
                 f"(pos == 0), got pos = {int(cache['pos'])}")
     x = params["embed"][prompt].astype(dt)                # [B,T0,D]
+    routed = []
     x, ck, cv = _layer_walk(
         params, cache[ka], cache[kb], x,
         functools.partial(kind.prefill, cfg=cfg, tp_axis=tp_axis,
                           chunk=chunk),
-        cfg, tp_axis)
+        cfg, tp_axis, None, routed)
     x = _rmsnorm(params["final_norm"]["scale"], x[:, -1:])
     logits = jnp.einsum("bod,vd->bov", x.astype(dt),
                         params["embed"].astype(dt),
                         preferred_element_type=jnp.float32)
     return logits[:, 0], {ka: ck, kb: cv,
-                          "pos": cache["pos"] + T0}
+                          "pos": cache["pos"] + T0,
+                          **_routed(cfg, routed)}
 
 
 def _resolve_max_len(cfg, T0, max_new_tokens, max_len):
@@ -1333,6 +1511,7 @@ def make_decode_step(mesh, cfg: TransformerConfig, quantize=None):
 
     _needs_slots(cfg, "make_decode_step (dp/tp sharding of the "
                       "state)")
+    _needs_uniform(cfg, "make_decode_step (dp/tp sharding)")
     axes = {a: mesh.shape.get(a, 1) > 1 for a in mesh.axis_names}
     if axes.get("ep") and cfg.moe_every:
         raise NotImplementedError(
@@ -1431,6 +1610,7 @@ def transformer_beam_search(params: Dict, cfg: TransformerConfig,
     """
     _needs_slots(cfg, "beam search (reordering beams copies "
                       "their states)")
+    _needs_uniform(cfg, "beam search")
     B, T0 = prompt.shape
     W = int(beam_width)
     if W < 1:

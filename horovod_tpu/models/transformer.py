@@ -18,6 +18,7 @@ the tests compare against.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -29,6 +30,50 @@ from ..common.exceptions import HorovodTpuError
 from ..parallel import moe as moe_mod
 from ..parallel import sequence as seq_mod
 from . import layers as L
+
+
+@dataclasses.dataclass(frozen=True)
+class Rotary:
+    """A rotary embedding's form.  `share` of a head's dims rotate (the
+    leading ones, as interleaved pairs) and the rest pass unrotated.
+    `yarn_factor` > 0 is YaRN as published: pair i of n turns at
+    `(f_i / factor) r_i + f_i (1 - r_i)` with `f_i = theta^(-i/n)` and
+    `r_i` a ramp from pair `lo` to pair `hi`, the pairs that turn
+    `beta_fast` and `beta_slow` times over `yarn_original` positions;
+    cos and sin are multiplied by `attention_factor`."""
+    theta: float = 10000.0
+    share: float = 1.0
+    yarn_factor: float = 0.0
+    yarn_original: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def tables(self, d_head: int):
+        """(freqs [n] float64 numpy, n): the angle a position each of the
+        n = d_head * share / 2 rotated pairs turns by."""
+        import numpy as np
+        n = int(d_head * self.share) // 2
+        f = self.theta ** (-np.arange(n, dtype=np.float64) / n)
+        if self.yarn_factor:
+            def pair(beta):     # the pair that turns `beta` times
+                return n * math.log(self.yarn_original
+                                    / (2 * math.pi * beta)) \
+                    / math.log(self.theta)
+            lo = max(math.floor(pair(self.yarn_beta_fast)), 0)
+            hi = min(math.ceil(pair(self.yarn_beta_slow)), 2 * n - 1)
+            r = np.clip((np.arange(n) - lo) / max(hi - lo, 1e-3), 0, 1)
+            f = (f / self.yarn_factor) * r + f * (1 - r)
+        return f, n
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnSpec:
+    """What one kind of attention layer of a patterned model has of its
+    own: query heads, window (0 = the whole context) and rotary form."""
+    n_heads: int
+    window: int = 0
+    rotary: Rotary = Rotary()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,8 +101,42 @@ class TransformerConfig:
     # (make_train_step).
     attn_kind: str = "softmax"
     state_dtype: Any = jnp.float32   # what the retention state is held in
+    # -- a layer PATTERN (models/decode.py, "Patterned models") ----------
+    # `layer_attn[l]` names layer l's kind of attention, a key of
+    # `attn_specs` (heads, window and rotary form by kind: `n_heads`,
+    # `attn_window` and `rope_theta` above then say nothing), and
+    # `layer_mlp[l]` its MLP: "dense" (SwiGLU of `d_ff`) or "experts":
+    # `experts_per_token` of `n_experts` routed SwiGLU experts of width
+    # `expert_ff`, sigmoid scores renormalised over the chosen ones and
+    # times `routed_scale`, the weight on the output, beside one shared
+    # SwiGLU of `shared_ff` (0: none).  `experts_held` is the range
+    # [lo, hi) of experts whose weights are here (None: all); the router
+    # keeps its width and the layer computes its own experts' part of
+    # the result.  `attn_gate`: a sigmoid gate a head on the attention
+    # output.  Empty tuples: one kind for all layers, everything as it
+    # was.  Served and generated; not trained (make_train_step), where
+    # `moe_every` / `capacity_factor` still mean the top-1 layer.
+    layer_attn: Tuple[str, ...] = ()
+    layer_mlp: Tuple[str, ...] = ()
+    attn_specs: Tuple[Tuple[str, AttnSpec], ...] = ()
+    attn_gate: bool = False
+    experts_per_token: int = 1
+    expert_ff: int = 0
+    shared_ff: int = 0
+    routed_scale: float = 1.0
+    experts_held: Optional[Tuple[int, int]] = None
+    # The rotary form of a uniform model whose form is not the plain one
+    # (`rope_theta` alone); a patterned model's layers get theirs from
+    # `attn_specs`.
+    rotary: Optional[Rotary] = None
+    # "auto": the prompt's attention is dense under T 16384 and the flash
+    # kernel from there (parallel/sequence.py).  "flash": the kernel at
+    # every length from 128 on, the prompt padded to its tile; a
+    # patterned model's layers take it, so that no [H, T, T] is kept.
+    prompt_attention: str = "auto"
 
     def __post_init__(self):
+        self._check_pattern()
         if self.attn_kind not in ("softmax", "retention"):
             raise ValueError(
                 f"attn_kind must be 'softmax' or 'retention', got "
@@ -83,12 +162,162 @@ class TransformerConfig:
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
+    # -- the pattern -----------------------------------------------------
+
+    @property
+    def patterned(self) -> bool:
+        return bool(self.layer_attn)
+
+    def _check_pattern(self) -> None:
+        if self.prompt_attention not in ("auto", "flash"):
+            raise ValueError(
+                f"prompt_attention must be 'auto' or 'flash', got "
+                f"{self.prompt_attention!r}")
+        if not self.patterned:
+            if self.layer_mlp or self.attn_specs:
+                raise ValueError(
+                    "layer_mlp and attn_specs come with layer_attn (a "
+                    "kind of attention a layer)")
+            return
+        if self.attn_kind != "softmax" or self.moe_every:
+            raise ValueError(
+                "a layer pattern is of softmax layers, with its own "
+                "experts: attn_kind 'softmax' and moe_every 0")
+        specs = dict(self.attn_specs)
+        if len(self.layer_attn) != self.n_layers or \
+                len(self.layer_mlp) != self.n_layers:
+            raise ValueError(
+                f"layer_attn and layer_mlp name every layer: "
+                f"{len(self.layer_attn)} and {len(self.layer_mlp)} names "
+                f"for n_layers {self.n_layers}")
+        for t in self.layer_attn:
+            if t not in specs:
+                raise ValueError(
+                    f"layer_attn names {t!r}, attn_specs has "
+                    f"{sorted(specs)}")
+        for t, spec in specs.items():
+            if spec.n_heads % self.kv_heads:
+                raise ValueError(
+                    f"attn_specs[{t!r}]: {spec.n_heads} heads over "
+                    f"{self.kv_heads} kv heads")
+            if int(self.d_head * spec.rotary.share) % 2:
+                raise ValueError(
+                    f"attn_specs[{t!r}]: rotary share "
+                    f"{spec.rotary.share} of d_head {self.d_head} is no "
+                    "whole number of pairs")
+        for m in self.layer_mlp:
+            if m not in ("dense", "experts"):
+                raise ValueError(
+                    f"layer_mlp is 'dense' or 'experts', got {m!r}")
+        if "experts" in self.layer_mlp:
+            lo, hi = self.held
+            if not (0 <= lo < hi <= self.n_experts):
+                raise ValueError(
+                    f"experts_held {self.experts_held} is no range of "
+                    f"the {self.n_experts} experts")
+            if not 1 <= self.experts_per_token <= self.n_experts \
+                    or self.expert_ff < 1:
+                raise ValueError(
+                    f"experts_per_token {self.experts_per_token} of "
+                    f"{self.n_experts} experts of width {self.expert_ff}")
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """[lo, hi): the experts whose weights are here."""
+        return self.experts_held or (0, self.n_experts)
+
+    def attn_kinds(self) -> Tuple[str, ...]:
+        """The kinds of attention layer, in order of first use."""
+        return tuple(dict.fromkeys(self.layer_attn))
+
+    def mlp_kinds(self) -> Tuple[str, ...]:
+        return tuple(dict.fromkeys(self.layer_mlp))
+
+    def kind_cfg(self, kind: str) -> "TransformerConfig":
+        """The uniform configuration of this model's `kind` attention
+        layers alone: what `_decode_layer` and `_prefill_layer` are
+        handed for such a layer, and what sizes its part of the cache
+        (`n_layers` of it is the number of such layers)."""
+        return _kind_cfg(self, kind)
+
+
+@functools.lru_cache(maxsize=None)
+def _kind_cfg(cfg: TransformerConfig, kind: str) -> TransformerConfig:
+    spec = dict(cfg.attn_specs)[kind]
+    plain = spec.rotary == Rotary(theta=spec.rotary.theta)
+    return dataclasses.replace(
+        cfg, n_heads=spec.n_heads, attn_window=spec.window,
+        rope_theta=spec.rotary.theta,
+        rotary=None if plain else spec.rotary,
+        n_layers=cfg.layer_attn.count(kind), prompt_attention="flash",
+        layer_attn=(), layer_mlp=(), attn_specs=())
+
+
+def _refuse_pattern(cfg: TransformerConfig, what: str) -> None:
+    if cfg.patterned:
+        raise HorovodTpuError(
+            f"{what}: a model with a layer pattern (layer_attn) is served "
+            "and generated (InferenceServer, transformer_generate), not "
+            "trained")
+
 
 # ---------------------------------------------------------------------------
 # Init — layer-stacked params [L, ...] (scan- and pipeline-friendly)
 # ---------------------------------------------------------------------------
 
+def _pattern_init(key, cfg: TransformerConfig) -> Dict:
+    """A patterned model's tree: leaves stacked by layer KIND, `attn[t]`
+    over the layers of attention kind t and `mlp[m]` over those of MLP
+    kind m, each in the order the layers come.  Normal, 1/sqrt(fan_in);
+    the experts' weights are those held here, [n, hi - lo, ...]."""
+    D, Dh, Hkv = cfg.d_model, cfg.d_head, cfg.kv_heads
+    s_d = 1.0 / math.sqrt(D)
+
+    def norm(k, shape, scale):
+        return jax.random.normal(k, shape, jnp.float32) * scale
+
+    def swiglu(k, lead, F):
+        ks = jax.random.split(k, 3)
+        return {"wi": norm(ks[0], lead + (D, F), s_d),
+                "wg": norm(ks[1], lead + (D, F), s_d),
+                "wd": norm(ks[2], lead + (F, D), 1.0 / math.sqrt(F))}
+
+    params = {
+        "embed": norm(jax.random.fold_in(key, 0), (cfg.vocab_size, D), s_d),
+        "final_norm": {"scale": jnp.ones((D,), jnp.float32)},
+        "attn": {}, "mlp": {}}
+    for n, t in enumerate(cfg.attn_kinds()):
+        ks = jax.random.split(jax.random.fold_in(key, 10 + n), 5)
+        Lt, H = cfg.layer_attn.count(t), dict(cfg.attn_specs)[t].n_heads
+        ap = {"ln1": {"scale": jnp.ones((Lt, D), jnp.float32)},
+              "wq": norm(ks[0], (Lt, D, H, Dh), s_d),
+              "wk": norm(ks[1], (Lt, D, Hkv, Dh), s_d),
+              "wv": norm(ks[2], (Lt, D, Hkv, Dh), s_d),
+              "wo": norm(ks[3], (Lt, H, Dh, D), 1.0 / math.sqrt(H * Dh))}
+        if cfg.attn_gate:
+            ap["w_gate"] = norm(ks[4], (Lt, D, H), s_d)
+        params["attn"][t] = ap
+    for n, m in enumerate(cfg.mlp_kinds()):
+        k = jax.random.fold_in(key, 20 + n)
+        Lm = cfg.layer_mlp.count(m)
+        ln2 = {"scale": jnp.ones((Lm, D), jnp.float32)}
+        if m == "dense":
+            params["mlp"][m] = {"ln2": ln2, **swiglu(k, (Lm,), cfg.d_ff)}
+            continue
+        ks = jax.random.split(k, 3)
+        lo, hi = cfg.held
+        mp = {"ln2": ln2,
+              "router": norm(ks[0], (Lm, D, cfg.n_experts), s_d),
+              "experts": swiglu(ks[1], (Lm, hi - lo), cfg.expert_ff)}
+        if cfg.shared_ff:
+            mp["shared"] = swiglu(ks[2], (Lm,), cfg.shared_ff)
+        params["mlp"][m] = mp
+    return params
+
+
 def transformer_init(key, cfg: TransformerConfig) -> Dict:
+    if cfg.patterned:
+        return _pattern_init(key, cfg)
     keys = jax.random.split(key, 8)
     D, H, Dh, F, Lr = (cfg.d_model, cfg.n_heads, cfg.d_head, cfg.d_ff,
                        cfg.n_layers)
@@ -167,6 +396,7 @@ def _rmsnorm(scale, x):
 def _attention_block(lp, x, positions, cfg, tp_axis, sp_axis):
     """Pre-norm attention with RoPE.  lp: this layer's params (unstacked).
     Inside shard_map: heads sharded over tp, sequence over sp."""
+    _refuse_pattern(cfg, "the training forward")
     if cfg.attn_kind != "softmax":
         raise HorovodTpuError(
             f"the training forward has no {cfg.attn_kind!r} layer: such "
@@ -235,6 +465,7 @@ def _moe_block(mp, scale, x, cfg, ep_axis):
 
 def transformer_ref_apply(params: Dict, tokens, cfg: TransformerConfig):
     """tokens [B, T] → logits [B, T, V]; returns (logits, aux_loss)."""
+    _refuse_pattern(cfg, "the training forward")
     x = params["embed"][tokens].astype(cfg.compute_dtype)
     positions = jnp.arange(tokens.shape[1])
     aux_total = jnp.zeros((), jnp.float32)
@@ -474,6 +705,7 @@ def make_train_step(mesh, cfg: TransformerConfig, optimizer,
     from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    _refuse_pattern(cfg, "make_train_step")
     if cfg.attn_kind != "softmax":
         raise HorovodTpuError(
             f"make_train_step: attn_kind {cfg.attn_kind!r} is not "

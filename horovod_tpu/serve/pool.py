@@ -1,6 +1,8 @@
 """The serving engine's caches: what `InferenceServer` asks of one
-(`DecodeCache`), the two that answer it (`PagedKVPool` for keys and
-values, `StateSlots` for a retention model's state a row), and
+(`DecodeCache`), the three that answer it (`PagedKVPool` for keys and
+values, `StateSlots` for a retention model's state a row, `WindowedKVPool`
+for a patterned model: pages for the layers that see the whole context,
+one ring of `window` slots a row for the layers that see a window), and
 `make_cache`, which picks one from the model's configuration.
 
 A contiguous decode cache ties a sequence's KV bytes to its batch row
@@ -243,9 +245,15 @@ class PagedKVPool(DecodeCache):
         scratch = init_decode_cache(
             self.cfg, 1, len(pids) * self.page_tokens, self.quantize)
         lg, scratch = prefill(params, scratch, jnp.asarray(prompt[None]))
-        self.scatter_pages(req_id, scratch["k"], scratch["v"])
-        self._row_seq[row] = self._boarded[row] = req_id
+        self.seat(req_id, row, scratch["k"], scratch["v"])
         return lg
+
+    def seat(self, req_id, row, cache_k, cache_v) -> None:
+        """A prefilled batch-1 cache into the request's pages, and the
+        request into `row`, whose slice of the view the next `refresh`
+        gathers."""
+        self.scatter_pages(req_id, cache_k, cache_v)
+        self._row_seq[row] = self._boarded[row] = req_id
 
     def release(self, req_id, row) -> None:
         self.free(req_id)
@@ -467,15 +475,107 @@ class StateSlots(DecodeCache):
         return self.rows_held() / self.rows
 
 
+class WindowedKVPool(DecodeCache):
+    """The cache of a patterned model (models/decode.py, `_empty_pattern`):
+    two kinds of cache under one manager.
+
+    The layers without a window keep what `PagedKVPool` keeps, in a pool
+    of their own over those layers alone (`pool`): pages, a gathered view,
+    a slot written through a step.  Admission counts THEIR pages and
+    nothing else.  The layers with a window keep one ring of `window`
+    slots a row and layer, written at ``pos % window``; like a retention
+    model's state it is held ONCE (`rings`, beside the pool's view),
+    never paged, never gathered and never written back: boarding writes
+    the ring a prefill left (a prompt's last `window` tokens) into its
+    row's slot whole, so a row reused after another request carries
+    nothing over.  Quantized layouts and speculation are refused here
+    (known gaps, by name)."""
+
+    def __init__(self, cfg, total_pages: int, page_tokens: int,
+                 quantize: Optional[str] = None, rows: int = 0,
+                 view_pages: int = 0, speculative: bool = False):
+        for what, asked in (
+                ("quantize", quantize is not None),
+                ("draft_params (speculative serving: the verify pass "
+                 "extends a window layer's ring by a chunk)", speculative)):
+            if asked:
+                raise InvalidRequestError(
+                    f"{what} is not supported for a model with a layer "
+                    "pattern (layer_attn)")
+        super().__init__(cfg)
+        windows = {t: cfg.kind_cfg(t).attn_window for t in cfg.attn_kinds()}
+        self.ringed = tuple(t for t, w in windows.items() if w)
+        paged = [t for t, w in windows.items() if not w]
+        if len(paged) != 1:
+            raise InvalidRequestError(
+                f"a patterned model is served with one kind of layer "
+                f"without a window (its pages), got {paged}")
+        self.paged = paged[0]
+        self.pool = PagedKVPool(cfg.kind_cfg(self.paged), total_pages,
+                                page_tokens, None, rows, view_pages)
+        self.pool.on_event = lambda *a: self.on_event and self.on_event(*a)
+        empty = init_decode_cache(cfg, rows, 1)
+        #: a leaf each, the window layers' rings by kind; None while lent
+        self.rings: Optional[Tuple] = tuple(
+            {t: empty[n][t] for t in self.ringed} for n in self.leaves)
+        #: bytes held by kind (the pages' view comes with its first gather)
+        self.ring_bytes = sum(a.nbytes for r in self.rings
+                              for a in r.values())
+        self.page_bytes = self.pool.k.nbytes + self.pool.v.nbytes
+        # what only the pages answer
+        for name in ("total_pages", "page_tokens", "pages_needed",
+                     "pages_free", "can_board", "utilization", "release",
+                     "refresh", "write_through"):
+            setattr(self, name, getattr(self.pool, name))
+
+    def board(self, req_id, row, n_tokens, params, prompt, prefill):
+        pool = self.pool
+        pids = pool.alloc(req_id, n_tokens)
+        scratch = init_decode_cache(self.cfg, 1,
+                                    len(pids) * pool.page_tokens)
+        lg, scratch = prefill(params, scratch, jnp.asarray(prompt[None]))
+        pool.seat(req_id, row, *(scratch[n][self.paged]
+                                 for n in self.leaves))
+        self.rings = tuple(
+            dict(zip(self.ringed, _state_install(
+                tuple(ring[t] for t in self.ringed),
+                tuple(scratch[n][t] for t in self.ringed),
+                jnp.int32(row))))
+            for ring, n in zip(self.rings, self.leaves))
+        self.installs += 1
+        return lg
+
+    def lend(self, pos) -> Dict:
+        full, rings = self.pool.view, self.rings
+        self.pool.view = self.rings = None
+        return {**{n: {self.paged: f, **r}
+                   for n, f, r in zip(self.leaves, full, rings)},
+                "pos": jnp.asarray(pos, jnp.int32)}
+
+    def take_back(self, cache: Dict) -> None:
+        self.pool.view = tuple(cache[n][self.paged] for n in self.leaves)
+        self.rings = tuple({t: cache[n][t] for t in self.ringed}
+                           for n in self.leaves)
+
+    def set_gauges(self) -> None:
+        self.pool.set_gauges()
+        view = sum(a.nbytes for a in self.pool.view or ())
+        _met.serve_cache_bytes.labels("pages").set(self.page_bytes + view)
+        _met.serve_cache_bytes.labels("rings").set(self.ring_bytes)
+
+
 def make_cache(cfg, *, rows, view_pages, page_tokens, pool_pages,
                quantize, speculative, rows_held) -> DecodeCache:
     """The `DecodeCache` of a model of this configuration: the one place
     under serve/ that knows which kind of attention has which cache."""
     if cfg.attn_kind == "retention":
         return StateSlots(cfg, rows, rows_held, quantize, speculative)
+    if cfg.patterned:
+        return WindowedKVPool(cfg, pool_pages, page_tokens, quantize, rows,
+                              view_pages, speculative)
     return PagedKVPool(cfg, pool_pages, page_tokens, quantize, rows,
                        view_pages)
 
 
 __all__ = ["DecodeCache", "PagedKVPool", "PoolExhaustedError",
-           "StateSlots", "make_cache"]
+           "StateSlots", "WindowedKVPool", "make_cache"]

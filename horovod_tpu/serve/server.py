@@ -70,6 +70,7 @@ from ..common.exceptions import InvalidRequestError
 from ..metrics import catalog as _met
 from ..models.decode import (_serve_step_fn, _spec_extend_fn,
                              _spec_step_fn, transformer_prefill)
+from ..models.experts import ROUTED
 from ..utils import autotune
 from ..utils.timeline import get_timeline, span
 from .flightrec import FlightRecorder
@@ -207,6 +208,13 @@ class InferenceServer:
         self.device_steps = 0
         self.spec_steps = 0
         self.occupancy_sum = 0.0
+        # What a patterned model's expert layers counted (models/experts.py
+        # `ROUTED`), summed over layers and steps: distinct experts a
+        # token chose, the most tokens one expert took, and how many
+        # (layer, step) pairs the two sums run over.
+        self.experts_hit_sum = 0
+        self.expert_load_max_sum = 0
+        self.moe_layer_steps = 0
         self.token_latencies_ms: List[float] = []
         self.request_latencies_ms: List[float] = []
 
@@ -497,8 +505,15 @@ class InferenceServer:
             self.pool.take_back(cache)
             self.pool.write_through(rows, base)
         # the step's one sync: the ids, not the logits they came from
+        # (behind them, a patterned model's routing counts a layer)
         with span("fetch", "serve", {"bytes": ids.nbytes}):
-            self._next_ids = np.array(ids)     # copy: row writes on admit
+            got = np.array(ids)                # copy: row writes on admit
+        self._next_ids = got[:self.max_batch]
+        routed = got[self.max_batch:].reshape(-1, len(ROUTED))
+        hit, fullest = routed.sum(axis=0).tolist() if len(routed) else (0, 0)
+        self.experts_hit_sum += hit          # in `ROUTED`'s order
+        self.expert_load_max_sum += fullest
+        self.moe_layer_steps += len(routed)
         for r in rows:
             self.row_pos[r] += 1
             self.sched.active[r].pos = int(self.row_pos[r])

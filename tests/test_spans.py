@@ -246,6 +246,47 @@ def test_fetch_carries_the_bytes_of_the_steps_one_sync(traced):
     assert srv.logit_fetches == 1           # kept until the next step
 
 
+def test_patterned_model_counts_its_routing_in_the_one_sync(tmp_path):
+    """A model with routed experts: the step's one sync brings the ids
+    and, behind them, two counts a sparse layer (`fetch` says how many
+    bytes), from which the server keeps `experts_hit_sum`,
+    `expert_load_max_sum` and `moe_layer_steps`; `prefill` keeps its
+    arguments, `pages` counting the full layers' pages; a uniform model
+    counts nothing."""
+    from horovod_tpu.models.transformer import AttnSpec
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, d_head=8, d_ff=64, n_layers=3,
+        n_kv_heads=2, compute_dtype=jnp.float32,
+        layer_attn=("full", "sliding", "full"),
+        layer_mlp=("dense", "experts", "experts"),
+        attn_specs=(("full", AttnSpec(4)), ("sliding", AttnSpec(6, 4))),
+        attn_gate=True, n_experts=8, experts_per_token=2, expert_ff=16,
+        shared_ff=16, routed_scale=2.5)
+    (srv, prompts, tokens), spans = _profiled(
+        tmp_path, lambda: _serve((cfg, transformer_init(
+            jax.random.PRNGKey(1), cfg))))
+    assert {r: len(t) for r, t in tokens.items()} == dict(enumerate(OUTPUTS))
+    fetches = [s for s in spans if s[0] == "hvd.serve.fetch"]
+    assert len(fetches) == srv.device_steps
+    # [max_batch] ids and (experts hit, fullest expert) of 2 sparse layers
+    assert all(f[3] == {"bytes": 4 * (2 + 2 * 2)} for f in fetches)
+    assert srv.moe_layer_steps == 2 * srv.device_steps
+    rows = srv.occupancy_sum * 2              # active rows, summed
+    assert 2 * srv.moe_layer_steps <= srv.experts_hit_sum <= 2 * 2 * rows
+    assert srv.moe_layer_steps <= srv.expert_load_max_sum <= 2 * rows
+    assert srv.logit_fetches == 0
+    for p in (s for s in spans if s[0] == "hvd.serve.prefill"):
+        assert set(p[3]) == {"req", "prompt_tokens", "row", "pages",
+                             "queue_wait_us"}
+        assert p[3]["pages"] == -(-(4 + OUTPUTS[p[3]["req"]]) // 4)
+
+
+def test_uniform_model_counts_no_routing(traced):
+    srv = traced["srv"]
+    assert (srv.experts_hit_sum, srv.expert_load_max_sum,
+            srv.moe_layer_steps) == (0, 0, 0)
+
+
 def test_speculative_round_is_one_launch(model, tmp_path):
     cfg, params = model
     (srv, _, _), spans = _profiled(

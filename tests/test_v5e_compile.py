@@ -136,6 +136,53 @@ def test_server_step_picks_its_ids_in_the_one_program(one_chip, widths,
     assert temp < _leaf_bytes(cache[leaf]) // 8, temp
 
 
+# laguna_xs2_codegen_steady's decode view (benchmark/traffic/
+# codegen_steady.json, benchmark/configs/laguna-xs2-serve.json): 32 rows,
+# the two full layers at 7168 slots, the three sliding layers' rings of
+# 512, 256 experts of 2048 x 512 in each of four sparse layers.
+PATTERN_ROWS, PATTERN_SLOTS = 32, 7168
+
+
+def test_patterned_step_copies_neither_cache_nor_experts(one_chip,
+                                                         monkeypatch):
+    """The served step of a patterned model with routed experts at the
+    cell's shapes: its temporaries (the scores of 48 heads over 7168
+    slots among them, 44 MB a full layer) stay far under ONE full
+    layer's K slice of the view, so no cache is transposed or copied out,
+    and far under one matrix of ONE layer's experts: the experts' stack
+    goes to the grouped product whole, where a layer's slice of it would
+    be copied first (537 MB a matrix, 4.4 GB of temporaries in all)."""
+    import json
+    import os
+
+    from benchmark.runners.pattern_serve import transformer_config
+    from horovod_tpu.models import experts
+    from horovod_tpu.models.decode import _serve_step_fn
+
+    monkeypatch.setattr(experts, "_interpret", lambda: False)
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "laguna-xs2-serve.json")) as f:
+        cfg = transformer_config(json.load(f))
+    args = _step_args(one_chip, cfg, PATTERN_ROWS, PATTERN_SLOTS)
+    cache = dict(args[1])
+    cache.pop("routed")             # as the server's cache lends it
+    lowered = _serve_step_fn(cfg).lower(args[0], cache, args[2])
+    logits, ids, out_cache = lowered.out_info
+    assert ids.shape == (PATTERN_ROWS + 4 * 2,)
+    assert out_cache["k"]["sliding_attention"].shape == (3, 32, 8, 512, 128)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit__lambda,")
+    assert text.count('custom_call_target="tpu_custom_call"') == 12
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    k_slice = PATTERN_ROWS * 8 * PATTERN_SLOTS * 128 * 2
+    one_matrix = 256 * 2048 * 512 * 2
+    assert cache["k"]["full_attention"].shape[1:] == (32, 8, 7168, 128)
+    assert temp < k_slice // 2, (temp, k_slice)
+    assert temp < one_matrix // 2, (temp, one_matrix)
+
+
 def test_pool_write_back_moves_slots_only(one_chip):
     """`scatter_slots`, once a step: the slots' bytes and no transposed
     copy of pool or view (layers or kv heads as window axes around the
