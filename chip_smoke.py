@@ -62,6 +62,7 @@ from horovod_tpu.models import (
 )
 from horovod_tpu.models.decode import _spec_step_fn
 from horovod_tpu.ops import pallas_kernels as pk
+from horovod_tpu.ops import decode_attention
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.ops.fused_collectives import pallas_matmul
 from horovod_tpu.parallel import (
@@ -107,6 +108,18 @@ FLASH_CASES = {
     "flash gqa4": dict(H=8, D=128, kv_heads=2),
 }
 KERNEL_SEQ, KERNEL_LONG_SEQ = 2048, 16384
+
+# The decode step's read of the view (ops/decode_attention.py), bf16, 8 kv
+# heads of 128 in a stack of 2 layers: rows at ragged depths with idle
+# ones between, a view that is no multiple of the block, a wrapped ring.
+DECODE_CASES = {
+    "decode attention g4": dict(slots=3584, group=4, window=4096,
+                                pos=[0, 1000, 0, 3583, 17, 0, 2047, 512]),
+    "decode attention g6 3840": dict(slots=3840, group=6, window=0,
+                                     pos=[3839, 0, 3600, 1, 0, 700]),
+    "decode attention wrapped": dict(slots=1024, group=4, window=1000,
+                                     pos=[5000, 1024, 0, 1023]),
+}
 
 # Server vs transformer_generate, in logit units (logits here are O(1):
 # unit-RMS activations against a 1/sqrt(d) embedding).  The server
@@ -441,9 +454,36 @@ def _attention_case(attn, B, T, H, D, kv_heads=None, window=None,
     return max(_rel_err(g, w) for g, w in zip(got, want))
 
 
-def phase_kernels(seq: int, long_seq: int, flash_cases: dict) -> dict:
+def _decode_case(slots, group, window, pos, kv_heads=8, d_head=128,
+                 block=decode_attention.BLOCK):
+    """`decode_attention` at layer 1 of a stack of 2 against the plain
+    softmax over every slot, masked on the slot's absolute position."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    B = len(pos)
+    q = jax.random.normal(ks[0], (B, kv_heads, group, d_head), jnp.bfloat16)
+    ck, cv = (jax.random.normal(k, (2, B, kv_heads, slots, d_head),
+                                jnp.bfloat16) for k in ks[1:])
+    pos = jnp.asarray(pos, jnp.int32)
+    got = jax.jit(lambda *a: decode_attention.decode_attention(
+        *a, window=window, block=block))(q, ck, cv, 1, pos)
+    held = pos[:, None] - (pos[:, None] - jnp.arange(slots)[None]) % slots
+    valid = held >= 0
+    if window:
+        valid &= pos[:, None] - held < window
+    with jax.default_matmul_precision("highest"):
+        s = jnp.einsum("bhgd,bhkd->bhgk", q.astype(jnp.float32),
+                       ck[1].astype(jnp.float32)) / d_head ** 0.5
+        p = jax.nn.softmax(jnp.where(valid[:, None, None], s, -1e30), -1)
+        want = jnp.einsum("bhgk,bhkd->bhgd", p, cv[1].astype(jnp.float32))
+    return _rel_err(got, want)
+
+
+def phase_kernels(seq: int, long_seq: int, flash_cases: dict,
+                  decode_cases: dict) -> dict:
     errs = {name: _attention_case(flash_attention, 1, seq, **case)
             for name, case in flash_cases.items()}
+    errs.update((name, _decode_case(**case))
+                for name, case in decode_cases.items())
     if long_seq:
         # Nothing forced: full_attention must pick the kernel by itself.
         probe = jax.ShapeDtypeStruct((1, long_seq, 1, 128), jnp.bfloat16)
@@ -516,7 +556,8 @@ def main() -> int:
     report("server", phase_server(cfg, tol=SERVE_LOGIT_TOL, **SERVE))
     report("decode layout", phase_decode_layout(cfg, **DECODE_VIEW))
     report("kernels",
-           phase_kernels(KERNEL_SEQ, KERNEL_LONG_SEQ, FLASH_CASES))
+           phase_kernels(KERNEL_SEQ, KERNEL_LONG_SEQ, FLASH_CASES,
+                         DECODE_CASES))
 
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind, "count": n}}),

@@ -31,8 +31,12 @@ negligible at decode and acceptable at prefill for modest E).
 Layout: cache k/v are HEAD-MAJOR, [L, B, Hkv, max_len, Dh] in
 `cfg.compute_dtype` (a quantized cache's scales [L, B, Hkv, max_len]),
 `pos` a scalar int32 count of tokens already absorbed.  The one reader
-of a layer's keys and values is the pair of contractions in
-`_decode_layer`, which want each kv head's slots contiguous.  The v5e
+of a layer's keys and values is `_decode_layer`: the kernel of
+ops/decode_attention.py, which reads each row's LIVE blocks of slots
+out of the stack (one query a row over a plain ring of two blocks or
+more), or else the pair of contractions over every slot
+(`_attend_view`: a chunk, a quantized layout, a small ring).  Both
+want each kv head's slots contiguous.  The v5e
 compiler fuses a `dynamic-slice` of the carried stack into a
 contraction's operand, or a change of layout, never both: from a
 slot-major cache ([L, B, max_len, Hkv, Dh]) every layer's K and V were
@@ -97,6 +101,7 @@ import numpy as np
 from jax import lax
 
 from ..common.exceptions import InvalidRequestError
+from ..ops import decode_attention
 from ..parallel import sequence as seq_mod
 from . import experts as experts_mod
 from .transformer import (
@@ -350,57 +355,17 @@ def _slot_positions(pos, S):
     return pos - ((pos - j) % S)
 
 
-def _decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
-                  tp_axis=None):
-    """Layer `i`'s attention for a CHUNK of c new token positions
-    (c == 1 is the plain decode step; c > 1 serves `transformer_extend`
-    and the speculative verify pass).
-
-    x [B, c, D]; ck/cv [L, B, Hkv, S, Dh], the WHOLE stacked cache
-    (LOCAL head counts under tensor parallelism; head dims are derived
-    from the weights, not cfg, so tp shards just work); `i` the layer's
-    index into it, a traced scalar under the scan or a Python int.
-    Returns (x, ck, cv): the same stacked arrays with only slots
-    `pos % S .. (pos+c-1) % S` of layer `i` overwritten — B x c vectors
-    written in place, nothing else of the cache moved — and attention
-    reads layer `i` out of them.  Chunks with c > 1 must not wrap the
-    ring (the c == 1 step may).
-
-    `pos` may be a SCALAR (all rows at the same depth — the classic
-    batch path) or a [B] VECTOR (each row at its own depth — the
-    continuous-batching serving path): rope angles, ring slots, and the
-    causal mask are then computed per row.  With equal entries the
-    vector path is bitwise-identical to the scalar path (same
-    elementwise ops, broadcast vs materialized operands;
-    tests/test_decode.py::test_layer_walk_in_place).
-    """
-    dt = cfg.compute_dtype
-    B, S = x.shape[0], cache_slots(ck)
-    Dh = cfg.d_head
-    c = x.shape[1]
-
-    h = _rmsnorm(lp["ln1"]["scale"], x)
-    q = jnp.einsum("bod,dhk->bohk", h, lp["wq"].astype(dt))
-    k = jnp.einsum("bod,dhk->bohk", h, lp["wk"].astype(dt))
-    v = jnp.einsum("bod,dhk->bohk", h, lp["wv"].astype(dt))
-    Hq, Hkv = q.shape[2], k.shape[2]
-    g = Hq // Hkv
-    pos = jnp.asarray(pos)
+def _attend_view(qg, ck, cv, i, pos, positions, cfg: TransformerConfig):
+    """The read side of `_decode_layer` as two contractions over EVERY
+    slot of the view: qg [B, c, Hkv, g, Dh] against layer `i` of the
+    stacked leaves, masked on each slot's reconstructed absolute
+    position.  Returns o [B, c, Hkv, g, Dh] float32.  The path of a
+    chunk (c > 1), of the quantized layouts and of a ring of one block
+    or less; a plain ring that spans more is read by
+    ops/decode_attention.py, live slots only."""
+    B, c, Dh = qg.shape[0], qg.shape[1], qg.shape[-1]
+    S = cache_slots(ck)
     vec = pos.ndim == 1
-    if vec:
-        positions = pos[:, None] + jnp.arange(c)[None, :]   # [B, c]
-        q = _rotate(q, positions, cfg).astype(dt)
-        k = _rotate(k, positions, cfg).astype(dt)
-        ck = _cache_write_rows(ck, i, k, pos % S)
-        cv = _cache_write_rows(cv, i, v, pos % S)
-    else:
-        positions = pos + jnp.arange(c)                # [c]
-        q = _rotate(q, positions, cfg).astype(dt)
-        k = _rotate(k, positions, cfg).astype(dt)
-        slot = pos % S
-        ck = _cache_write(ck, i, k, slot)
-        cv = _cache_write(cv, i, v, slot)
-
     # Grouped attention against the ring: q [B,c,Hkv,g,Dh] x
     # cache [B,Hkv,S,Dh] — the repeated kv heads never materialize, and
     # the layer's slice of the stack is the operand as it lies.
@@ -408,7 +373,6 @@ def _decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
     # contractions (scale is constant over Dh), so they multiply the
     # [..,S]-shaped scores/probs instead of a Dh-times-larger
     # dequantized cache copy.
-    qg = q.reshape(B, c, Hkv, g, Dh)
     lk, lv = _cache_layer(ck, i), _cache_layer(cv, i)
     if isinstance(lk, dict):
         s = jnp.einsum("bqhgd,bhkd->bhgqk", qg.astype(jnp.float32),
@@ -450,6 +414,72 @@ def _decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
     else:
         o = jnp.einsum("bhgqk,bhkd->bqhgd", p,
                        lv.astype(jnp.float32))
+    return o
+
+
+def _decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
+                  tp_axis=None):
+    """Layer `i`'s attention for a CHUNK of c new token positions
+    (c == 1 is the plain decode step; c > 1 serves `transformer_extend`
+    and the speculative verify pass).
+
+    x [B, c, D]; ck/cv [L, B, Hkv, S, Dh], the WHOLE stacked cache
+    (LOCAL head counts under tensor parallelism; head dims are derived
+    from the weights, not cfg, so tp shards just work); `i` the layer's
+    index into it, a traced scalar under the scan or a Python int.
+    Returns (x, ck, cv): the same stacked arrays with only slots
+    `pos % S .. (pos+c-1) % S` of layer `i` overwritten — B x c vectors
+    written in place, nothing else of the cache moved — and attention
+    reads layer `i` out of them: shape and type pick the reader, the
+    kernel over each row's live blocks or `_attend_view` over every
+    slot.  Chunks with c > 1 must not wrap the ring (the c == 1 step
+    may).
+
+    `pos` may be a SCALAR (all rows at the same depth — the classic
+    batch path) or a [B] VECTOR (each row at its own depth — the
+    continuous-batching serving path): rope angles, ring slots, and the
+    causal mask are then computed per row.  With equal entries the
+    vector path is bitwise-identical to the scalar path (same
+    elementwise ops, broadcast vs materialized operands;
+    tests/test_decode.py::test_layer_walk_in_place).
+    """
+    dt = cfg.compute_dtype
+    B, S = x.shape[0], cache_slots(ck)
+    Dh = cfg.d_head
+    c = x.shape[1]
+
+    h = _rmsnorm(lp["ln1"]["scale"], x)
+    q = jnp.einsum("bod,dhk->bohk", h, lp["wq"].astype(dt))
+    k = jnp.einsum("bod,dhk->bohk", h, lp["wk"].astype(dt))
+    v = jnp.einsum("bod,dhk->bohk", h, lp["wv"].astype(dt))
+    Hq, Hkv = q.shape[2], k.shape[2]
+    g = Hq // Hkv
+    pos = jnp.asarray(pos)
+    vec = pos.ndim == 1
+    if vec:
+        positions = pos[:, None] + jnp.arange(c)[None, :]   # [B, c]
+        q = _rotate(q, positions, cfg).astype(dt)
+        k = _rotate(k, positions, cfg).astype(dt)
+        ck = _cache_write_rows(ck, i, k, pos % S)
+        cv = _cache_write_rows(cv, i, v, pos % S)
+    else:
+        positions = pos + jnp.arange(c)                # [c]
+        q = _rotate(q, positions, cfg).astype(dt)
+        k = _rotate(k, positions, cfg).astype(dt)
+        slot = pos % S
+        ck = _cache_write(ck, i, k, slot)
+        cv = _cache_write(cv, i, v, slot)
+
+    qg = q.reshape(B, c, Hkv, g, Dh)
+    if c == 1 and not isinstance(ck, dict) and decode_attention.reads_live(S):
+        # one query a row over a plain ring of several blocks: the
+        # kernel reads each row's live blocks and no other (a scalar
+        # `pos` is a [B] of equal entries)
+        o = decode_attention.decode_attention(
+            qg[:, 0], ck, cv, i, jnp.broadcast_to(pos, (B,)),
+            window=cfg.attn_window)
+    else:
+        o = _attend_view(qg, ck, cv, i, pos, positions, cfg)
     o = o.reshape(B, c, Hq, Dh)
     if "w_gate" in lp:
         o = _head_gate(lp, h, o, cfg)

@@ -52,6 +52,7 @@ from jax import lax
 from ..common.exceptions import HorovodTpuError, InvalidRequestError
 from ..metrics import catalog as _met
 from ..models.decode import cache_leaves, cache_slots, init_decode_cache
+from ..ops import decode_attention
 from ..utils.timeline import span
 
 
@@ -164,6 +165,9 @@ class DecodeCache:
         dict a step program takes, and what it returned;
       - ``write_through(rows, positions)``: the view's `rows` were
         stepped at ``positions[row]``; carry that to where it is kept;
+      - ``view_read_pct(positions)``: the share of the view's blocks
+        that a step at these positions reads, %; None from a cache that
+        keeps no slot a token;
       - ``utilization()``, ``set_gauges()``, ``state_bytes``,
         ``installs``, and ``on_event``, which a cache with pages calls
         with (event, req_id, n_pages, pages_free).
@@ -189,6 +193,9 @@ class DecodeCache:
 
     def take_back(self, cache: Dict) -> None:
         self.view = tuple(cache[n] for n in self.leaves)
+
+    def view_read_pct(self, positions) -> Optional[float]:
+        return None
 
     def set_gauges(self) -> None:
         _met.serve_state_bytes.set(self.state_bytes)
@@ -274,6 +281,17 @@ class PagedKVPool(DecodeCache):
         ring = self.view_pages * self.page_tokens
         self.scatter_slots(*self.view, [self._row_seq[r] for r in rows],
                            rows, [int(positions[r]) % ring for r in rows])
+
+    def view_read_pct(self, positions) -> float:
+        """What models/decode.py `_decode_layer` reads of the view in a
+        step over rows at `positions` (host integers, 0 for an idle
+        row): each row's live blocks where the kernel runs
+        (ops/decode_attention.py), every slot under the einsum."""
+        slots = self.view_pages * self.page_tokens
+        if self.quantize is not None or not decode_attention.reads_live(
+                slots):
+            return 100.0
+        return decode_attention.read_pct(positions, slots)
 
     def set_gauges(self) -> None:
         super().set_gauges()
@@ -525,7 +543,7 @@ class WindowedKVPool(DecodeCache):
         # what only the pages answer
         for name in ("total_pages", "page_tokens", "pages_needed",
                      "pages_free", "can_board", "utilization", "release",
-                     "refresh", "write_through"):
+                     "refresh", "write_through", "view_read_pct"):
             setattr(self, name, getattr(self.pool, name))
 
     def board(self, req_id, row, n_tokens, params, prompt, prefill):

@@ -494,10 +494,13 @@ class InferenceServer:
         return finished
 
     def _plain_step(self, rows: Sequence[int], feed: np.ndarray) -> None:
-        with span("launch", "serve"):      # dispatches only, no wait
+        base = self.row_pos.copy()
+        read = self.pool.view_read_pct(base)
+        with span("launch", "serve",       # dispatches only, no wait
+                  None if read is None else
+                  {"view_read_pct": round(read, 2)}):
             for cache, _ in self._caches:
                 cache.refresh()
-            base = self.row_pos.copy()
             self._logits, ids, cache = _serve_step_fn(self.cfg)(
                 self.params, self.pool.lend(base),
                 jnp.asarray(feed, jnp.int32))

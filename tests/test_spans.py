@@ -3,6 +3,7 @@ the primitive alone, then a tiny server run inside a real `jax.profiler`
 session and read back with `jax.profiler.ProfileData`, the same server
 with `HOROVOD_TIMELINE` on as well, and with neither on."""
 
+import dataclasses
 import glob
 import os
 
@@ -246,6 +247,44 @@ def test_fetch_carries_the_bytes_of_the_steps_one_sync(traced):
     assert srv.logit_fetches == 1           # kept until the next step
 
 
+def test_launch_carries_the_view_read_share(model, traced, tmp_path):
+    """`hvd.serve.launch` says which share of the view's blocks the step's
+    attention reads (`view_read_pct`, from `row_pos` on the host): all of
+    a view of one block or less, which the einsum reads whole; a
+    retention server keeps no slots and says nothing."""
+    launches = [s for s in traced["spans"] if s[0] == "hvd.serve.launch"]
+    assert len(launches) == traced["srv"].device_steps
+    assert all(s[3] == {"view_read_pct": 100.0} for s in launches)
+    events = [e for e in traced["events"] if e["name"] == "launch"]
+    assert [e["args"] for e in events] == \
+        [{"view_read_pct": 100.0}] * len(launches)
+    cfg, _ = model
+    rcfg = dataclasses.replace(cfg, attn_kind="retention", n_kv_heads=2)
+    (srv, _, _), spans = _profiled(tmp_path, lambda: _serve(
+        (rcfg, transformer_init(jax.random.PRNGKey(0), rcfg))))
+    launches = [s for s in spans if s[0] == "hvd.serve.launch"]
+    assert len(launches) == srv.device_steps > 0
+    assert all(s[3] == {} for s in launches)
+
+
+@pytest.mark.parametrize("kw,positions,want", [
+    # three blocks of 512 a row: 0 + 1 + 2 + 3 of 4 x 3
+    (dict(view_pages=96), [0, 5, 600, 1535], 50.0),
+    (dict(view_pages=96), [0, 0, 0, 0], 0.0),
+    (dict(view_pages=96), [4000, 1536, 1, 511], 100.0 * 8 / 12),
+    (dict(view_pages=60), [0, 5, 600, 900], 100.0),     # under two blocks
+    (dict(view_pages=96, quantize="int8"), [0, 5, 600, 1535], 100.0),
+], ids=["ragged", "idle", "wrapped", "einsum_small", "einsum_quantized"])
+def test_paged_cache_reckons_the_blocks_a_step_reads(model, kw, positions,
+                                                      want):
+    """`PagedKVPool.view_read_pct`: the kernel's block count where the
+    shape rule of models/decode.py picks the kernel, 100 where it picks
+    the einsum; on the host, no array of the device touched."""
+    from horovod_tpu.serve.pool import PagedKVPool
+    pool = PagedKVPool(model[0], 8, 16, rows=4, **kw)
+    assert pool.view_read_pct(np.asarray(positions)) == pytest.approx(want)
+
+
 def test_patterned_model_counts_its_routing_in_the_one_sync(tmp_path):
     """A model with routed experts: the step's one sync brings the ids
     and, behind them, two counts a sparse layer (`fetch` says how many
@@ -275,6 +314,9 @@ def test_patterned_model_counts_its_routing_in_the_one_sync(tmp_path):
     assert 2 * srv.moe_layer_steps <= srv.experts_hit_sum <= 2 * 2 * rows
     assert srv.moe_layer_steps <= srv.expert_load_max_sum <= 2 * rows
     assert srv.logit_fetches == 0
+    # the pages' view answers for the view (24 slots: the einsum's)
+    assert all(s[3] == {"view_read_pct": 100.0} for s in spans
+               if s[0] == "hvd.serve.launch")
     for p in (s for s in spans if s[0] == "hvd.serve.prefill"):
         assert set(p[3]) == {"req", "prompt_tokens", "row", "pages",
                              "queue_wait_us"}

@@ -36,8 +36,12 @@ def one_chip():
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import SingleDeviceSharding
 
+    from horovod_tpu.ops import decode_attention
+
     mp = pytest.MonkeyPatch()
     mp.setenv("TPU_LOG_DIR", "disabled")    # or the compiler logs to /tmp
+    # the decode step's kernel as the chip would get it, through Mosaic
+    mp.setattr(decode_attention, "_interpret", lambda: False)
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -76,22 +80,36 @@ def _step_args(one_chip, cfg, rows, slots, quantize=None):
     return one_chip(params), one_chip(cache), one_chip(tokens)
 
 
-@pytest.mark.parametrize("quantize", [None, "int8"], ids=["bf16", "int8"])
-def test_decode_step_reads_the_view_where_it_lies(one_chip, quantize):
+# mistral7b_doc_saturated's view (benchmark/traffic/doc_saturated.json):
+# 28 rows x 3840 slots, 7.5 of the kernel's blocks of 512.
+DOC_ROWS, DOC_SLOTS = 28, 3840
+
+
+@pytest.mark.parametrize("rows,slots,quantize,kernels", [
+    (ROWS, SLOTS, None, 1), (ROWS, SLOTS, "int8", 0),
+    (DOC_ROWS, DOC_SLOTS, None, 1)],
+    ids=["bf16", "int8", "doc-28x3840"])
+def test_decode_step_reads_the_view_where_it_lies(one_chip, rows, slots,
+                                                  quantize, kernels):
     """The served step (vector `pos`): the program's temporaries stay
     far under ONE layer's K slice of the view, so no layer's K or V is
-    copied out before its contraction (slot-major, PR 27: 235 MB of
-    them) and the per-row write does not make the compiler transpose
-    the cache (kv heads in the scatter's window: 1.9 GB)."""
+    copied out before it is read (slot-major, PR 27: 235 MB of them;
+    a slice of the stack as the kernel's operand: as much) and the
+    per-row write does not make the compiler transpose the cache (kv
+    heads in the scatter's window: 1.9 GB).  A plain view is read by
+    ops/decode_attention.py's kernel, once in the loop over layers, a
+    quantized one by the einsum."""
     from horovod_tpu.models.decode import _spec_step_fn
 
     cfg = TransformerConfig(**WIDTHS)
-    args = _step_args(one_chip, cfg, ROWS, SLOTS, quantize)
+    args = _step_args(one_chip, cfg, rows, slots, quantize)
     cache = args[1]
     compiled = _spec_step_fn(cfg).lower(*args).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == kernels
     temp = compiled.memory_analysis().temp_size_in_bytes
     k_slice = _leaf_bytes(cache["k"])
-    assert k_slice == ROWS * 8 * SLOTS * 128 * (1 if quantize else 2)
+    assert k_slice == rows * 8 * slots * 128 * (1 if quantize else 2)
     assert temp < k_slice // 8, (temp, k_slice)
 
 
@@ -146,10 +164,10 @@ PATTERN_ROWS, PATTERN_SLOTS = 32, 7168
 def test_patterned_step_copies_neither_cache_nor_experts(one_chip,
                                                          monkeypatch):
     """The served step of a patterned model with routed experts at the
-    cell's shapes: its temporaries (the scores of 48 heads over 7168
-    slots among them, 44 MB a full layer) stay far under ONE full
-    layer's K slice of the view, so no cache is transposed or copied out,
-    and far under one matrix of ONE layer's experts: the experts' stack
+    cell's shapes: its temporaries stay far under ONE full layer's K
+    slice of the view (which the kernel of ops/decode_attention.py reads
+    out of the stack, 32 x 7168 slots under groups of 6), so no cache is
+    transposed or copied out, and far under one matrix of ONE layer's experts: the experts' stack
     goes to the grouped product whole, where a layer's slice of it would
     be copied first (537 MB a matrix, 4.4 GB of temporaries in all)."""
     import json
@@ -174,7 +192,9 @@ def test_patterned_step_copies_neither_cache_nor_experts(one_chip,
     compiled = lowered.compile()
     text = compiled.as_text()
     assert text.startswith("HloModule jit__lambda,")
-    assert text.count('custom_call_target="tpu_custom_call"') == 12
+    # twelve grouped products and the two full layers' attention (48
+    # query heads on 8: groups of 6); the rings of 512 are the einsum's
+    assert text.count('custom_call_target="tpu_custom_call"') == 12 + 2
     temp = compiled.memory_analysis().temp_size_in_bytes
     k_slice = PATTERN_ROWS * 8 * PATTERN_SLOTS * 128 * 2
     one_matrix = 256 * 2048 * 512 * 2
