@@ -110,6 +110,7 @@ from .transformer import (
     _mlp_block,
     _rmsnorm,
     _rope,
+    refuse_unserved,
 )
 
 
@@ -136,7 +137,9 @@ def _kind(cfg: TransformerConfig) -> _Kind:
         lambda *a, chunk, **kw: _prefill_layer(*a, **kw))
     if cfg.patterned:
         # the layers are softmax layers, each handed its kind's uniform
-        # configuration by `_pattern_walk`; the cache is a ring a kind
+        # configuration by `_pattern_walk`; the cache is a ring a kind.
+        # A convolution kind has no record here yet (ROADMAP R2).
+        refuse_unserved(cfg, "serving and generation")
         return softmax._replace(empty=_empty_pattern)
     kinds = {
         "softmax": softmax,
@@ -1264,18 +1267,20 @@ def _spec_draft_scan(cfg: TransformerConfig, n: int, sampled: bool):
     return jax.jit(run, donate_argnums=(1,))
 
 
-def _flash_prompt(q, k, v, window):
+def _flash_prompt(q, k, v, window, blocks=None):
     """Causal attention of a whole prompt through the flash kernel
     (ops/flash_attention.py), the prompt padded to the kernel's tile of
     128: behind a causal mask what is appended changes nothing before
-    it.  No [H, T, T] scores are kept at any length."""
+    it.  No [H, T, T] scores are kept at any length.  `blocks`: the
+    kernels' tiles (None: the kernel's own default)."""
     from ..ops.flash_attention import flash_attention
     T = q.shape[1]
     pad = -T % 128
     if pad:
         q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
                    for a in (q, k, v))
-    return flash_attention(q, k, v, causal=True, window=window)[:, :T]
+    return flash_attention(q, k, v, causal=True, window=window,
+                           blocks=blocks)[:, :T]
 
 
 def _prefill_layer(lp, ck, cv, i, x, cfg: TransformerConfig,

@@ -29,6 +29,7 @@ from jax import lax
 from ..common.exceptions import HorovodTpuError
 from ..parallel import moe as moe_mod
 from ..parallel import sequence as seq_mod
+from ..utils.timeline import span
 from . import layers as L
 
 
@@ -70,10 +71,23 @@ class Rotary:
 @dataclasses.dataclass(frozen=True)
 class AttnSpec:
     """What one kind of attention layer of a patterned model has of its
-    own: query heads, window (0 = the whole context) and rotary form."""
+    own: query heads, window (0 = the whole context), rotary form, and
+    whether q and k are RMS-normed a head (a learned scale over `d_head`,
+    before the rotary embedding)."""
     n_heads: int
     window: int = 0
     rotary: Rotary = Rotary()
+    qk_norm: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class ConvSpec:
+    """A gated short convolution as a patterned model's token mixer
+    (models/pattern.py, `conv_mixer`): `(b, c, u) = split3(h W_in)`, a
+    causal depthwise convolution of `taps` taps a channel over `b * u`,
+    then `(c * v) W_out`.  No heads, no cache of keys: trained
+    (`make_train_step`), not yet served."""
+    taps: int = 3
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,7 +112,7 @@ class TransformerConfig:
     # decay(j..t) * (q.k / sqrt(d_head)) ** 2 with no softmax, q and k
     # normed per head; its cache is a fixed state a row, not a ring of
     # keys and values.  Served and generated; not trained
-    # (make_train_step).
+    # (make_train_step refuses it by name).
     attn_kind: str = "softmax"
     state_dtype: Any = jnp.float32   # what the retention state is held in
     # -- a layer PATTERN (models/decode.py, "Patterned models") ----------
@@ -113,18 +127,30 @@ class TransformerConfig:
     # [lo, hi) of experts whose weights are here (None: all); the router
     # keeps its width and the layer computes its own experts' part of
     # the result.  `attn_gate`: a sigmoid gate a head on the attention
-    # output.  Empty tuples: one kind for all layers, everything as it
-    # was.  Served and generated; not trained (make_train_step), where
-    # `moe_every` / `capacity_factor` still mean the top-1 layer.
+    # output.  `expert_bias`: the router carries a `router_bias`
+    # [n_experts] that takes part in the CHOICE of experts only (no
+    # gradient, no optimizer step); `route_eps` is added to the sum the
+    # chosen scores are renormalised by.  A kind whose spec is a
+    # `ConvSpec` is a gated short convolution in the attention's place.
+    # Empty tuples: one kind for all layers, everything as it was.
+    # Served and generated (all but a convolution kind and q/k norm,
+    # which refuse by name), and trained on a `dp` mesh
+    # (make_train_step -> models/pattern.py: every layer recomputed,
+    # attention through the flash kernel; a window, an attention gate, a
+    # shared expert and tp/sp/pp/ep over a pattern refuse by name).
+    # `moe_every` / `capacity_factor` still mean the uniform model's
+    # top-1 layer.
     layer_attn: Tuple[str, ...] = ()
     layer_mlp: Tuple[str, ...] = ()
-    attn_specs: Tuple[Tuple[str, AttnSpec], ...] = ()
+    attn_specs: Tuple[Tuple[str, Any], ...] = ()   # AttnSpec | ConvSpec
     attn_gate: bool = False
     experts_per_token: int = 1
     expert_ff: int = 0
     shared_ff: int = 0
     routed_scale: float = 1.0
     experts_held: Optional[Tuple[int, int]] = None
+    expert_bias: bool = False
+    route_eps: float = 0.0
     # The rotary form of a uniform model whose form is not the plain one
     # (`rope_theta` alone); a patterned model's layers get theirs from
     # `attn_specs`.
@@ -196,6 +222,12 @@ class TransformerConfig:
                     f"layer_attn names {t!r}, attn_specs has "
                     f"{sorted(specs)}")
         for t, spec in specs.items():
+            if isinstance(spec, ConvSpec):
+                if spec.taps < 1:
+                    raise ValueError(
+                        f"attn_specs[{t!r}]: a convolution of "
+                        f"{spec.taps} taps")
+                continue
             if spec.n_heads % self.kv_heads:
                 raise ValueError(
                     f"attn_specs[{t!r}]: {spec.n_heads} heads over "
@@ -227,8 +259,14 @@ class TransformerConfig:
         return self.experts_held or (0, self.n_experts)
 
     def attn_kinds(self) -> Tuple[str, ...]:
-        """The kinds of attention layer, in order of first use."""
+        """The kinds of attention layer (or of the convolution in its
+        place), in order of first use."""
         return tuple(dict.fromkeys(self.layer_attn))
+
+    def conv_kinds(self) -> Tuple[str, ...]:
+        specs = dict(self.attn_specs)
+        return tuple(t for t in self.attn_kinds()
+                     if isinstance(specs[t], ConvSpec))
 
     def mlp_kinds(self) -> Tuple[str, ...]:
         return tuple(dict.fromkeys(self.layer_mlp))
@@ -244,6 +282,11 @@ class TransformerConfig:
 @functools.lru_cache(maxsize=None)
 def _kind_cfg(cfg: TransformerConfig, kind: str) -> TransformerConfig:
     spec = dict(cfg.attn_specs)[kind]
+    if isinstance(spec, ConvSpec):
+        raise HorovodTpuError(
+            f"layer kind {kind!r} is a gated short convolution: it has no "
+            "attention configuration; such a model is trained "
+            "(make_train_step), not served or generated")
     plain = spec.rotary == Rotary(theta=spec.rotary.theta)
     return dataclasses.replace(
         cfg, n_heads=spec.n_heads, attn_window=spec.window,
@@ -256,9 +299,28 @@ def _kind_cfg(cfg: TransformerConfig, kind: str) -> TransformerConfig:
 def _refuse_pattern(cfg: TransformerConfig, what: str) -> None:
     if cfg.patterned:
         raise HorovodTpuError(
-            f"{what}: a model with a layer pattern (layer_attn) is served "
-            "and generated (InferenceServer, transformer_generate), not "
-            "trained")
+            f"{what}: a model with a layer pattern (layer_attn) is not "
+            "run here; it is served and generated (InferenceServer, "
+            "transformer_generate) and trained through make_train_step "
+            "on a dp mesh (models/pattern.py)")
+
+
+def refuse_unserved(cfg: TransformerConfig, what: str) -> None:
+    """What of a layer pattern is trained and not served: a convolution
+    kind (no `_Kind` record, no state beside the pages) and q/k norm."""
+    specs = dict(cfg.attn_specs)
+    if cfg.conv_kinds():
+        raise HorovodTpuError(
+            f"{what}: this layer pattern has a gated short convolution "
+            f"({', '.join(cfg.conv_kinds())}), which is trained "
+            "(make_train_step) and not served or generated: a decode "
+            "step would need its state of `taps - 1` vectors a row "
+            "beside the pages")
+    if any(specs[t].qk_norm for t in cfg.attn_kinds()):
+        raise HorovodTpuError(
+            f"{what}: this layer pattern norms q and k a head, which the "
+            "training forward does (make_train_step) and the served "
+            "layers do not yet")
 
 
 # ---------------------------------------------------------------------------
@@ -288,7 +350,16 @@ def _pattern_init(key, cfg: TransformerConfig) -> Dict:
         "attn": {}, "mlp": {}}
     for n, t in enumerate(cfg.attn_kinds()):
         ks = jax.random.split(jax.random.fold_in(key, 10 + n), 5)
-        Lt, H = cfg.layer_attn.count(t), dict(cfg.attn_specs)[t].n_heads
+        Lt, spec = cfg.layer_attn.count(t), dict(cfg.attn_specs)[t]
+        if isinstance(spec, ConvSpec):
+            params["attn"][t] = {
+                "ln1": {"scale": jnp.ones((Lt, D), jnp.float32)},
+                "w_in": norm(ks[0], (Lt, D, 3 * D), s_d),
+                "w_conv": norm(ks[1], (Lt, D, spec.taps),
+                               1.0 / math.sqrt(spec.taps)),
+                "w_out": norm(ks[2], (Lt, D, D), s_d)}
+            continue
+        H = spec.n_heads
         ap = {"ln1": {"scale": jnp.ones((Lt, D), jnp.float32)},
               "wq": norm(ks[0], (Lt, D, H, Dh), s_d),
               "wk": norm(ks[1], (Lt, D, Hkv, Dh), s_d),
@@ -296,6 +367,9 @@ def _pattern_init(key, cfg: TransformerConfig) -> Dict:
               "wo": norm(ks[3], (Lt, H, Dh, D), 1.0 / math.sqrt(H * Dh))}
         if cfg.attn_gate:
             ap["w_gate"] = norm(ks[4], (Lt, D, H), s_d)
+        if spec.qk_norm:
+            for name in ("q_norm", "k_norm"):
+                ap[name] = {"scale": jnp.ones((Lt, Dh), jnp.float32)}
         params["attn"][t] = ap
     for n, m in enumerate(cfg.mlp_kinds()):
         k = jax.random.fold_in(key, 20 + n)
@@ -309,6 +383,11 @@ def _pattern_init(key, cfg: TransformerConfig) -> Dict:
         mp = {"ln2": ln2,
               "router": norm(ks[0], (Lm, D, cfg.n_experts), s_d),
               "experts": swiglu(ks[1], (Lm, hi - lo), cfg.expert_ff)}
+        if cfg.expert_bias:
+            # small beside the scores' spread: it flips the closest
+            # choices and not all of them
+            mp["router_bias"] = norm(jax.random.fold_in(k, 3),
+                                     (Lm, cfg.n_experts), 0.01)
         if cfg.shared_ff:
             mp["shared"] = swiglu(ks[2], (Lm,), cfg.shared_ff)
         params["mlp"][m] = mp
@@ -700,12 +779,20 @@ def make_train_step(mesh, cfg: TransformerConfig, optimizer,
     train_step(params, opt_state, (tokens, targets)) →
     (params, opt_state, loss).  Gradient reduction over dp is the
     shard_map transpose of the replicated param specs — the compiled
-    analog of hvd.DistributedOptimizer.
+    analog of hvd.DistributedOptimizer.  The step is dispatched under
+    the host span `hvd.train.step` (`traced_step`).
+
+    A model with a layer pattern is trained on a `dp` mesh by
+    models/pattern.py (`make_pattern_train_step`: each layer recomputed,
+    attention through the flash kernel); with routed experts its step
+    returns a fourth value, the routing counts a sparse layer.
     """
     from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    _refuse_pattern(cfg, "make_train_step")
+    if cfg.patterned:
+        from .pattern import make_pattern_train_step
+        return make_pattern_train_step(mesh, cfg, optimizer)
     if cfg.attn_kind != "softmax":
         raise HorovodTpuError(
             f"make_train_step: attn_kind {cfg.attn_kind!r} is not "
@@ -759,5 +846,17 @@ def make_train_step(mesh, cfg: TransformerConfig, optimizer,
         sh = NamedSharding(mesh, data_spec)
         return (jax.device_put(tokens, sh), jax.device_put(targets, sh))
 
-    return jax.jit(train_step, donate_argnums=(0, 1)), shard_state, \
-        shard_lm_batch
+    return traced_step(jax.jit(train_step, donate_argnums=(0, 1))), \
+        shard_state, shard_lm_batch
+
+
+def traced_step(jitted):
+    """A train step as callers dispatch it: under the host span
+    `hvd.train.step` (utils/timeline.span), which brackets the dispatch
+    and waits for nothing.  `.lower` is the jitted step's."""
+    @functools.wraps(jitted)
+    def step(params, opt_state, batch):
+        with span("step", "train"):
+            return jitted(params, opt_state, batch)
+    step.lower = jitted.lower
+    return step

@@ -73,11 +73,15 @@ def _fit_block(req: int, t: int) -> int:
     return _BLOCK
 
 
-def _block_sizes(t: int):
-    """(block_q, block_k) from HOROVOD_FLASH_BLOCK_Q/K (default 128),
-    clamped to the largest dividing tile for this T (see _fit_block)."""
-    bq = util.env_int("FLASH_BLOCK_Q", _BLOCK)
-    bk = util.env_int("FLASH_BLOCK_K", _BLOCK)
+def _block_sizes(t: int, blocks=None):
+    """(block_q, block_k): the caller's `blocks`, or from
+    HOROVOD_FLASH_BLOCK_Q/K (default 128); clamped to the largest
+    dividing tile for this T (see _fit_block)."""
+    if blocks is not None:
+        bq, bk = blocks
+    else:
+        bq = util.env_int("FLASH_BLOCK_Q", _BLOCK)
+        bk = util.env_int("FLASH_BLOCK_K", _BLOCK)
     if bq <= 0 or bk <= 0:
         raise ValueError(
             f"HOROVOD_FLASH_BLOCK_Q/K must be positive, got ({bq}, {bk})")
@@ -205,13 +209,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, window,
         lse_ref[0, :, 0] = (m_scr[...] + jnp.log(l_scr[...]))[:, 0]
 
 
-def _fwd(q3, k3, v3, seg, scale, causal, window, group, hq):
+def _fwd(q3, k3, v3, seg, scale, causal, window, group, hq, blocks=None):
     """q3: (B*Hq, T, D), k3/v3: (B*Hkv, T, D) with T % block == 0 and
     group = Hq // Hkv; seg None or (B, T) int32 (hq = Hq, for the
     batch index map).  GQA never materializes repeated K/V: the index
     map points q-head b at kv-head b // group.  Returns (o, lse)."""
     bh, t, d = q3.shape
-    bq, bk = _block_sizes(t)
+    bq, bk = _block_sizes(t, blocks)
     nq = t // bq
     nk = t // bk
     has_seg = seg is not None
@@ -354,12 +358,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd(res, g):
     (q3, k3, v3, seg, o3, lse, scale, causal, window, group,
-     hq) = res
+     hq, blocks) = res
     has_seg = seg is not None
     do3 = g[0]                                   # input dtype (MXU rate)
     dlse = g[1]                                              # (bh, t, 1)
     bh, t, d = q3.shape
-    bq, bk = _block_sizes(t)
+    bq, bk = _block_sizes(t, blocks)
     nq = t // bq
     nk = t // bk
     # delta_i = sum_d dO_i * O_i (rowwise, the flash-2 correction term),
@@ -444,20 +448,21 @@ def _bwd(res, g):
 # Public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash3(q3, k3, v3, seg, causal, window, group, hq):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash3(q3, k3, v3, seg, causal, window, group, hq, blocks):
     return _fwd(q3, k3, v3, seg, 1.0 / math.sqrt(q3.shape[-1]), causal,
-                window, group, hq)
+                window, group, hq, blocks)
 
 
-def _flash3_fwd(q3, k3, v3, seg, causal, window, group, hq):
+def _flash3_fwd(q3, k3, v3, seg, causal, window, group, hq, blocks):
     scale = 1.0 / math.sqrt(q3.shape[-1])
-    o, lse = _fwd(q3, k3, v3, seg, scale, causal, window, group, hq)
+    o, lse = _fwd(q3, k3, v3, seg, scale, causal, window, group, hq,
+                  blocks)
     return (o, lse), (q3, k3, v3, seg, o, lse, scale, causal, window,
-                      group, hq)
+                      group, hq, blocks)
 
 
-def _flash3_bwd(causal, window, group, hq, res, g):
+def _flash3_bwd(causal, window, group, hq, blocks, res, g):
     return _bwd(res, g)
 
 
@@ -514,7 +519,7 @@ def _check_and_to3(q, k, v, window=None, causal=True,
 
 
 def flash_attention(q, k, v, causal: bool = True, window=None,
-                    segment_ids=None):
+                    segment_ids=None, blocks=None):
     """Flash attention on [B, T, H, D] (same convention as
     parallel/sequence.py), differentiable, O(T) memory.
 
@@ -534,11 +539,17 @@ def flash_attention(q, k, v, causal: bool = True, window=None,
 
     `segment_ids` ([B, T] int): packed-sequence block-diagonal masking —
     tokens attend only within their own segment, so multiple documents
-    packed into one row never cross-attend."""
+    packed into one row never cross-attend.
+
+    `blocks` (block_q, block_k): the kernels' tiles for this call, each
+    clamped to a divisor of T; None: HOROVOD_FLASH_BLOCK_Q/K (128).  A
+    grid step costs about a third of a microsecond whatever it holds,
+    so long sequences want larger tiles than 128 (PERF.md, PR 37)."""
     window = None if window is None else int(window)
     (B, T, H, Hkv, D), q3, k3, v3, seg = _check_and_to3(
         q, k, v, window, causal, segment_ids)
-    o3, _ = _flash3(q3, k3, v3, seg, causal, window, H // Hkv, H)
+    o3, _ = _flash3(q3, k3, v3, seg, causal, window, H // Hkv, H,
+                    None if blocks is None else tuple(blocks))
     return o3.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
@@ -550,7 +561,8 @@ def flash_attention_lse(q, k, v, causal: bool = True, window=None,
     window = None if window is None else int(window)
     (B, T, H, Hkv, D), q3, k3, v3, seg = _check_and_to3(
         q, k, v, window, causal, segment_ids)
-    o3, lse3 = _flash3(q3, k3, v3, seg, causal, window, H // Hkv, H)
+    o3, lse3 = _flash3(q3, k3, v3, seg, causal, window, H // Hkv, H,
+                       None)
     o = o3.reshape(B, H, T, D).transpose(0, 2, 1, 3)
     lse = lse3.reshape(B, H, T).transpose(0, 2, 1)
     return o, lse

@@ -16,6 +16,7 @@ margin (seen: 9e-4 and over).
 """
 
 import dataclasses
+import types
 
 import jax
 import jax.numpy as jnp
@@ -322,8 +323,23 @@ def test_boarding_writes_the_prompts_last_window_into_the_ring(model):
         atol=1e-5)
 
 
+# make_train_step trains a layer pattern since PR 37 (models/pattern.py,
+# tests/test_conv_moe_train.py); what it still refuses of THIS model is
+# its windows, its gate a head and its shared expert, and every mesh axis
+# but dp.
+_MESH = lambda **axes: types.SimpleNamespace(shape=axes)
+_NO_WINDOW = dataclasses.replace(CFG, attn_specs=tuple(
+    (t, dataclasses.replace(s, window=0)) for t, s in CFG.attn_specs))
 REFUSALS = {
-    "make_train_step": lambda p: make_train_step(None, CFG, None),
+    "make_train_step, a window": lambda p: make_train_step(
+        _MESH(dp=1), CFG, None),
+    "make_train_step, a gate a head": lambda p: make_train_step(
+        _MESH(dp=1), _NO_WINDOW, None),
+    "make_train_step, a shared expert": lambda p: make_train_step(
+        _MESH(dp=1), dataclasses.replace(_NO_WINDOW, attn_gate=False),
+        None),
+    "make_train_step, a tp axis": lambda p: make_train_step(
+        _MESH(dp=1, tp=2), CFG, None),
     "training forward": lambda p: transformer_ref_apply(
         p, jnp.zeros((1, 4), jnp.int32), CFG),
     "quantized cache": lambda p: init_decode_cache(CFG, 1, 8, "int8"),

@@ -217,3 +217,65 @@ def test_pool_write_back_moves_slots_only(one_chip):
         one_chip(kv(PAGES, PAGE_TOKENS)), one_chip(kv(ROWS, SLOTS)),
         *(one_chip(idx),) * 4).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_patterned_train_step_fits_beside_its_state(one_chip, monkeypatch):
+    """`lfm2_8b_train_8k`'s train step (benchmark/configs/
+    lfm2-8b-a1b-train.json at B 4 x 8192, through `make_train_step`): the
+    compiler takes it, its temporaries fit beside the 6.1 GB of
+    parameters and AdamW's moments, and they are less than ONE sequence's
+    float32 scores of one layer ([32, 8192, 8192]: 8.6 GB), so that no
+    [H, T, T] is kept, forward or backward (attention is the flash
+    kernel's calls), and less than the logits of the batch and their
+    gradient (the loss is taken a sequence at a time).  The routed
+    experts' products are the grouped kernels: three forward, three
+    recomputed and six backward a sparse layer."""
+    import json
+    import os
+
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmark.runners.conv_moe_train import transformer_config
+    from horovod_tpu.models import experts, make_train_step
+    from horovod_tpu.ops import flash_attention
+
+    monkeypatch.setattr(experts, "_interpret", lambda: False)
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2-8b-a1b-train.json")) as f:
+        m = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "seq8k_b4.json")) as f:
+        tr = json.load(f)
+    cfg = transformer_config(m, jnp.bfloat16)
+    dev, = one_chip(jax.ShapeDtypeStruct((), jnp.int32)).sharding.device_set
+    mesh = Mesh(np.array([dev]), ("dp",))
+    on = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=NamedSharding(mesh, P())),
+        tree)
+    hp = m["train"]["optimizer"]
+    opt = optax.adamw(hp["learning_rate"], b1=hp["b1"], b2=hp["b2"],
+                      eps=hp["eps"], weight_decay=hp["weight_decay"])
+    step, _, _ = make_train_step(mesh, cfg, opt)
+    params = jax.eval_shape(
+        lambda: transformer_init(jax.random.PRNGKey(0), cfg))
+    assert sum(a.size for a in jax.tree_util.tree_leaves(params)) \
+        == 507820288
+    B, T = tr["per_chip_batch"], tr["seq_len"]
+    toks = on(jax.ShapeDtypeStruct((B, T), jnp.int32))
+    compiled = step.lower(on(params), on(jax.eval_shape(opt.init, params)),
+                          (toks, toks)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 6.09e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    scores = 32 * T * T * 4
+    logits = B * T * m["vocab_size"] * 4
+    assert mem.temp_size_in_bytes < scores
+    assert mem.temp_size_in_bytes < 6.1e9 + 2 * logits
+    text = compiled.as_text()
+    assert text.count("tgmm") and text.count("hvd.moe.experts")
+    assert text.count("hvd.conv") and text.count("hvd.attn")
