@@ -62,7 +62,7 @@ from horovod_tpu.models import (
 )
 from horovod_tpu.models.decode import _spec_step_fn
 from horovod_tpu.ops import pallas_kernels as pk
-from horovod_tpu.ops import decode_attention
+from horovod_tpu.ops import decode_attention, retention_step
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.ops.fused_collectives import pallas_matmul
 from horovod_tpu.parallel import (
@@ -121,6 +121,13 @@ DECODE_CASES = {
                                      pos=[5000, 1024, 0, 1023]),
 }
 
+# A retention layer's pass over the state (ops/retention_step.py), float32,
+# heads of 128 (a state of 8320 x 128 a kv head) at layer 1 of a stack of
+# 2: idle rows between the live ones and at both ends.
+STATE_CASES = {
+    "retention step g5": dict(live=[0, 1, 1, 0, 1, 0], kv_heads=8, group=5),
+}
+
 # Server vs transformer_generate, in logit units (logits here are O(1):
 # unit-RMS activations against a 1/sqrt(d) embedding).  The server
 # decodes max_batch rows over a paged view, the reference one row over a
@@ -137,7 +144,8 @@ SERVE_LOGIT_TOL = 0.1
 # operands too, because Mosaic's dot runs at the MXU's default precision.
 MXU_KERNEL_TOL = 2e-2
 # The Adasum kernels are f32 on the vector unit; what differs is the
-# order of a 300k-element sum.
+# order of a 300k-element sum.  The retention step's read-out is a
+# float32 product at "highest", its update f32 on the vector unit.
 F32_KERNEL_TOL = 1e-4
 
 # Sharded vs one-chip loss for the same seed and global batch: the same
@@ -478,12 +486,42 @@ def _decode_case(slots, group, window, pos, kv_heads=8, d_head=128,
     return _rel_err(got, want)
 
 
+def _state_case(live, kv_heads, group, d_head=128):
+    """`retention_step` at layer 1 of a stack of 2 against the pass
+    written plainly (read-out of the state as it was, then decay and
+    phi(k) v^T), the live rows' four results and the idle rows' state."""
+    feats = (d_head // 2 + 1) * d_head
+    B, lv = len(live), np.asarray(live, bool)
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    fq = jax.random.normal(ks[0], (B, kv_heads, group, feats), jnp.float32)
+    fk = jax.random.normal(ks[1], (B, kv_heads, feats), jnp.float32)
+    v = jax.random.normal(ks[2], (B, kv_heads, d_head), jnp.float32)
+    decay = jax.random.uniform(ks[3], (B, kv_heads), jnp.float32)
+    cs = jax.random.normal(ks[4], (2, B, kv_heads, feats, d_head), jnp.float32)
+    cz = jax.random.normal(ks[5], (2, B, kv_heads, feats), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = (jnp.einsum("bhgf,bhfd->bhgd", fq, cs[1]),
+                jnp.einsum("bhgf,bhf->bhg", fq, cz[1]),
+                decay[..., None, None] * cs[1]
+                + fk[..., None] * v[..., None, :],
+                decay[..., None] * cz[1] + fk)
+    idle = np.asarray(cs[1])[~lv]
+    num, den, s, z = jax.jit(retention_step.retention_step)(
+        fq, fk, v, decay, cs, cz, 1, jnp.asarray(lv))
+    _check(np.array_equal(np.asarray(s[1])[~lv], idle), "kernels",
+           "retention_step touched an idle row's state")
+    return max(_rel_err(np.asarray(g)[lv], np.asarray(w)[lv])
+               for g, w in zip((num, den, s[1], z[1]), want))
+
+
 def phase_kernels(seq: int, long_seq: int, flash_cases: dict,
-                  decode_cases: dict) -> dict:
+                  decode_cases: dict, state_cases: dict) -> dict:
     errs = {name: _attention_case(flash_attention, 1, seq, **case)
             for name, case in flash_cases.items()}
     errs.update((name, _decode_case(**case))
                 for name, case in decode_cases.items())
+    f32 = {name: _state_case(**case) for name, case in state_cases.items()}
+    errs.update(f32)
     if long_seq:
         # Nothing forced: full_attention must pick the kernel by itself.
         probe = jax.ShapeDtypeStruct((1, long_seq, 1, 128), jnp.bfloat16)
@@ -506,8 +544,9 @@ def phase_kernels(seq: int, long_seq: int, flash_cases: dict,
     want = (1 - dot / (2 * nx)) * x + (1 - dot / (2 * ny)) * y
     errs["adasum pair"] = _rel_err(
         pk.pallas_pair_combine_batched(jnp.asarray(x), jnp.asarray(y)), want)
+    f32["adasum pair"] = errs["adasum pair"]
     for name, err in errs.items():
-        tol = F32_KERNEL_TOL if name == "adasum pair" else MXU_KERNEL_TOL
+        tol = F32_KERNEL_TOL if name in f32 else MXU_KERNEL_TOL
         _check(err <= tol, "kernels",
                f"{name}: relative error {err:.4g} > {tol}")
     return {name: float(f"{err:.3g}") for name, err in errs.items()}
@@ -557,7 +596,7 @@ def main() -> int:
     report("decode layout", phase_decode_layout(cfg, **DECODE_VIEW))
     report("kernels",
            phase_kernels(KERNEL_SEQ, KERNEL_LONG_SEQ, FLASH_CASES,
-                         DECODE_CASES))
+                         DECODE_CASES, STATE_CASES))
 
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind, "count": n}}),

@@ -101,7 +101,7 @@ import numpy as np
 from jax import lax
 
 from ..common.exceptions import InvalidRequestError
-from ..ops import decode_attention
+from ..ops import decode_attention, retention_step
 from ..parallel import sequence as seq_mod
 from . import experts as experts_mod
 from .transformer import (
@@ -609,43 +609,64 @@ def _state_put(c, i, val):
         c, val[None].astype(c.dtype), (i,) + (0,) * (c.ndim - 1))
 
 
+def _state_pass(fq, fk, v0, decay, cs, cz, i):
+    """Layer `i`'s pass over the state as three einsums, the form
+    ops/retention_step.py's kernel is held against and what every shape
+    it does not take runs: (num [B,Hkv,g,Dh], den [B,Hkv,g]) read by
+    `fq` out of the state AS IT WAS, before the decay, and the stacked
+    leaves with layer `i` decayed and given `fk v0^T` / `fk`.  The
+    read-out runs at "highest" so that a float32 state is read as
+    float32 (a default matmul would round it to bfloat16 on the way
+    in)."""
+    s = _cache_layer(cs, i).astype(jnp.float32)
+    z = _cache_layer(cz, i)
+    num = jnp.einsum("bhgf,bhfd->bhgd", fq, s,
+                     precision=lax.Precision.HIGHEST)
+    den = jnp.einsum("bhgf,bhf->bhg", fq, z,
+                     precision=lax.Precision.HIGHEST)
+    s = decay[..., None, None] * s + fk[..., None] * v0[..., None, :]
+    z = decay[..., None] * z + fk
+    return num, den, _state_put(cs, i, s), _state_put(cz, i, z)
+
+
 def _retention_decode_layer(lp, cs, cz, i, x, pos,
                             cfg: TransformerConfig, tp_axis=None):
     """Layer `i` for ONE new token a row: x [B, 1, D]; cs [L, B, Hkv, Df,
     Dh] and cz [L, B, Hkv, Df] the whole stacked states and normalisers.
-    The state is decayed, takes the token's phi(k) v^T and is read out by
-    phi(q), all of it read and all of it written: a step's cost is the
-    state's bytes, whatever the tokens behind it.  The read-out runs at
-    "highest" so that a float32 state is read as float32 (a default
-    matmul would round it to bfloat16 on the way in).  `pos` scalar or
-    [B], as in `_decode_layer`."""
+    The state is read out by phi(q), decayed, and takes the token's
+    phi(k) v^T: a step's cost is the state's bytes, whatever the tokens
+    behind it.  Shape and type pick who makes the pass: the kernel of
+    ops/retention_step.py, one read and one write of each live row's
+    state where it lies, or `_state_pass` over every row.  `pos` scalar
+    or [B], as in `_decode_layer`; with a [B] `pos` a row at depth 0 is
+    nobody's (a served batch's idle row: a prompt is never empty), and
+    the kernel leaves its state as it is and its output 0."""
     B = x.shape[0]
     pos = jnp.asarray(pos)
     positions = pos[:, None] if pos.ndim == 1 else pos[None]
     q, k, v, gamma = _retention_qkv(lp, x, positions, cfg)
     Hkv, Dh = k.shape[2], k.shape[3]
     decay = jnp.exp(gamma[:, 0])                            # [B, Hkv]
-    s = _cache_layer(cs, i).astype(jnp.float32)
-    z = _cache_layer(cz, i)
     k0, v0 = k[:, 0], v[:, 0].astype(jnp.float32)
     qg = q[:, 0].reshape(B, Hkv, -1, Dh)                    # [B,Hkv,g,Dh]
     # What came before, out of the state as it was; the token's own
     # weight from q . k itself, so that it is >= 0 exactly (through phi
     # it would round, and an empty state would divide by that).
     fq = _phi(qg)                                           # [B,Hkv,g,Df]
+    fk = _phi(k0)                                           # [B, Hkv, Df]
     own = jnp.square(jnp.einsum("bhgd,bhd->bhg", qg, k0,
                                 precision=lax.Precision.HIGHEST)) / Dh
-    num = decay[..., None, None] * jnp.einsum(
-        "bhgf,bhfd->bhgd", fq, s, precision=lax.Precision.HIGHEST) \
-        + own[..., None] * v0[:, :, None, :]
-    den = decay[..., None] * jnp.einsum(
-        "bhgf,bhf->bhg", fq, z, precision=lax.Precision.HIGHEST) + own
+    if retention_step.takes(cs):
+        num, den, cs, cz = retention_step.retention_step(
+            fq, fk, v0, decay, cs, cz, i,
+            pos > 0 if pos.ndim == 1 else None)
+    else:
+        num, den, cs, cz = _state_pass(fq, fk, v0, decay, cs, cz, i)
+    num = decay[..., None, None] * num + own[..., None] * v0[:, :, None, :]
+    den = decay[..., None] * den + own
     y = num / (den[..., None] + RETENTION_EPS)
     x = _retention_out(lp, x, y.reshape(B, 1, -1, Dh), cfg, tp_axis)
-    fk = _phi(k0)                                           # [B, Hkv, Df]
-    s = decay[..., None, None] * s + fk[..., None] * v0[..., None, :]
-    z = decay[..., None] * z + fk
-    return x, _state_put(cs, i, s), _state_put(cz, i, z)
+    return x, cs, cz
 
 
 def _phi_rows(u, mxu):

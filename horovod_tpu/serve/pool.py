@@ -52,7 +52,7 @@ from jax import lax
 from ..common.exceptions import HorovodTpuError, InvalidRequestError
 from ..metrics import catalog as _met
 from ..models.decode import cache_leaves, cache_slots, init_decode_cache
-from ..ops import decode_attention
+from ..ops import decode_attention, retention_step
 from ..utils.timeline import span
 
 
@@ -168,6 +168,8 @@ class DecodeCache:
       - ``view_read_pct(positions)``: the share of the view's blocks
         that a step at these positions reads, %; None from a cache that
         keeps no slot a token;
+      - ``state_read_pct(positions)``: the share of the rows' states
+        that such a step reads, %; None from a cache that keeps slots;
       - ``utilization()``, ``set_gauges()``, ``state_bytes``,
         ``installs``, and ``on_event``, which a cache with pages calls
         with (event, req_id, n_pages, pages_free).
@@ -195,6 +197,9 @@ class DecodeCache:
         self.view = tuple(cache[n] for n in self.leaves)
 
     def view_read_pct(self, positions) -> Optional[float]:
+        return None
+
+    def state_read_pct(self, positions) -> Optional[float]:
         return None
 
     def set_gauges(self) -> None:
@@ -466,6 +471,8 @@ class StateSlots(DecodeCache):
         #: bytes of all rows' states and normalisers, and of one row's
         self.state_bytes = sum(a.nbytes for a in self.view)
         self.row_bytes = self.state_bytes // rows
+        #: does the step's kernel make the pass over these leaves?
+        self.kernel = retention_step.takes(self.view[0])
 
     def pages_needed(self, n_tokens: int) -> int:
         return 0
@@ -486,6 +493,15 @@ class StateSlots(DecodeCache):
 
     # the row given back is all there is, and the view the only copy
     release = refresh = write_through = lambda self, *a: None
+
+    def state_read_pct(self, positions) -> float:
+        """What models/decode.py `_retention_decode_layer` reads of the
+        rows' states in a step over rows at `positions` (host integers,
+        0 for an idle row): the live rows' where the kernel makes the
+        pass (ops/retention_step.py), every row's under the einsums."""
+        if not self.kernel:
+            return 100.0
+        return retention_step.read_pct(positions)
 
     def utilization(self) -> float:
         """Rows held over rows: what the pool's page share is for a

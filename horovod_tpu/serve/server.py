@@ -495,10 +495,13 @@ class InferenceServer:
 
     def _plain_step(self, rows: Sequence[int], feed: np.ndarray) -> None:
         base = self.row_pos.copy()
-        read = self.pool.view_read_pct(base)
-        with span("launch", "serve",       # dispatches only, no wait
-                  None if read is None else
-                  {"view_read_pct": round(read, 2)}):
+        # what the step reads of what the cache holds: its view's blocks
+        # or its rows' states, whichever the cache answers
+        read = {name: round(pct, 2) for name, pct in (
+            ("view_read_pct", self.pool.view_read_pct(base)),
+            ("state_read_pct", self.pool.state_read_pct(base)))
+            if pct is not None}
+        with span("launch", "serve", read or None):   # dispatches only
             for cache, _ in self._caches:
                 cache.refresh()
             self._logits, ids, cache = _serve_step_fn(self.cfg)(

@@ -70,9 +70,10 @@ def test_kernels_phase_interpreted_on_the_cpu_mesh():
     errs = chip_smoke.phase_kernels(128, 0, {"flash all masks": dict(
         H=4, D=32, kv_heads=2, window=48, segments=2)}, {"decode": dict(
             slots=320, group=6, window=300, pos=[700, 0, 130], kv_heads=2,
-            d_head=16, block=128)})
-    assert set(errs) == {"flash all masks", "decode", "pallas_matmul",
-                         "adasum pair"}
+            d_head=16, block=128)}, {"state": dict(
+                live=[0, 1, 0], kv_heads=1, group=2)})
+    assert set(errs) == {"flash all masks", "decode", "state",
+                         "pallas_matmul", "adasum pair"}
 
 
 def test_chip_smoke_refuses_the_cpu_and_names_the_cache(monkeypatch):

@@ -64,6 +64,23 @@ def model():
     return make()
 
 
+@pytest.fixture(scope="module")
+def served(request, model):
+    """The model a server test runs: `model`, whose state of 40 features
+    the einsums step, or one with heads of 128, whose state tiles and is
+    stepped by the kernel of ops/retention_step.py (interpreted here)."""
+    if request.param == "einsums":
+        return model
+    cfg, params = make(d_head=128)
+    from horovod_tpu.ops import retention_step
+    assert retention_step.takes(init_decode_cache(cfg, 3, 1)["s"])
+    return cfg, params
+
+
+both_passes = pytest.mark.parametrize("served", ["einsums", "kernel"],
+                                      indirect=True)
+
+
 def reference(cfg, params, tokens):
     m = dict(num_hidden_layers=cfg.n_layers, rope_theta=cfg.rope_theta)
     return np.asarray(ref.forward(params, jnp.asarray(tokens), m))
@@ -258,11 +275,12 @@ def serve(cfg, params, requests, max_batch, stagger=True):
     return srv, ids, {s.req.req_id: list(s.generated) for s in done}
 
 
-def test_server_serves_what_generate_gives_alone(model):
+@both_passes
+def test_server_serves_what_generate_gives_alone(served):
     """Seven requests through three rows, admitted a step apart: each
     gets the tokens `transformer_generate` gives it alone, the state is
     held once and no page is ever allocated."""
-    cfg, params = model
+    cfg, params = served
     rng = np.random.RandomState(1)
     requests = [(rng.randint(0, V, size=rng.randint(3, 14)), n)
                 for n in (3, 6, 4, 5, 7, 2, 4)]
@@ -305,7 +323,8 @@ def test_server_prompt_longer_than_a_chunk(model):
             assert tok == int(row.argmax())
 
 
-def test_logits_read_between_steps_are_the_states_read_out(model):
+@both_passes
+def test_logits_read_between_steps_are_the_states_read_out(served):
     """What the benchmark's `state_error` rests on (`served_again`,
     benchmark/runners/retention_serve.py): the request's row of
     `last_logits` between two steps is what the decode step read out of
@@ -314,7 +333,7 @@ def test_logits_read_between_steps_are_the_states_read_out(model):
     positions, and each read pulled the logits from the device once."""
     from benchmark.runners.retention_serve import served_again
 
-    cfg, params = model
+    cfg, params = served
     srv = InferenceServer(params, cfg, max_seq_tokens=40, max_batch=3)
     for seed, n in ((31, 20), (32, 9)):
         srv.submit(tokens_of(7, seed=seed), n)

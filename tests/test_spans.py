@@ -251,7 +251,8 @@ def test_launch_carries_the_view_read_share(model, traced, tmp_path):
     """`hvd.serve.launch` says which share of the view's blocks the step's
     attention reads (`view_read_pct`, from `row_pos` on the host): all of
     a view of one block or less, which the einsum reads whole; a
-    retention server keeps no slots and says nothing."""
+    retention server keeps no slots and says nothing of a view (what it
+    says instead: `test_retention_launch_carries_the_state_read_share`)."""
     launches = [s for s in traced["spans"] if s[0] == "hvd.serve.launch"]
     assert len(launches) == traced["srv"].device_steps
     assert all(s[3] == {"view_read_pct": 100.0} for s in launches)
@@ -264,7 +265,41 @@ def test_launch_carries_the_view_read_share(model, traced, tmp_path):
         (rcfg, transformer_init(jax.random.PRNGKey(0), rcfg))))
     launches = [s for s in spans if s[0] == "hvd.serve.launch"]
     assert len(launches) == srv.device_steps > 0
-    assert all(s[3] == {} for s in launches)
+    assert all("view_read_pct" not in s[3] for s in launches)
+
+
+@pytest.mark.parametrize("d_head,kernel", [(8, False), (128, True)],
+                         ids=["einsums", "kernel"])
+def test_retention_launch_carries_the_state_read_share(model, tmp_path,
+                                                       d_head, kernel):
+    """A retention server's `hvd.serve.launch` says which share of the
+    rows' states the step reads (`state_read_pct`, from `row_pos` on the
+    host) and nothing of a view: every row's, 100, where the einsums
+    make the pass (a state too small to tile), the live rows over the
+    rows where the kernel of ops/retention_step.py does, so that over a
+    run the shares sum to the occupancy.  The tokens are what
+    `transformer_generate` gives each request alone either way."""
+    cfg, _ = model
+    rcfg = dataclasses.replace(cfg, attn_kind="retention", n_kv_heads=2,
+                               d_head=d_head)
+    params = transformer_init(jax.random.PRNGKey(0), rcfg)
+    (srv, prompts, tokens), spans = _profiled(
+        tmp_path, lambda: _serve((rcfg, params)))
+    assert srv.pool.kernel == kernel
+    launches = [s for s in spans if s[0] == "hvd.serve.launch"]
+    assert len(launches) == srv.device_steps > 0
+    assert all(set(s[3]) == {"state_read_pct"} for s in launches)
+    shares = [s[3]["state_read_pct"] for s in launches]
+    if kernel:
+        assert set(shares) == {50.0, 100.0}     # one row live, or both
+        assert sum(shares) / 100 == pytest.approx(srv.occupancy_sum)
+    else:
+        assert set(shares) == {100.0}
+    for rid, prompt in prompts.items():
+        want, _ = transformer_generate(params, rcfg,
+                                       jnp.asarray(prompt)[None],
+                                       OUTPUTS[rid])
+        assert tokens[rid] == np.asarray(want)[0].tolist()
 
 
 @pytest.mark.parametrize("kw,positions,want", [

@@ -36,12 +36,13 @@ def one_chip():
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import SingleDeviceSharding
 
-    from horovod_tpu.ops import decode_attention
+    from horovod_tpu.ops import decode_attention, retention_step
 
     mp = pytest.MonkeyPatch()
     mp.setenv("TPU_LOG_DIR", "disabled")    # or the compiler logs to /tmp
-    # the decode step's kernel as the chip would get it, through Mosaic
+    # the decode steps' kernels as the chip would get them, through Mosaic
     mp.setattr(decode_attention, "_interpret", lambda: False)
+    mp.setattr(retention_step, "_interpret", lambda: False)
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -152,6 +153,30 @@ def test_server_step_picks_its_ids_in_the_one_program(one_chip, widths,
     logits_bytes = rows * cfg.vocab_size * 4
     assert temp <= bare.memory_analysis().temp_size_in_bytes + logits_bytes
     assert temp < _leaf_bytes(cache[leaf]) // 8, temp
+
+
+def test_retention_step_passes_over_its_state_where_it_lies(one_chip):
+    """`brumby14b_longdoc_steady`'s served step at the cell's 16 rows and
+    8 layers (4.4 GB of states): a layer's pass over the state is ONE
+    kernel in the loop over layers (ops/retention_step.py), handed the
+    stacked leaves whole and aliased onto them, so the program holds no
+    temporary of a layer's state (550 MB; a slice handed to a kernel, or
+    a read-out that keeps the decayed state beside the old one, would
+    be one) and the whole cache comes back in the argument's buffers."""
+    from horovod_tpu.models.decode import _serve_step_fn
+
+    cfg = TransformerConfig(**{**STATE_WIDTHS, "n_layers": 8})
+    args = _step_args(one_chip, cfg, STATE_ROWS, 1)
+    cache = args[1]
+    assert cache["s"].shape == (8, 16, 8, 8320, 128)
+    compiled = _serve_step_fn(cfg).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+    state = sum(cache[n].size * cache[n].dtype.itemsize for n in "sz")
+    assert state == 16 * 8 * 8 * 8320 * 129 * 4
+    assert mem.alias_size_in_bytes >= state
 
 
 # laguna_xs2_codegen_steady's decode view (benchmark/traffic/
@@ -279,3 +304,89 @@ def test_patterned_train_step_fits_beside_its_state(one_chip, monkeypatch):
     text = compiled.as_text()
     assert text.count("tgmm") and text.count("hvd.moe.experts")
     assert text.count("hvd.conv") and text.count("hvd.attn")
+
+
+# Lowered text of the programs that ops/retention_step.py's PR (39) must
+# not move, hashed at its parent (6635e6d) with `_programs` below: the
+# softmax decode step with the kernel over live blocks and with the
+# einsum, a Mistral prefill, a patterned model's step with routed experts
+# (Laguna's kind), the uniform train step, and a retention model's
+# PREFILL.  A change of JAX moves them all.
+PARENT_TEXT = {
+    "mistral_step_kernel": "73c1243f07b3a6dc",
+    "mistral_step_einsum": "583b21132fb4cd5f",
+    "mistral_prefill": "209877bf1d709583",
+    "laguna_step": "6bef917b6b6201e5",
+    "mistral_train": "cc76927979bf3144",
+    "brumby_prefill": "fc1050497b5397b8",
+}
+
+
+def _programs():
+    import optax
+
+    from horovod_tpu.models import (make_train_step, transformer_decode_step,
+                                    transformer_prefill)
+    from horovod_tpu.models.transformer import AttnSpec
+    from horovod_tpu.parallel.mesh import create_hybrid_mesh
+
+    tiny = dict(vocab_size=64, d_model=32, n_heads=4, d_head=8, d_ff=64,
+                n_layers=2, n_kv_heads=2)
+    ids = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    weights = lambda cfg: jax.eval_shape(
+        lambda: transformer_init(jax.random.PRNGKey(0), cfg))
+
+    def step(cfg, rows, slots):
+        cache = jax.eval_shape(lambda: init_decode_cache(cfg, rows, slots))
+        cache["pos"] = ids(rows)
+        return jax.jit(
+            lambda p, c, t: transformer_decode_step(p, c, t, cfg)).lower(
+                weights(cfg), cache, ids(rows))
+
+    def prefill(cfg, n, slots):
+        return jax.jit(
+            lambda p, c, t: transformer_prefill(p, c, t, cfg)).lower(
+                weights(cfg),
+                jax.eval_shape(lambda: init_decode_cache(cfg, 1, slots)),
+                ids(1, n))
+
+    def train(cfg):
+        opt = optax.adamw(3e-4)
+        fn = make_train_step(
+            create_hybrid_mesh(devices=jax.devices()[:1], dp=1), cfg, opt)[0]
+        p = weights(cfg)
+        return fn.lower(p, jax.eval_shape(opt.init, p),
+                        (ids(2, 16), ids(2, 16)))
+
+    mistral = TransformerConfig(**tiny, attn_window=2048)
+    laguna = TransformerConfig(
+        **tiny, layer_attn=("full", "full"), layer_mlp=("dense", "experts"),
+        attn_specs=(("full", AttnSpec(4)),), n_experts=8,
+        experts_per_token=2, expert_ff=16, shared_ff=16, routed_scale=2.5,
+        attn_gate=True)
+    return {
+        "mistral_step_kernel": lambda: step(mistral, 3, 1024),
+        "mistral_step_einsum": lambda: step(mistral, 3, 64),
+        "mistral_prefill": lambda: prefill(mistral, 200, 256),
+        "laguna_step": lambda: step(laguna, 2, 16),
+        "mistral_train": lambda: train(
+            TransformerConfig(**tiny, attn_window=16)),
+        "brumby_prefill": lambda: prefill(
+            TransformerConfig(**tiny, attn_kind="retention"), 300, 1),
+    }
+
+
+@pytest.mark.parametrize("name", list(PARENT_TEXT))
+def test_untouched_programs_lower_to_the_parents_text(name, monkeypatch):
+    """None of the six other cells runs `_retention_decode_layer`: their
+    step, prefill and train programs lower (here, for the CPU, at tiny
+    widths) to the text they had at the parent commit."""
+    import hashlib
+
+    from horovod_tpu.ops import decode_attention
+
+    # for the CPU, whatever `one_chip` holds for the tests above
+    monkeypatch.setattr(decode_attention, "_interpret", lambda: True)
+    text = _programs()[name]().as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PARENT_TEXT[name]
