@@ -165,11 +165,14 @@ class DecodeCache:
         dict a step program takes, and what it returned;
       - ``write_through(rows, positions)``: the view's `rows` were
         stepped at ``positions[row]``; carry that to where it is kept;
-      - ``view_read_pct(positions)``: the share of the view's blocks
-        that a step at these positions reads, %; None from a cache that
-        keeps no slot a token;
-      - ``state_read_pct(positions)``: the share of the rows' states
-        that such a step reads, %; None from a cache that keeps slots;
+      - ``step_args(positions)``: what this cache alone knows of the
+        work of a step at these positions (host integers, 0 for an idle
+        row), as arguments of the step's `hvd.serve.launch` span: a
+        cache with slots gives ``view_read_pct``, the share of the
+        view's blocks the step reads, %; a cache of states
+        ``state_read_pct``, the share of the rows' states it reads, %;
+        a cache with rings of one kind also ``ring_tokens``, the tokens
+        live in a layer's ring over the stepped rows;
       - ``utilization()``, ``set_gauges()``, ``state_bytes``,
         ``installs``, and ``on_event``, which a cache with pages calls
         with (event, req_id, n_pages, pages_free).
@@ -196,11 +199,8 @@ class DecodeCache:
     def take_back(self, cache: Dict) -> None:
         self.view = tuple(cache[n] for n in self.leaves)
 
-    def view_read_pct(self, positions) -> Optional[float]:
-        return None
-
-    def state_read_pct(self, positions) -> Optional[float]:
-        return None
+    def step_args(self, positions) -> Dict[str, float]:
+        return {}
 
     def set_gauges(self) -> None:
         _met.serve_state_bytes.set(self.state_bytes)
@@ -297,6 +297,9 @@ class PagedKVPool(DecodeCache):
                 slots):
             return 100.0
         return decode_attention.read_pct(positions, slots)
+
+    def step_args(self, positions) -> Dict[str, float]:
+        return {"view_read_pct": round(self.view_read_pct(positions), 2)}
 
     def set_gauges(self) -> None:
         super().set_gauges()
@@ -503,6 +506,9 @@ class StateSlots(DecodeCache):
             return 100.0
         return retention_step.read_pct(positions)
 
+    def step_args(self, positions) -> Dict[str, float]:
+        return {"state_read_pct": round(self.state_read_pct(positions), 2)}
+
     def utilization(self) -> float:
         """Rows held over rows: what the pool's page share is for a
         paged model (the autoscaler's signal)."""
@@ -539,6 +545,9 @@ class WindowedKVPool(DecodeCache):
         super().__init__(cfg)
         windows = {t: cfg.kind_cfg(t).attn_window for t in cfg.attn_kinds()}
         self.ringed = tuple(t for t, w in windows.items() if w)
+        #: the rings' length where they are of one kind (`step_args`)
+        self.ring_window = (windows[self.ringed[0]]
+                            if len(self.ringed) == 1 else None)
         paged = [t for t, w in windows.items() if not w]
         if len(paged) != 1:
             raise InvalidRequestError(
@@ -559,7 +568,7 @@ class WindowedKVPool(DecodeCache):
         # what only the pages answer
         for name in ("total_pages", "page_tokens", "pages_needed",
                      "pages_free", "can_board", "utilization", "release",
-                     "refresh", "write_through", "view_read_pct"):
+                     "refresh", "write_through"):
             setattr(self, name, getattr(self.pool, name))
 
     def board(self, req_id, row, n_tokens, params, prompt, prefill):
@@ -590,6 +599,20 @@ class WindowedKVPool(DecodeCache):
         self.pool.view = tuple(cache[n][self.paged] for n in self.leaves)
         self.rings = tuple({t: cache[n][t] for t in self.ringed}
                            for n in self.leaves)
+
+    def step_args(self, positions) -> Dict[str, float]:
+        """The pages' share of the view, and `ring_tokens`: the tokens
+        live in a window layer's ring over the rows stepped (those not
+        at 0), the step's own among them: ``min(pos + 1, window)``
+        summed over the rows.  A model with rings of several kinds does
+        not say it: one number would not tell them apart."""
+        args = self.pool.step_args(positions)
+        if self.ring_window:
+            pos = np.asarray(positions)        # min(pos, w - 1) + 1 each
+            args["ring_tokens"] = (
+                int(np.minimum(pos, self.ring_window - 1).sum())
+                + int(np.count_nonzero(pos)))
+        return args
 
     def set_gauges(self) -> None:
         self.pool.set_gauges()

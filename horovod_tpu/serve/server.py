@@ -39,9 +39,10 @@ read ring property ``transformer_speculative_generate`` relies on).
 
 The host's side of a step is five spans under one
 (`utils/timeline.span`, category ``serve``: ``step`` holding ``admit``
-with one ``prefill`` a request, ``sample``, ``launch``, ``fetch``,
-``observe``; docs/SERVING.md has the table), so a profiler trace shows
-which of them the device waits for.
+with one ``prefill`` a request, ``sample``, ``launch`` with ``put`` and
+``write_through`` inside, ``fetch``, ``observe``; docs/SERVING.md has
+the table), so a profiler trace shows which of them the device waits
+for; ``launch`` and ``observe`` say what work the step did.
 
 All host orchestration (clocks, metrics, env) stays OUTSIDE the jitted
 programs; the compiled pieces are the same module-cached
@@ -200,6 +201,9 @@ class InferenceServer:
         self._logits = np.zeros((self.max_batch, V), np.float32)
         self._fresh: Dict[int, np.ndarray] = {}
         self.logit_fetches = 0      # whole logits pulled to the host
+        # What this iteration's sync brought, and of which device step
+        # (`_plain_step`), for `observe`; empty where none was made.
+        self._synced: Dict = {}
         self.step_no = 0
         self._next_req_id = 0
         self._submit_wall: Dict[int, float] = {}
@@ -459,6 +463,7 @@ class InferenceServer:
                     self._finish(seq)
         rows = sorted(self.sched.active)
         decided = 0
+        self._synced = {}
         if rows:
             spec = (self.draft_params is not None
                     and (self.force_spec or self.slo.update(self.step_no)))
@@ -480,7 +485,8 @@ class InferenceServer:
             self.occupancy_sum += len(rows) / self.max_batch
         counts = {"rows": len(rows), "admitted": admitted,
                   "finished": len(finished), "decided": 1 + decided}
-        with span("observe", "serve", {"step": self.step_no, **counts}):
+        with span("observe", "serve",
+                  {"step": self.step_no, **counts, **self._synced}):
             if rows:
                 dt_ms = (time.perf_counter() - t0) * 1e3
                 per_tok = dt_ms / (1 + decided)
@@ -494,32 +500,50 @@ class InferenceServer:
         return finished
 
     def _plain_step(self, rows: Sequence[int], feed: np.ndarray) -> None:
+        """One decode step over `rows`, as three spans.  `launch`
+        (dispatches only) says what work the step is: `dstep`, the
+        ordinal of this device step (`device_steps` as it is
+        dispatched); `rows` and `rows_pct`, the rows stepped, and of
+        `max_batch`; `live_tokens`, the positions the step reads up to,
+        summed over them, each row's own new token counted (`pos + 1`:
+        what `seq.pos` reads AFTER the step); and what the cache alone
+        knows (`DecodeCache.step_args`).  Inside it `put` is the view's
+        refresh and the step's inputs put on the device,
+        `write_through` the carrying of the new slots to where they are
+        kept; the rest of `launch` is the dispatch.  `fetch` is the
+        step's one sync.  What it brought is left for `observe`
+        (`_synced`), which opens after it."""
         base = self.row_pos.copy()
-        # what the step reads of what the cache holds: its view's blocks
-        # or its rows' states, whichever the cache answers
-        read = {name: round(pct, 2) for name, pct in (
-            ("view_read_pct", self.pool.view_read_pct(base)),
-            ("state_read_pct", self.pool.state_read_pct(base)))
-            if pct is not None}
-        with span("launch", "serve", read or None):   # dispatches only
-            for cache, _ in self._caches:
-                cache.refresh()
+        work = {"dstep": self.device_steps, "rows": len(rows),
+                "rows_pct": round(100.0 * len(rows) / self.max_batch, 2),
+                "live_tokens": int(base.sum()) + len(rows),  # idle: 0
+                **self.pool.step_args(base)}
+        with span("launch", "serve", work):
+            with span("put", "serve"):
+                for cache, _ in self._caches:
+                    cache.refresh()
+                lent = self.pool.lend(base)
+                fed = jnp.asarray(feed, jnp.int32)
             self._logits, ids, cache = _serve_step_fn(self.cfg)(
-                self.params, self.pool.lend(base),
-                jnp.asarray(feed, jnp.int32))
+                self.params, lent, fed)
+            del lent, fed       # as temporaries would: let go of here
             self._fresh.clear()
             self.pool.take_back(cache)
-            self.pool.write_through(rows, base)
+            with span("write_through", "serve"):
+                self.pool.write_through(rows, base)
         # the step's one sync: the ids, not the logits they came from
         # (behind them, a patterned model's routing counts a layer)
         with span("fetch", "serve", {"bytes": ids.nbytes}):
             got = np.array(ids)                # copy: row writes on admit
         self._next_ids = got[:self.max_batch]
         routed = got[self.max_batch:].reshape(-1, len(ROUTED))
-        hit, fullest = routed.sum(axis=0).tolist() if len(routed) else (0, 0)
-        self.experts_hit_sum += hit          # in `ROUTED`'s order
-        self.expert_load_max_sum += fullest
-        self.moe_layer_steps += len(routed)
+        self._synced = {"dstep": work["dstep"]}
+        if len(routed):
+            hit, fullest = routed.sum(axis=0).tolist()   # `ROUTED`'s order
+            self.experts_hit_sum += hit
+            self.expert_load_max_sum += fullest
+            self.moe_layer_steps += len(routed)
+            self._synced["experts_hit"] = hit
         for r in rows:
             self.row_pos[r] += 1
             self.sched.active[r].pos = int(self.row_pos[r])

@@ -41,6 +41,18 @@ TOL = 1e-4
 V = 64
 
 
+@pytest.fixture(scope="module", autouse=True)
+def start_without_others_programs():
+    """Let go of what the files before this one compiled in the same
+    process.  After test_torch_shim or test_faults and then test_serve
+    in ONE process (xdist's choice, by timing), XLA's CPU compiler dies
+    of a segmentation fault in `backend_compile_and_load` inside this
+    file, at PR 39 as at PR 40; after either alone, or with their
+    executables dropped first, it does not (CHANGES.md, PR 40, has the
+    runs and the trace)."""
+    jax.clear_caches()
+
+
 def make(seed=0, **kw):
     base = dict(vocab_size=V, d_model=32, n_heads=4, d_head=8, d_ff=64,
                 n_layers=2, n_kv_heads=2, compute_dtype=jnp.float32,

@@ -21,6 +21,16 @@ from horovod_tpu.utils import timeline as tl_mod
 from horovod_tpu.utils.timeline import span, start_timeline, stop_timeline
 
 PHASES = ("admit", "sample", "launch", "fetch", "observe")
+INSIDE_LAUNCH = ("put", "write_through")
+# What a plain step's `launch` says of its work whatever the cache, what
+# each kind of cache adds, and what `observe` says after the step's sync.
+WORK = {"dstep", "rows", "rows_pct", "live_tokens"}
+CACHE_SAYS = {"paged": {"view_read_pct"},
+              "windowed": {"view_read_pct", "ring_tokens"},
+              "retention": {"state_read_pct"}}
+COUNTS = {"step", "rows", "admitted", "finished", "decided"}
+ROUTED = {"experts_hit"}
+WINDOW = 4                         # of the patterned model's ring layers
 OUTPUTS = (2, 4, 3, 5, 2)          # tokens asked of the five requests
 # What the parent of the PR that added the spans gives on this traffic
 # (max_batch 2, fifo): the spans may move neither.
@@ -34,9 +44,10 @@ def model():
     return cfg, transformer_init(jax.random.PRNGKey(0), cfg)
 
 
-def _serve(model, **kw):
+def _serve(model, after_step=None, **kw):
     """Five requests through a two-row server; -> (server, prompts by
-    request, generated tokens by request)."""
+    request, generated tokens by request).  `after_step(server)` is
+    called between steps, where a benchmark's runner looks."""
     cfg, params = model
     srv = InferenceServer(params, cfg, max_seq_tokens=24, max_batch=2,
                           page_tokens=4, **kw)
@@ -45,7 +56,13 @@ def _serve(model, **kw):
     for n in OUTPUTS:
         prompt = rng.randint(0, 64, size=4)
         prompts[srv.submit(prompt.tolist(), n)] = prompt
-    done = srv.run()
+    if after_step is None:
+        done = srv.run()
+    else:
+        done = []
+        while not srv.sched.drained():
+            done.extend(srv.step())
+            after_step(srv)
     return srv, prompts, {s.req.req_id: list(s.generated) for s in done}
 
 
@@ -162,8 +179,10 @@ def test_step_and_observe_carry_the_steps_counts(traced):
     for s in steps:
         assert set(s[3]) == {"step", "queued", "active"}
         obs, = _inside(traced["spans"], s, "hvd.serve.observe")
-        assert set(obs[3]) == {"step", "rows", "admitted", "finished",
-                               "decided"}
+        # a step that made a sync says of which device step; this model
+        # routes nothing, so nothing of experts
+        assert set(obs[3]) == COUNTS | ({"dstep"} if obs[3]["rows"]
+                                        else set())
         assert obs[3]["step"] == s[3]["step"]
         prefills = _inside(traced["spans"], s, "hvd.serve.prefill")
         assert obs[3]["admitted"] == len(prefills)
@@ -178,8 +197,10 @@ def test_step_and_observe_carry_the_steps_counts(traced):
 def test_phases_nest_without_overlap_and_cover_the_step(traced):
     covered = total = 0
     for s in _steps(traced["spans"]):
+        launches = _inside(traced["spans"], s, "hvd.serve.launch")
+        held = [k for l in launches for k in _inside(traced["spans"], l)]
         kids = [k for k in _inside(traced["spans"], s)
-                if k[0] != "hvd.serve.prefill"]
+                if k[0] != "hvd.serve.prefill" and k not in held]
         names = [k[0].rsplit(".", 1)[1] for k in kids]
         rows = _inside(traced["spans"], s, "hvd.serve.observe")[0][3]["rows"]
         # a step that decodes has all five, in this order; one that only
@@ -187,6 +208,12 @@ def test_phases_nest_without_overlap_and_cover_the_step(traced):
         assert names == (list(PHASES) if rows
                          else ["admit", "sample", "observe"])
         for a, b in zip(kids, kids[1:]):
+            assert a[2] <= b[1]
+        # the host's two parts of a launch lie inside it, one after the
+        # other; what is left of it is the dispatch
+        assert [k[0].rsplit(".", 1)[1] for k in held] == \
+            (list(INSIDE_LAUNCH) if rows else [])
+        for a, b in zip(held, held[1:]):
             assert a[2] <= b[1]
         covered += sum(k[2] - k[1] for k in kids)
         total += s[2] - s[1]
@@ -255,10 +282,10 @@ def test_launch_carries_the_view_read_share(model, traced, tmp_path):
     says instead: `test_retention_launch_carries_the_state_read_share`)."""
     launches = [s for s in traced["spans"] if s[0] == "hvd.serve.launch"]
     assert len(launches) == traced["srv"].device_steps
-    assert all(s[3] == {"view_read_pct": 100.0} for s in launches)
+    assert all(set(s[3]) == WORK | CACHE_SAYS["paged"] for s in launches)
+    assert all(s[3]["view_read_pct"] == 100.0 for s in launches)
     events = [e for e in traced["events"] if e["name"] == "launch"]
-    assert [e["args"] for e in events] == \
-        [{"view_read_pct": 100.0}] * len(launches)
+    assert [e["args"] for e in events] == [s[3] for s in launches]
     cfg, _ = model
     rcfg = dataclasses.replace(cfg, attn_kind="retention", n_kv_heads=2)
     (srv, _, _), spans = _profiled(tmp_path, lambda: _serve(
@@ -288,7 +315,8 @@ def test_retention_launch_carries_the_state_read_share(model, tmp_path,
     assert srv.pool.kernel == kernel
     launches = [s for s in spans if s[0] == "hvd.serve.launch"]
     assert len(launches) == srv.device_steps > 0
-    assert all(set(s[3]) == {"state_read_pct"} for s in launches)
+    assert all(set(s[3]) == WORK | CACHE_SAYS["retention"]
+               for s in launches)
     shares = [s[3]["state_read_pct"] for s in launches]
     if kernel:
         assert set(shares) == {50.0, 100.0}     # one row live, or both
@@ -320,6 +348,18 @@ def test_paged_cache_reckons_the_blocks_a_step_reads(model, kw, positions,
     assert pool.view_read_pct(np.asarray(positions)) == pytest.approx(want)
 
 
+def _patterned_cfg():
+    from horovod_tpu.models.transformer import AttnSpec
+    return TransformerConfig(
+        vocab_size=64, d_model=32, d_head=8, d_ff=64, n_layers=3,
+        n_kv_heads=2, compute_dtype=jnp.float32,
+        layer_attn=("full", "sliding", "full"),
+        layer_mlp=("dense", "experts", "experts"),
+        attn_specs=(("full", AttnSpec(4)), ("sliding", AttnSpec(6, WINDOW))),
+        attn_gate=True, n_experts=8, experts_per_token=2, expert_ff=16,
+        shared_ff=16, routed_scale=2.5)
+
+
 def test_patterned_model_counts_its_routing_in_the_one_sync(tmp_path):
     """A model with routed experts: the step's one sync brings the ids
     and, behind them, two counts a sparse layer (`fetch` says how many
@@ -327,15 +367,7 @@ def test_patterned_model_counts_its_routing_in_the_one_sync(tmp_path):
     `expert_load_max_sum` and `moe_layer_steps`; `prefill` keeps its
     arguments, `pages` counting the full layers' pages; a uniform model
     counts nothing."""
-    from horovod_tpu.models.transformer import AttnSpec
-    cfg = TransformerConfig(
-        vocab_size=64, d_model=32, d_head=8, d_ff=64, n_layers=3,
-        n_kv_heads=2, compute_dtype=jnp.float32,
-        layer_attn=("full", "sliding", "full"),
-        layer_mlp=("dense", "experts", "experts"),
-        attn_specs=(("full", AttnSpec(4)), ("sliding", AttnSpec(6, 4))),
-        attn_gate=True, n_experts=8, experts_per_token=2, expert_ff=16,
-        shared_ff=16, routed_scale=2.5)
+    cfg = _patterned_cfg()
     (srv, prompts, tokens), spans = _profiled(
         tmp_path, lambda: _serve((cfg, transformer_init(
             jax.random.PRNGKey(1), cfg))))
@@ -350,7 +382,7 @@ def test_patterned_model_counts_its_routing_in_the_one_sync(tmp_path):
     assert srv.moe_layer_steps <= srv.expert_load_max_sum <= 2 * rows
     assert srv.logit_fetches == 0
     # the pages' view answers for the view (24 slots: the einsum's)
-    assert all(s[3] == {"view_read_pct": 100.0} for s in spans
+    assert all(s[3]["view_read_pct"] == 100.0 for s in spans
                if s[0] == "hvd.serve.launch")
     for p in (s for s in spans if s[0] == "hvd.serve.prefill"):
         assert set(p[3]) == {"req", "prompt_tokens", "row", "pages",
@@ -364,6 +396,137 @@ def test_uniform_model_counts_no_routing(traced):
             srv.moe_layer_steps) == (0, 0, 0)
 
 
+# -- a step's work, on its own spans, whatever the cache -------------------
+
+@pytest.fixture(scope="module", params=sorted(CACHE_SAYS))
+def worked(request, model, tmp_path_factory):
+    """A server of each kind of cache run inside a profiler session,
+    with what a benchmark's runner would have summed between its steps
+    from `sched.active` (runners/lm_serve.py `live_tokens_sum`,
+    pattern_serve.py `ring_tokens_sum`) kept beside."""
+    kind = request.param
+    cfg, params = model
+    if kind == "windowed":
+        cfg = _patterned_cfg()
+    elif kind == "retention":
+        cfg = dataclasses.replace(cfg, attn_kind="retention", n_kv_heads=2)
+    if kind != "paged":
+        params = transformer_init(jax.random.PRNGKey(1), cfg)
+    summed = {"live_tokens": 0, "ring_tokens": 0}
+
+    def runner_sums(srv):
+        for seq in srv.sched.active.values():
+            summed["live_tokens"] += seq.pos
+            summed["ring_tokens"] += min(seq.pos, WINDOW)
+
+    (srv, prompts, tokens), spans = _profiled(
+        tmp_path_factory.mktemp(kind),
+        lambda: _serve((cfg, params), after_step=runner_sums))
+    return dict(kind=kind, model=(cfg, params), srv=srv, tokens=tokens,
+                spans=spans, summed=summed)
+
+
+def _named(spans, name):
+    return [s for s in spans if s[0] == "hvd.serve." + name]
+
+
+def test_launch_and_observe_say_what_the_cache_can(worked):
+    """`launch` of a plain step: the step's ordinal, its rows and live
+    tokens, and from the cache its own share read, `ring_tokens` from a
+    cache that keeps rings and from no other.  `observe`: the step whose
+    sync it follows, and that step's routing from a model that routes."""
+    kind, spans = worked["kind"], worked["spans"]
+    launches, observes = _named(spans, "launch"), _named(spans, "observe")
+    assert launches
+    for s in launches:
+        assert set(s[3]) == WORK | CACHE_SAYS[kind]
+        assert s[3]["rows_pct"] == 100.0 * s[3]["rows"] / 2
+    synced = [o for o in observes if o[3]["rows"]]
+    assert len(synced) == len(launches)
+    for o in observes:
+        assert set(o[3]) == COUNTS | (
+            set() if not o[3]["rows"]
+            else {"dstep"} | ROUTED if kind == "windowed" else {"dstep"})
+    # the sync an iteration makes is of the step it launched
+    assert [o[3]["dstep"] for o in synced] == \
+        [s[3]["dstep"] for s in launches]
+    assert [o[3]["rows"] for o in synced] == [s[3]["rows"] for s in launches]
+
+
+def test_sums_of_the_arguments_are_the_servers_counters(worked):
+    """Over a whole run, to the last digit: the work the spans carry is
+    what the server counted and what a runner would have summed."""
+    srv, spans, kind = worked["srv"], worked["spans"], worked["kind"]
+    launches, observes = _named(spans, "launch"), _named(spans, "observe")
+    assert [s[3]["dstep"] for s in launches] == list(range(srv.device_steps))
+    assert sum(s[3]["rows"] for s in launches) == srv.occupancy_sum * 2
+    assert sum(s[3]["rows_pct"] for s in launches) == \
+        100 * srv.occupancy_sum
+    assert sum(s[3]["live_tokens"] for s in launches) == \
+        worked["summed"]["live_tokens"]
+    assert sum(o[3].get("experts_hit", 0) for o in observes) == \
+        srv.experts_hit_sum
+    assert (srv.experts_hit_sum > 0) == (kind == "windowed")
+    if kind == "windowed":
+        assert sum(s[3]["ring_tokens"] for s in launches) == \
+            worked["summed"]["ring_tokens"]
+        # the rings have wrapped: the two sums differ
+        assert worked["summed"]["ring_tokens"] < \
+            worked["summed"]["live_tokens"]
+
+
+def test_put_and_write_through_lie_inside_launch(worked):
+    """The host's two parts of a launch, for every cache (a retention
+    server carries nothing to anywhere: its `write_through` opens all
+    the same, around nothing), in order and apart; nothing else lies in
+    a launch, and each launch is followed by its fetch."""
+    spans = worked["spans"]
+    launches = _named(spans, "launch")
+    for l in launches:
+        put, wt = _inside(spans, l)
+        assert (put[0], wt[0]) == ("hvd.serve.put",
+                                   "hvd.serve.write_through")
+        assert l[1] <= put[1] <= put[2] <= wt[1] <= wt[2] <= l[2]
+        assert put[3] == {} and wt[3] == {}
+    assert len(_named(spans, "put")) == len(launches) == \
+        len(_named(spans, "write_through")) == len(_named(spans, "fetch"))
+
+
+def test_tracing_moves_no_token_of_any_cache(worked):
+    """The same server with nothing on: the same tokens, steps and
+    counters."""
+    assert tl_mod.get_timeline() is None
+    srv, _, tokens = _serve(worked["model"])
+    assert tokens == worked["tokens"]
+    for name in ("device_steps", "step_no", "occupancy_sum", "tokens_out",
+                 "experts_hit_sum", "expert_load_max_sum",
+                 "moe_layer_steps"):
+        assert getattr(srv, name) == getattr(worked["srv"], name)
+
+
+@pytest.mark.parametrize("positions,narrow,want", [
+    ([0, 0, 0, 0], 0, 0), ([1, 0, 2, 0], 0, 2 + 3),
+    ([3, 4, 9, 0], 0, 4 + 4 + 4), ([3, 4, 9, 0], 2, None),
+], ids=["idle", "filling", "wrapped", "two_kinds_of_ring"])
+def test_windowed_cache_reckons_the_tokens_in_its_rings(positions, narrow,
+                                                        want):
+    """`WindowedKVPool.step_args`: `min(pos + 1, window)` over the rows
+    that are stepped, beside the pages' share of the view; on the host.
+    `ring_tokens` is ONE kind of ring's: a model that also has rings of
+    `narrow` slots says the pages' share alone."""
+    from horovod_tpu.models.transformer import AttnSpec
+    from horovod_tpu.serve.pool import WindowedKVPool
+    cfg = _patterned_cfg()
+    if narrow:
+        cfg = dataclasses.replace(
+            cfg, layer_attn=("full", "sliding", "narrow"),
+            attn_specs=cfg.attn_specs + (("narrow", AttnSpec(6, narrow)),))
+    pool = WindowedKVPool(cfg, 8, 4, rows=4, view_pages=6)
+    assert pool.step_args(np.asarray(positions)) == {
+        "view_read_pct": 100.0,
+        **({} if want is None else {"ring_tokens": want})}
+
+
 def test_speculative_round_is_one_launch(model, tmp_path):
     cfg, params = model
     (srv, _, _), spans = _profiled(
@@ -372,6 +535,12 @@ def test_speculative_round_is_one_launch(model, tmp_path):
     assert srv.spec_steps > 0
     names = {s[0] for s in spans}
     assert "hvd.serve.launch" in names and "hvd.serve.fetch" not in names
+    # a round is no plain step: it says nothing of a step's work, and
+    # its launch is not split
+    assert all(s[3] == {} for s in spans if s[0] == "hvd.serve.launch")
+    assert not names & {"hvd.serve.put", "hvd.serve.write_through"}
+    assert all("dstep" not in s[3] for s in spans
+               if s[0] == "hvd.serve.observe")
 
 
 def test_same_tokens_and_steps_with_everything_off(model, traced):
