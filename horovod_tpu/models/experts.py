@@ -35,15 +35,28 @@ Training (`expert_layer_train`, from `models/pattern.py` under
 `custom_vjp` whose backward is a grouped product against the transposed
 weights for the rows and `tgmm` for the weights, so gradients reach the
 held experts' three stacks (an expert no token chose gets exact zeros),
-the router through the weights, and the tokens.  Rows of the sorted
-pairs that lie in no group are never written by either kernel: they are
-masked going in and coming out, so that nothing unwritten reaches a
-sum, forward or backward.  The layer's OWN experts are handed to the
-kernel there (`stack[j]`, cast to the compute dtype: a copy of 8
-experts, where the float32 master weights need a cast anyway), so that
-`tgmm` writes a gradient of one layer's experts and not of every
-layer's.  It counts `TRAINED`: `ROUTED` and the pairs that lay in a
-group here.
+the router through the weights, and the tokens.  The kernels visit the
+row tiles a group reaches and write no other row.  What lies between
+them, in the SORTED rows, follows the pairs as they do (`_worked`): the
+rows are cut into chunks of `_CHUNK_TILES` row tiles, and the gather of
+the tokens' rows into the sorted order (`_spread`), `up * silu(gate)`
+and its gradient (`_gated`), the sum of the two row gradients
+(`_twice`) and the gather of the output's gradient (`_collect`'s
+backward) pass over the chunks up to the last that holds a pair here,
+masked inside it; the chunks behind it are zeros, written once, that
+nothing reads or computes.  Dropless as before: no capacity, no bound
+on the pairs, no other path; with every pair here every chunk is
+worked, and the sorted rows of one chunk or less (any small model) are
+one masked pass with no loop.  The two gathers back into the TOKENS'
+order stay whole (`_collect` forward, `_spread` backward): the pairs
+that lie nowhere are spread among a token's others there, so no chunk
+of them is empty until the exchange hands a chip its own pairs only
+(ROADMAP R1).  The layer's OWN experts are handed to the kernel
+(`stack[j]`, cast to the compute dtype: a copy of 8 experts, where the
+float32 master weights need a cast anyway), so that `tgmm` writes a
+gradient of one layer's experts and not of every layer's.  It counts
+`TRAINED`: `ROUTED`, the pairs that lay in a group here, and the sorted
+rows `_worked` passed over (whole chunks: how tight they are).
 """
 
 from __future__ import annotations
@@ -62,7 +75,7 @@ from ..ops.pallas_kernels import _interpret
 #: what an expert layer counts a pass, in this order
 ROUTED = ("experts_hit", "expert_load_max")
 #: what a TRAINED expert layer counts a step
-TRAINED = ROUTED + ("pairs_here",)
+TRAINED = ROUTED + ("pairs_here", "rows_worked")
 
 
 def sparse_layers(cfg) -> int:
@@ -198,6 +211,96 @@ def _train_row_tile(pairs: int) -> int:
     return 512 if pairs >= 4096 else 128
 
 
+#: Row tiles (`_train_row_tile`) in a chunk of the sorted rows (`_worked`):
+#: 8192 rows at the benchmark's 131072 pairs a layer.  One sweep on the
+#: v5e at that shape (PERF.md 6, PR 41).
+_CHUNK_TILES = 16
+
+
+def _chunk_rows(rows: int) -> int:
+    return _CHUNK_TILES * _train_row_tile(rows)
+
+
+def _rows_worked(total, rows: int):
+    """The sorted rows `_worked` passes over, of `rows`: the chunks up to
+    the last that holds one of the `total` pairs here, whole."""
+    step = _chunk_rows(rows)
+    if rows <= step:
+        return jnp.asarray(rows, jnp.int32)
+    return (total + step - 1) // step * step
+
+
+def _worked(fn, total, *xs):
+    """`fn(*xs)` for a `fn` that works ROW BY ROW on arrays of the sorted
+    pairs' rows (`xs` [P, ...] each; an array or a tuple of them back,
+    [P, ...] each), over the rows that hold a pair: the rows are cut into
+    chunks of `_chunk_rows`, a loop of dynamic length passes over the
+    chunks up to the last that starts below `total`, writing into zeros,
+    and rows from `total` on inside that last chunk are masked; the
+    chunks behind it are read and computed by nobody.  ONE copy of `fn`
+    in the program whatever P is.  P of one chunk or less is one masked
+    pass and no loop; a longer P is whole chunks (`expert_layer_train`
+    pads the pairs to them).  The loop has no reverse rule: for use inside a
+    `custom_vjp`'s two functions.  A whole array that `fn` closes over
+    goes through `_held` first."""
+    P = xs[0].shape[0]
+    step = _chunk_rows(P)
+    tree_map = jax.tree_util.tree_map
+
+    def masked(start, out):
+        return tree_map(lambda o: jnp.where(
+            (start + jnp.arange(o.shape[0]) < total).reshape(
+                (-1,) + (1,) * (o.ndim - 1)), o, 0), out)
+
+    if P <= step:
+        return masked(0, fn(*xs))
+
+    def body(c, out):
+        start = c * step
+        part = masked(start, fn(*(
+            jax.lax.dynamic_slice_in_dim(x, start, step) for x in xs)))
+        return tree_map(
+            lambda o, p: jax.lax.dynamic_update_slice_in_dim(o, p, start, 0),
+            out, part)
+
+    zeros = tree_map(
+        lambda a: jnp.zeros((P,) + a.shape[1:], a.dtype),
+        jax.eval_shape(fn, *(jax.ShapeDtypeStruct(
+            (step,) + x.shape[1:], x.dtype) for x in xs)))
+    return jax.lax.fori_loop(0, _rows_worked(total, P) // step, body, zeros)
+
+
+def _held(x):
+    """x as an array of its own: the compiler moves what makes a loop's
+    operand INTO the loop where it can (the whole [P, D] gradient made
+    again in every chunk: 1.7 ms a chunk on the v5e, PERF.md 6, PR 41)."""
+    return jax.lax.optimization_barrier(x)
+
+
+def _gated_rows(up, gate):
+    return (up * jax.nn.silu(gate.astype(jnp.float32))).astype(up.dtype)
+
+
+@jax.custom_vjp
+def _gated(up, gate, total):
+    """`up * silu(gate)`, [R, F], and its gradients, over the rows that
+    hold a pair."""
+    return _worked(_gated_rows, total, up, gate)
+
+
+def _gated_fwd(up, gate, total):
+    return _gated(up, gate, total), (up, gate, total)
+
+
+def _gated_bwd(res, g):
+    up, gate, total = res
+    return _worked(lambda g, *xs: jax.vjp(_gated_rows, *xs)[1](g), total,
+                   g, up, gate) + (None,)
+
+
+_gated.defvjp(_gated_fwd, _gated_bwd)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def grouped_product(x, w, sizes, out_dtype):
     """x [P, K], rows sorted by group, times w [groups, K, N] group by
@@ -205,8 +308,10 @@ def grouped_product(x, w, sizes, out_dtype):
     `megablox.gmm` with its backward (the rows' gradient a grouped
     product against the transposed weights, the weights' `tgmm`, which
     gives a group of no rows exact zeros), each of the three products at
-    tiles of its own shape.  Rows in no group are not written, forward
-    or backward: `expert_layer_train` masks them."""
+    tiles of its own shape.  Rows in no group are neither read nor
+    written, forward or backward: what comes out of here goes through
+    `_worked` before anything reads every row of it.  (`tgmm` takes its
+    rows transposed and transposes them back: the compiler drops both.)"""
     P, K = x.shape
     return _gmm_kernel(x, w, sizes, out_dtype,
                        (_train_row_tile(P), _tile(K), _tile(w.shape[2])),
@@ -234,40 +339,69 @@ grouped_product.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 @jax.custom_vjp
-def _spread(h, order, inv):
-    """Tokens' rows h [N, D] to the sorted pairs: [P, D], row r the token
-    of pair `order[r]` (`inv` the inverse permutation).  The gradient is
-    a GATHER by `inv` and a sum over a token's k pairs: the scatter-add
-    XLA derives took three times as long on the v5e (PERF.md, PR 37)."""
-    return h[order // (order.shape[0] // h.shape[0])]
+def _spread(h, order, inv, total):
+    """Tokens' rows h [N, D] to the sorted pairs: [R, D] for `order` [R]
+    (the pairs by expert, padded to whole chunks), row r the token of
+    pair `order[r]` where r < `total`, the pairs that lie in a group
+    here, and zeros behind (`_worked`: the gather stops at the last chunk
+    that holds a pair).  `inv` [P] is the inverse permutation: the
+    gradient is a GATHER by it, over every pair since the pairs that lie
+    nowhere are spread among a token's others, and a sum over a token's k
+    pairs: the scatter-add XLA derives took three times as long on the
+    v5e (PERF.md, PR 37)."""
+    k, h = inv.shape[0] // h.shape[0], _held(h)
+    return _worked(lambda o: h[o // k], total, order)
 
 
-def _spread_fwd(h, order, inv):
-    return _spread(h, order, inv), (inv, h.shape[0])
+def _spread_fwd(h, order, inv, total):
+    return _spread(h, order, inv, total), (inv, h.shape[0])
 
 
 def _spread_bwd(res, g):
     inv, n = res
     return (g[inv].reshape(n, -1, g.shape[-1]).astype(jnp.float32)
-            .sum(axis=1).astype(g.dtype), None, None)
+            .sum(axis=1).astype(g.dtype), None, None, None)
 
 
 _spread.defvjp(_spread_fwd, _spread_bwd)
 
 
 @jax.custom_vjp
-def _collect(y, order, inv):
-    """Sorted pairs' rows y [P, D] back to the pairs' own order: a
-    permutation, so its gradient is the gather by `order`."""
+def _twice(x, total):
+    """x [R, D] for two readers, so that their two gradients are summed
+    over the rows that hold a pair and nowhere else."""
+    return x, x
+
+
+def _twice_fwd(x, total):
+    return (x, x), total
+
+
+def _twice_bwd(total, g):
+    return _worked(jnp.add, total, *g), None
+
+
+_twice.defvjp(_twice_fwd, _twice_bwd)
+
+
+@jax.custom_vjp
+def _collect(y, order, inv, total):
+    """Sorted pairs' rows y [R, D] back to the pairs' own order, [P, D]:
+    a gather by `inv` over every pair (rows in no group come as the
+    kernel left them: the caller masks by `here`).  Its gradient is the
+    gather by `order`, which gives SORTED rows and stops where they
+    do."""
     return y[inv]
 
 
-def _collect_fwd(y, order, inv):
-    return y[inv], (order,)
+def _collect_fwd(y, order, inv, total):
+    return y[inv], (order, total)
 
 
 def _collect_bwd(res, g):
-    return g[res[0]], None, None
+    order, total = res
+    g = _held(g)
+    return _worked(lambda o: g[o], total, order), None, None, None
 
 
 _collect.defvjp(_collect_fwd, _collect_bwd)
@@ -288,26 +422,25 @@ def expert_layer_train(mp: Dict, h, cfg):
         here, order, sizes = _sort_pairs(idx, cfg, None)
     with jax.named_scope("hvd.moe.experts"):
         P = N * k
-        pad = -P % _train_row_tile(P)
-        # rows in no group are written by no kernel, forward or backward:
-        # masked going in (which masks the gradient coming back) and out
-        valid = (jnp.arange(P + pad) < jnp.sum(sizes))[:, None]
+        # whole row tiles for the kernels, whole chunks for `_worked`
+        step = _chunk_rows(P)
+        rows = P + (-P % (step if P > step else _train_row_tile(P)))
+        total = jnp.sum(sizes)
         inv = jnp.argsort(order)
-        xs = jnp.where(valid, jnp.pad(_spread(h, order, inv),
-                                      ((0, pad), (0, 0))), 0)
-        up = grouped_product(xs, experts["wi"].astype(dt), sizes, dt)
-        gate = jax.nn.silu(
-            grouped_product(xs, experts["wg"].astype(dt), sizes, dt)
-            .astype(jnp.float32))
-        mid = jnp.where(valid, (up * gate).astype(dt), 0)
-        y = jnp.where(valid, grouped_product(
-            mid, experts["wd"].astype(dt), sizes, dt), 0)
-        # back to the tokens' order, each pair times its weight
-        out = jnp.einsum(
-            "nkd,nk->nd",
-            _collect(y[:P], order, inv).reshape(N, k, -1).astype(
-                jnp.float32), jnp.where(here, w, 0.0))
-    counts = jnp.stack([jnp.sum(sizes > 0), jnp.max(sizes), jnp.sum(sizes)])
+        order = jnp.pad(order, (0, rows - P))
+        # sorted rows: zeros from `total` on in all that `_worked` makes
+        xa, xb = _twice(_spread(h, order, inv, total), total)
+        up = grouped_product(xa, experts["wi"].astype(dt), sizes, dt)
+        gate = grouped_product(xb, experts["wg"].astype(dt), sizes, dt)
+        mid = _gated(up, gate, total)
+        y = grouped_product(mid, experts["wd"].astype(dt), sizes, dt)
+        # back to the tokens' order, each pair times its weight; a pair
+        # whose expert is elsewhere reads a row no kernel wrote
+        y = jnp.where(here[..., None],
+                      _collect(y, order, inv, total).reshape(N, k, -1), 0)
+        out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), w)
+    counts = jnp.stack([jnp.sum(sizes > 0), jnp.max(sizes), total,
+                        _rows_worked(total, rows)])
     return out, counts.astype(jnp.int32)
 
 

@@ -164,7 +164,8 @@ def pattern_loss_shard(params: Dict, tokens, targets,
                        cfg: TransformerConfig, dp: bool):
     """Per-shard (loss, counts), both replicated over `dp`: the mean
     cross-entropy over every replica's tokens; of the counts, the
-    fullest replica's `ROUTED` and the replicas' summed `pairs_here`."""
+    fullest replica's `ROUTED` and the replicas' summed `pairs_here` and
+    `rows_worked`."""
     x, counts = pattern_forward(params, tokens, cfg)
     row = jax.checkpoint(functools.partial(
         _row_loss, params["embed"], params["final_norm"]["scale"],
@@ -196,8 +197,8 @@ def make_pattern_train_step(mesh, cfg: TransformerConfig, optimizer):
     """`make_train_step` for a patterned model: (step, shard_state,
     shard_lm_batch).  step(params, opt_state, (tokens, targets)) ->
     (params, opt_state, loss) and, for a model with routed experts, a
-    fourth value: `TRAINED` a sparse layer, [sparse layers, 3] int32,
-    left on the device (no sync of its own)."""
+    fourth value: `TRAINED` a sparse layer, [sparse layers, len(TRAINED)]
+    int32, left on the device (no sync of its own)."""
     from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 

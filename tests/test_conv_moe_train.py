@@ -262,6 +262,144 @@ def test_an_expert_no_token_chose_gets_an_exactly_zero_gradient(
     assert not np.asarray(dh)[away].any()
 
 
+# -- the sorted rows that hold a pair (`experts._worked`) --------------------
+
+CHUNK = 128          # `_CHUNK_TILES` 1 at the test's row tile of 128
+# pairs that lie in a group of experts 0..3, of the 1024 of 256 tokens:
+# none; inside the first chunk; on a chunk's edge; one past it; all
+HERE = [0, 60, CHUNK, CHUNK + 1, 1024]
+HELD = (0, 4)
+
+
+def steered(total):
+    """(mp with all 16 experts, h) of a layer in which, with experts 0..3
+    held (`HELD`), exactly `total` of the 1024 (token, expert) pairs lie in
+    a group: a token of kind A chooses experts 0..3 (four pairs here), of
+    kind C 3..6 (one), of kind B 4..7 (none), by a feature of its own that
+    the router reads at weight 1 (the other features are noise to it)."""
+    N, D = 256, CFG.d_model
+    a, c = divmod(total, 4)
+    kind = np.full(N, 1)
+    kind[np.random.RandomState(total).permutation(N)[:a + c]] = \
+        [0] * a + [2] * c
+    ks = jax.random.split(jax.random.PRNGKey(total), 6)
+    h = jax.random.normal(ks[0], (N, D)).at[:, :3].set(
+        10.0 * jax.nn.one_hot(kind, 3))
+    router = 0.01 * jax.random.normal(ks[1], (D, 16))
+    for row, chosen in enumerate([range(0, 4), range(4, 8), range(3, 7)]):
+        router = router.at[row].set(
+            jnp.zeros(16).at[jnp.asarray(chosen)].set(1.0))
+    F = CFG.expert_ff
+    mp = {"router": router, "router_bias": jnp.zeros(16),
+          "experts": {"wi": 0.1 * jax.random.normal(ks[2], (16, D, F)),
+                      "wg": 0.1 * jax.random.normal(ks[3], (16, D, F)),
+                      "wd": 0.1 * jax.random.normal(ks[4], (16, F, D))}}
+    return mp, h
+
+
+@pytest.mark.parametrize("total", HERE)
+def test_the_chunked_layer_is_the_single_pass_layer(total, monkeypatch):
+    """`out`, the tokens' gradient, the three expert stacks' and the
+    router's, with the sorted rows in eight chunks against one pass."""
+    mp, h = steered(total)
+    assert 1024 <= experts._chunk_rows(1024)        # one pass as shipped
+    one = share_of(mp, h, HELD)
+    monkeypatch.setattr(experts, "_CHUNK_TILES", 1)
+    assert experts._chunk_rows(1024) == CHUNK
+    cut = share_of(mp, h, HELD)
+    assert int(one[1][2]) == int(cut[1][2]) == total
+    # what each worked: every row, and the chunks up to the last pair
+    assert int(one[1][3]) == 1024
+    assert int(cut[1][3]) == -(-total // CHUNK) * CHUNK
+    for got, want in zip(jax.tree_util.tree_leaves((cut[0], cut[2])),
+                         jax.tree_util.tree_leaves((one[0], one[2]))):
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    out, (dh, de, _) = cut[0], cut[2]
+    if total:
+        assert np.asarray(out).any() and np.asarray(dh).any()
+        assert all(np.asarray(de[n]).any() for n in ("wi", "wg", "wd"))
+    else:
+        assert not np.asarray(out).any() and not np.asarray(dh).any()
+
+
+@pytest.mark.parametrize("total", HERE)
+def test_rows_in_no_group_read_zero(total, monkeypatch):
+    """Every array of sorted rows that `_worked` makes, forward or
+    backward, is zero from `total` on, though what it is made FROM is
+    whatever a kernel left there (NaN here)."""
+    monkeypatch.setattr(experts, "_CHUNK_TILES", 1)
+    mp, h = steered(total)
+    cfg = dataclasses.replace(CFG, experts_held=HELD)
+    idx, _ = experts.route(mp["router"], h, cfg, mp["router_bias"])
+    _, order, sizes = experts._sort_pairs(idx, cfg, None)
+    assert int(jnp.sum(sizes)) == total
+    t, inv = jnp.sum(sizes), jnp.argsort(order)
+    R, D, F = 1024, h.shape[1], cfg.expert_ff
+    dead = (jnp.arange(R) >= total)[:, None]
+    left = lambda key, width: jnp.where(
+        dead, jnp.nan, jax.random.normal(jax.random.PRNGKey(key), (R, width)))
+
+    def zero_behind(rows):
+        rows = np.asarray(rows)
+        assert rows.shape[0] == R and not rows[total:].any()
+        assert np.isfinite(rows).all()
+        if total:
+            assert rows[:total].any()
+
+    xs, spread_back = jax.vjp(
+        lambda h: experts._spread(h, order, inv, t), h)
+    zero_behind(xs)
+    np.testing.assert_array_equal(xs[:total], h[order[:total] // 4])
+    _, twice_back = jax.vjp(lambda x: experts._twice(x, t), xs)
+    zero_behind(twice_back((left(1, D), left(2, D)))[0])
+    up, gate = left(3, F), left(4, F)
+    mid, gated_back = jax.vjp(lambda u, g: experts._gated(u, g, t), up, gate)
+    zero_behind(mid)
+    for grad in gated_back(left(5, F)):
+        zero_behind(grad)
+    y = left(6, D)
+    back, collect_back = jax.vjp(
+        lambda y: experts._collect(y, order, inv, t), y)
+    zero_behind(collect_back(jnp.ones((R, D)))[0])
+    # the pairs' own order: a pair here reads its sorted row
+    here = np.asarray(inv) < total
+    assert np.isfinite(np.asarray(back)[here]).all()
+    assert np.isnan(np.asarray(back)[~here]).all()
+
+
+@pytest.mark.parametrize("dp", [1, 2])
+def test_rows_worked_is_counted_and_summed_over_replicas(dp, monkeypatch):
+    """`rows_worked`, the fourth of `TRAINED`: whole chunks, at least the
+    pairs here and under a chunk more a replica; summed over `dp` as
+    `pairs_here` is, where `ROUTED` is the fullest replica's."""
+    if len(jax.devices()) < dp:
+        pytest.skip(f"needs {dp} devices")
+    assert experts.TRAINED == experts.ROUTED + ("pairs_here", "rows_worked")
+    monkeypatch.setattr(experts, "_CHUNK_TILES", 1)
+    cfg = dataclasses.replace(CFG, experts_held=(0, 4))
+    p = transformer_init(jax.random.PRNGKey(3), cfg)
+    toks = weights.lm_tokens(weights.seed_key(5), 0, 2, T + 1, V)
+    tokens, targets = toks[:, :-1], toks[:, 1:]
+    opt = optax.sgd(0.0)
+    mesh = create_hybrid_mesh(devices=jax.devices()[:dp], dp=dp)
+    step, shard_state, shard_batch = make_train_step(mesh, cfg, opt)
+    # a replica's own counts: its rows of the batch through the walk
+    alone = [np.asarray(pattern.pattern_forward(p, rows, cfg)[1])
+             for rows in np.split(np.asarray(tokens), dp)]
+    sp, so = shard_state(jax.tree_util.tree_map(jnp.array, p), opt.init(p))
+    c = np.asarray(step(sp, so, shard_batch((tokens, targets)))[3])
+    assert c.shape == (experts.sparse_layers(cfg), len(experts.TRAINED))
+    n = len(experts.ROUTED)
+    np.testing.assert_array_equal(c[:, :n], np.max(alone, axis=0)[:, :n])
+    np.testing.assert_array_equal(c[:, n:], np.sum(alone, axis=0)[:, n:])
+    pairs, worked = c[:, 2], c[:, 3]
+    chunk = experts._chunk_rows(tokens.size // dp * cfg.experts_per_token)
+    assert chunk == CHUNK
+    assert (worked % chunk == 0).all()
+    assert (worked >= pairs).all() and (worked < pairs + dp * chunk).all()
+    assert (worked < tokens.size * cfg.experts_per_token).all()  # skipped
+
+
 def test_recomputation_changes_no_number(params, batch, monkeypatch):
     """Checkpointed (as the step always is) against the same walk with
     every intermediate kept, bitwise on the CPU."""
@@ -315,6 +453,7 @@ def test_three_adamw_steps_follow_the_reference(params, dp):
     c = np.stack(counts)                 # [steps, sparse layers, TRAINED]
     assert c.shape == (3, 2, len(experts.TRAINED))
     assert (c[..., 2] == 2 * T * CFG.experts_per_token).all()   # all held
+    assert (c[..., 3] == c[..., 2]).all()    # one pass a replica: every row
     # the fullest replica's fullest expert; the replicas' pairs together
     assert (c[..., 0] <= 16).all()
     assert (c[..., 1] >= c[..., 2] / 16 / dp).all()

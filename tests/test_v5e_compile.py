@@ -249,12 +249,17 @@ def test_patterned_train_step_fits_beside_its_state(one_chip, monkeypatch):
     lfm2-8b-a1b-train.json at B 4 x 8192, through `make_train_step`): the
     compiler takes it, its temporaries fit beside the 6.1 GB of
     parameters and AdamW's moments, and they are less than ONE sequence's
-    float32 scores of one layer ([32, 8192, 8192]: 8.6 GB), so that no
-    [H, T, T] is kept, forward or backward (attention is the flash
-    kernel's calls), and less than the logits of the batch and their
-    gradient (the loss is taken a sequence at a time).  The routed
+    float32 scores of one layer ([32, 8192, 8192]: 8.6 GB), and no
+    [.., T, T] array is in the program, forward or backward (attention is
+    the flash kernel's calls), and less than the logits of the batch and
+    their gradient (the loss is taken a sequence at a time).  The routed
     experts' products are the grouped kernels: three forward, three
-    recomputed and six backward a sparse layer."""
+    recomputed and six backward a sparse layer; the sorted rows between
+    them are worked in loops (`experts._worked`), seven a sparse layer.
+    A loop's result cannot take an operand's place as a fusion's can, and
+    the scheduler runs loops late and their zeros early: 7.98 GB of
+    temporaries where the step without loops asked for 6.94 (9.06 with
+    one more mask in the layer: PERF.md 6, PR 41)."""
     import json
     import os
 
@@ -302,6 +307,12 @@ def test_patterned_train_step_fits_beside_its_state(one_chip, monkeypatch):
     assert mem.temp_size_in_bytes < scores
     assert mem.temp_size_in_bytes < 6.1e9 + 2 * logits
     text = compiled.as_text()
+    assert f"{T},{T}]" not in text
+    # seven loops over the sorted rows a sparse layer, each ONE loop: the
+    # gather and `up * silu(gate)` forward and recomputed, three backward
+    pairs = B * T * m["num_experts_per_tok"]
+    assert sum(" while(" in line and f"[{pairs}," in line
+               for line in text.splitlines()) == 7 * 4
     assert text.count("tgmm") and text.count("hvd.moe.experts")
     assert text.count("hvd.conv") and text.count("hvd.attn")
 
