@@ -252,7 +252,8 @@ serve_cache_bytes = _REG.gauge(
     "Bytes a patterned model's cache holds, by kind: 'pages' (the pool of "
     "the layers that see the whole context, and the decode view gathered "
     "from it) and 'rings' (one ring of `window` slots a row for the "
-    "layers that see a window, held once).",
+    "layers that see a window, held once); 'latent' (pool and view of a "
+    "model whose layers keep one compressed latent a token).",
     labelnames=("kind",))
 serve_p99_ms = _REG.gauge(
     "hvd_serve_p99_ms",

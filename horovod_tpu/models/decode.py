@@ -88,6 +88,25 @@ contract; a layer's MLP is `_mlp_block` or the routed experts of
 models/experts.py, whose counts a pass ride in the cache as `routed`.
 What extends a ring by a chunk, rolls back, shards or quantizes refuses
 such a model by name (`_needs_uniform`).
+
+Latent attention (a kind whose spec is a `LatentSpec`; "Latent
+attention" below has the equations): the cache holds ONE normed latent
+and ONE rotated key a token and layer, a head of each under the two
+leaves, of unequal width ([L, B, 1, slots, kv_rank] and [L, B, 1, slots,
+key_lanes]).  The layer has two forms over that one cache: a prompt is
+prefilled EXPANDED (`_latent_prefill_layer`: every head's keys and values
+made from the prompt's latents, the flash kernel, heads in groups), a
+row is stepped ABSORBED (`_latent_decode_layer`: the key's expansion
+moved onto the query, ops/decode_attention.py's walk over the live
+blocks with every query head against the one head, the value's expansion
+after it).  The kind's record is looked up from the kind's own
+configuration (`cfg.latent`), so `_pattern_walk` hands each layer what it
+hands a softmax layer.  A long prompt of a wide model goes through the
+dense MLP and the experts in passes of tokens and its residual stream is
+written after every half layer (`_mlp_passes`, `experts.expert_layer`,
+`_written`): a 16384-token prefill keeps 2.1 GB of temporaries where it
+asked for 6.1.  A chunk at a step, the quantized layouts, speculation,
+beam search, sharding and training refuse the kind by name.
 """
 
 from __future__ import annotations
@@ -136,11 +155,21 @@ def _kind(cfg: TransformerConfig) -> _Kind:
         # a ring is filled in one pass: no chunks
         lambda *a, chunk, **kw: _prefill_layer(*a, **kw))
     if cfg.patterned:
-        # the layers are softmax layers, each handed its kind's uniform
-        # configuration by `_pattern_walk`; the cache is a ring a kind.
+        # each layer is handed its kind's uniform configuration by
+        # `_pattern_walk` and is that configuration's own record's (a
+        # softmax layer, or a latent one); the cache is a ring a kind.
         # A convolution kind has no record here yet (ROADMAP R2).
         refuse_unserved(cfg, "serving and generation")
-        return softmax._replace(empty=_empty_pattern)
+        return _Kind(
+            ("k", "v"), True, _empty_pattern,
+            lambda *a, cfg, **kw: _kind(cfg).step(*a, cfg=cfg, **kw),
+            lambda *a, cfg, **kw: _kind(cfg).prefill(*a, cfg=cfg, **kw))
+    if cfg.latent is not None:
+        # a latent kind's layers (`cfg` is `kind_cfg` of such a kind):
+        # leaf one the latent, leaf two the shared rotated key
+        return _Kind(
+            ("k", "v"), True, _empty_latent, _latent_decode_layer,
+            lambda *a, chunk, **kw: _latent_prefill_layer(*a, **kw))
     kinds = {
         "softmax": softmax,
         "retention": _Kind(
@@ -209,11 +238,20 @@ def _empty_pattern(cfg, batch, max_len, quantize) -> Dict:
     `max_len` (written at `pos % window`, never more), the others
     `max_len`.  `routed` is what the last pass's expert layers counted
     (`experts.ROUTED`), there so that a scan can carry the cache."""
-    rings = {t: _empty_ring(kc, batch, kc.attn_window or max_len, None)
+    rings = {t: _kind(kc).empty(kc, batch, kc.attn_window or max_len, None)
              for t in cfg.attn_kinds() for kc in (cfg.kind_cfg(t),)}
     return {"k": {t: r["k"] for t, r in rings.items()},
             "v": {t: r["v"] for t, r in rings.items()},
             "routed": experts_mod.no_counts(cfg)}
+
+
+def _empty_latent(cfg, batch, max_len, quantize) -> Dict:
+    # One head under both leaves, as `_empty_ring` lays it: the normed
+    # latent under the first, the rotated key every head shares under the
+    # second, in whole tiles of lanes (`LatentSpec.key_lanes`).
+    sp, lead = cfg.latent, (cfg.n_layers, batch, 1, max_len)
+    return {"k": jnp.zeros(lead + (sp.kv_rank,), cfg.compute_dtype),
+            "v": jnp.zeros(lead + (sp.key_lanes,), cfg.compute_dtype)}
 
 
 def _empty_state(cfg, batch, max_len, quantize) -> Dict:
@@ -528,6 +566,14 @@ def _needs_slots(cfg: TransformerConfig, what: str,
 
 def _needs_uniform(cfg: TransformerConfig, what: str,
                    asked: bool = True) -> None:
+    if asked and cfg.latent_kinds():
+        raise InvalidRequestError(
+            f"{what} is not supported for a model with a latent kind of "
+            f"attention layer (a LatentSpec: "
+            f"{', '.join(cfg.latent_kinds())}): its cache is one latent "
+            "and one shared key a token, written by a whole prompt "
+            "(expanded) or by one token a row (absorbed), with nothing yet "
+            "to quantize, shard, extend by a chunk or roll back")
     if asked and cfg.patterned:
         raise InvalidRequestError(
             f"{what} is not supported for a model with a layer pattern "
@@ -760,6 +806,199 @@ def _retention_prefill_layer(lp, cs, cz, i, x, cfg: TransformerConfig,
             _state_put(cz, i, sa[..., Dh]))
 
 
+# -- latent attention --------------------------------------------------------
+# A latent kind's layer (`LatentSpec`, `cfg.latent`; multi-head latent
+# attention as `deepseek_v3` publishes it).  Per token u = norm1(x) at
+# position p: c_q = norm(u W_qa); a head's query (q^n [nope], q^r [rope]) =
+# c_q W_qb, q^r rotated; (c', k') = u W_kva, c = norm(c'), r = rope(k', p).
+# c [kv_rank] and r [rope_dim] are ALL the cache holds of the token, one
+# head under each of the two leaves, for every query head.  A head's key is
+# (c W_uk [nope], r) and its value c W_uv [v_dim], (W_uk, W_uv) = W_kvb's
+# two parts; softmax over `sp.softmax_scale` times q . k.  Two forms over
+# the one cache, the same numbers up to rounding:
+#   EXPANDED (a prompt, `_latent_prefill_layer`): every head's keys and
+#     values are made from c and the prompt attends to them through the
+#     flash kernel, one kv head a query head;
+#   ABSORBED (a step, `_latent_decode_layer`): W_uk goes onto the query,
+#     qc = q^n W_uk^T [kv_rank], the scores are s (qc . c_j + q^r . r_j),
+#     the values ARE the latents, z = sum_j p_j c_j, and W_uv comes after,
+#     o = z W_uv: no key or value of a head is ever written, and the read
+#     is ops/decode_attention.py's walk over the live blocks with all the
+#     query heads against the one head.
+# One token a row at a step (a chunk refuses by name), no window, no tp.
+
+
+def _latent_low(lp, x, positions, cfg: TransformerConfig):
+    """What both forms start from, for x [B, c, D] at `positions`: (c_q
+    [B, c, q_rank] normed, c [B, c, 1, kv_rank] normed, r [B, c, 1, rope]
+    rotated), in the compute dtype; `_lanes` gives r as the cache holds
+    it."""
+    dt, sp = cfg.compute_dtype, cfg.latent
+    h = _rmsnorm(lp["ln1"]["scale"], x)
+    cq = _rmsnorm(lp["q_norm"]["scale"],
+                  jnp.einsum("btd,dr->btr", h, lp["wq_a"].astype(dt)))
+    kv = jnp.einsum("btd,dr->btr", h, lp["wkv_a"].astype(dt))
+    c = _rmsnorm(lp["kv_norm"]["scale"], kv[..., :sp.kv_rank])[:, :, None]
+    r = _rotate(kv[:, :, None, sp.kv_rank:], positions, cfg).astype(dt)
+    return cq, c, r
+
+
+def _latent_queries(cq, wq_b, positions, cfg: TransformerConfig):
+    """The heads of `wq_b` [q_rank, h, nope + rope]: (q^n [B, c, h, nope],
+    q^r [B, c, h, rope] rotated)."""
+    dt, sp = cfg.compute_dtype, cfg.latent
+    q = jnp.einsum("btr,rhk->bthk", cq, wq_b.astype(dt))
+    return (q[..., :sp.nope_dim],
+            _rotate(q[..., sp.nope_dim:], positions, cfg).astype(dt))
+
+
+def _lanes(a, sp):
+    """a [..., rope_dim] with zeros behind it up to `sp.key_lanes`."""
+    return jnp.pad(a, ((0, 0),) * (a.ndim - 1)
+                   + ((0, sp.key_lanes - a.shape[-1]),))
+
+
+def _latent_out(lp, x, o, cfg: TransformerConfig):
+    """Heads' outputs o [B, c, H, v_dim] through wo, onto the residual."""
+    dt = cfg.compute_dtype
+    out = jnp.einsum("bthk,hkd->btd", o.astype(dt), lp["wo"].astype(dt))
+    return x + out.astype(x.dtype)
+
+
+def _latent_attend_view(qc, qr, ck, cv, i, pos, scale):
+    """The absorbed read as contractions over EVERY slot of the view: qc
+    [B, H, kv_rank] and qr [B, H, rope] against layer `i` of the stacked
+    leaves, masked on each slot's reconstructed absolute position (`pos`
+    [B]); z [B, H, kv_rank] float32.  The path of a ring the kernel does
+    not take (`decode_attention.reads_live`)."""
+    lc = _cache_layer(ck, i)[:, 0].astype(jnp.float32)       # [B, S, R]
+    lr = _cache_layer(cv, i)[:, 0, :, :qr.shape[-1]].astype(jnp.float32)
+    S = lc.shape[1]
+    s = (jnp.einsum("bhr,bsr->bhs", qc.astype(jnp.float32), lc)
+         + jnp.einsum("bhk,bsk->bhs", qr.astype(jnp.float32), lr)) * scale
+    held = pos[:, None] - ((pos[:, None] - jnp.arange(S)[None, :]) % S)
+    p = jax.nn.softmax(jnp.where((held >= 0)[:, None, :], s, -1e30), axis=-1)
+    return jnp.einsum("bhs,bsr->bhr", p, lc)
+
+
+def _refuse_latent_tp(tp_axis) -> None:
+    if tp_axis is not None:
+        raise InvalidRequestError(
+            "tensor parallelism is not supported for a latent kind of "
+            "attention layer (a LatentSpec): its heads share one latent a "
+            "token, which a split of the heads would have to copy")
+
+
+def _latent_decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
+                         tp_axis=None):
+    """Layer `i` of a latent kind for ONE new token a row, ABSORBED: x
+    [B, 1, D]; ck [L, B, 1, S, kv_rank] and cv [L, B, 1, S, key_lanes]
+    the whole stacked leaves, updated in place at `pos % S` under
+    `_layer_walk`'s contract; `pos` scalar or [B], as in
+    `_decode_layer`."""
+    _refuse_latent_tp(tp_axis)
+    B, c = x.shape[:2]
+    if c != 1:
+        raise InvalidRequestError(
+            f"a latent kind of attention layer (a LatentSpec) is stepped "
+            f"one token a row, got a chunk of {c}: the absorbed form has "
+            "no mask inside a chunk")
+    dt, sp = cfg.compute_dtype, cfg.latent
+    S = cache_slots(ck)
+    pos = jnp.asarray(pos)
+    positions = pos[:, None] if pos.ndim == 1 else pos[None]
+    cq, lat, r = _latent_low(lp, x, positions, cfg)
+    qn, qr = _latent_queries(cq, lp["wq_b"], positions, cfg)
+    write = _cache_write_rows if pos.ndim == 1 else _cache_write
+    ck = write(ck, i, lat, pos % S)
+    cv = write(cv, i, _lanes(r, sp), pos % S)
+    wkv = lp["wkv_b"].astype(dt)                       # [R, H, nope + v]
+    with jax.named_scope("hvd.attn.latent"):
+        qc = jnp.einsum("bhk,rhk->bhr", qn[:, 0], wkv[..., :sp.nope_dim])
+        rows = jnp.broadcast_to(pos, (B,))
+        if decode_attention.reads_live(S):
+            z = decode_attention.decode_attention(
+                jnp.concatenate([qc.astype(dt), _lanes(qr[:, 0], sp)],
+                                -1)[:, None],
+                ck, cv, i, rows, scale=sp.softmax_scale, latent=True)[:, 0]
+        else:
+            z = _latent_attend_view(qc, qr[:, 0], ck, cv, i, rows,
+                                    sp.softmax_scale)
+        o = jnp.einsum("bhr,rhv->bhv", z.astype(dt), wkv[..., sp.nope_dim:])
+    return _latent_out(lp, x, o[:, None], cfg), ck, cv
+
+
+#: The flash kernel's tiles (block_q, block_k) for a latent kind's prompt,
+#: each clamped to a divisor of the padded length: at the kernel's own
+#: 128 x 128 a prompt of 8192 tokens is 262144 grid steps a layer for 64
+#: heads (PERF.md 6, PR 42: the sweep on the v5e).
+_LATENT_FLASH_BLOCKS = (1024, 1024)
+
+#: Numbers one head group's q, k or v of a prompt may hold
+#: (`_latent_prefill_layer`): 8 of 64 heads at 16384 tokens of width 192,
+#: 32 at 4096, every head up to 2048.
+_PROMPT_HEAD_NUMBERS = 2 ** 25
+
+
+def _latent_prefill_layer(lp, ck, cv, i, x, cfg: TransformerConfig,
+                          tp_axis=None):
+    """Layer `i` of a latent kind over a whole prompt x [B, T0, D],
+    EXPANDED: slots 0..T0-1 of both leaves are written in place (the
+    latents and the shared keys, nothing a head) and nothing of the cache
+    is read; every head's keys and values are made from the prompt's own
+    latents and attended to causally, through the flash kernel from 128
+    tokens on.  The kernel scales by 1/sqrt of the head width it is
+    handed and takes one width for q, k and v: the narrower of key and
+    value is padded with zeros to the wider (no score and no output
+    changes; at 128 + 64 and 192 nothing is) and the layer's own scale
+    goes onto q.  A long prompt's heads go in groups, one after another,
+    each expanded from the latents when its turn comes: all 64 heads' q,
+    k and v of 16384 tokens would be 1.2 GB beside the same again
+    transposed for the kernel."""
+    _refuse_latent_tp(tp_axis)
+    dt, sp = cfg.compute_dtype, cfg.latent
+    B, T0, H = x.shape[0], x.shape[1], sp.n_heads
+    positions = jnp.arange(T0)
+    cq, lat, r = _latent_low(lp, x, positions, cfg)
+    ck = _cache_write(ck, i, lat, 0)
+    cv = _cache_write(cv, i, _lanes(r, sp), 0)
+    wide = max(sp.nope_dim + sp.rope_dim, sp.v_dim)
+
+    def heads(ws):                  # one group's (wq_b, wkv_b)
+        qn, qr = _latent_queries(cq, ws[0], positions, cfg)
+        kv = jnp.einsum("btr,rhk->bthk", lat[:, :, 0], ws[1].astype(dt))
+        q = jnp.concatenate([qn, qr], -1)
+        k = jnp.concatenate(
+            [kv[..., :sp.nope_dim],
+             jnp.broadcast_to(r, q.shape[:3] + (sp.rope_dim,))], -1)
+        v = kv[..., sp.nope_dim:]
+        if T0 >= 128:
+            q = (q.astype(jnp.float32)
+                 * (sp.softmax_scale * wide ** 0.5)).astype(dt)
+            padded = lambda a: jnp.pad(
+                a, ((0, 0),) * 3 + ((0, wide - a.shape[-1]),))
+            return _flash_prompt(padded(q), padded(k), padded(v), None,
+                                 _LATENT_FLASH_BLOCKS)[..., :sp.v_dim]
+        s = jnp.einsum("bthk,bshk->bhts", q.astype(jnp.float32),
+                       k.astype(jnp.float32)) * sp.softmax_scale
+        p = jax.nn.softmax(jnp.where(
+            jnp.tril(jnp.ones((T0, T0), bool)), s, -1e30), axis=-1)
+        return jnp.einsum("bhts,bshk->bthk", p,
+                          v.astype(jnp.float32)).astype(dt)
+
+    with jax.named_scope("hvd.attn.latent"):
+        hg = max(h for h in range(1, H + 1) if H % h == 0
+                 and h <= max(1, _PROMPT_HEAD_NUMBERS // (B * T0 * wide)))
+        if hg == H:
+            o = heads((lp["wq_b"], lp["wkv_b"]))
+        else:
+            group = lambda w: jnp.moveaxis(
+                w.reshape(w.shape[0], H // hg, hg, w.shape[-1]), 1, 0)
+            o = lax.map(heads, (group(lp["wq_b"]), group(lp["wkv_b"])))
+            o = jnp.moveaxis(o, 0, 2).reshape(B, T0, H, sp.v_dim)
+    return _latent_out(lp, x, o, cfg), ck, cv
+
+
 def _moe_tokens(mp, scale, x, cfg: TransformerConfig):
     """No-capacity top-1 MoE for decode/prefill: x [B, T, D] ->
     residual-added output.  All experts run on all tokens and the
@@ -778,6 +1017,43 @@ def _moe_tokens(mp, scale, x, cfg: TransformerConfig):
     out = jnp.einsum("ne,end->nd", onehot * gate[:, None],
                      oe.astype(jnp.float32))
     return x + out.reshape(B, T, D).astype(x.dtype)
+
+
+#: Numbers one pass of a patterned model's dense MLP may hold in its
+#: widened form (`_mlp_passes`): 8192 tokens at a width of 8192, 2048 at
+#: 18432.
+_MLP_PASS_NUMBERS = 2 ** 26
+
+
+def _mlp_passes(mp, x, cfg: TransformerConfig):
+    """`_mlp_block` on x [B, T, D], a long prompt of a wide model in
+    passes of tokens, one after another: 16384 tokens widened to 18432
+    are 604 MB three times over."""
+    B, T, D = x.shape
+    step = 1 << max((_MLP_PASS_NUMBERS // cfg.d_ff).bit_length() - 1, 0)
+    if B * T <= step:
+        return _mlp_block(mp, x, cfg, None)
+    rows = x.reshape(B * T, D)
+    rows = jnp.pad(rows, ((0, -(B * T) % step), (0, 0)))
+    out = lax.map(lambda r: _mlp_block(mp, r[None], cfg, None)[0],
+                  rows.reshape(-1, step, D))
+    return out.reshape(-1, D)[:B * T].reshape(B, T, D)
+
+
+#: From this many bytes on a patterned model's residual stream is WRITTEN
+#: after every half layer (`_written`).
+_RESIDUAL_BYTES = 2 ** 26
+
+
+def _written(x):
+    """x as an array of its own where it is large.  Left to itself the
+    compiler keeps every half layer's addend of the residual stream and
+    sums them again inside each reader: at 16384 tokens of width 7168
+    ten buffers of 235 MB and the attention outputs behind them, 3 GB of
+    a prefill's temporaries."""
+    if x.size * x.dtype.itemsize < _RESIDUAL_BYTES:
+        return x
+    return lax.optimization_barrier(x)
 
 
 def _pattern_walk(params, ck, cv, x, attn_fn, cfg, live, routed):
@@ -800,17 +1076,18 @@ def _pattern_walk(params, ck, cv, x, attn_fn, cfg, live, routed):
         lp = jax.tree_util.tree_map(lambda p: p[j], params["attn"][t])
         x, ck[t], cv[t] = attn_fn(lp, ck[t], cv[t], j, x,
                                   cfg=cfg.kind_cfg(t))
+        x = _written(x)
         # the experts' stack is not sliced: `experts._grouped` has why
         mp = jax.tree_util.tree_map(
             lambda p: p[jm], {n: p for n, p in params["mlp"][m].items()
                               if n != "experts"})
         if m == "dense":
-            x = _mlp_block(mp, x, cfg, None)
+            x = _written(_mlp_passes(mp, x, cfg))
             continue
         h = _rmsnorm(mp["ln2"]["scale"], x).reshape(B * T, D).astype(dt)
         out, counts = experts_mod.expert_layer(
             mp, params["mlp"][m]["experts"], jm, h, cfg, tokens_live)
-        x = x + out.reshape(B, T, D).astype(x.dtype)
+        x = _written(x + out.reshape(B, T, D).astype(x.dtype))
         routed.append(counts)
     return x, ck, cv
 

@@ -15,7 +15,11 @@ largest chosen, by `s` or, where the layer carries a `router_bias`, by
 `s + bias` (the bias takes part in the CHOICE only: the weights are of
 `s`, and nothing differentiates through a choice), their scores
 renormalised to sum 1 (over `sum + cfg.route_eps`) and times
-`routed_scale`; the weight goes on the expert's OUTPUT.
+`routed_scale`; the weight goes on the expert's OUTPUT.  With
+`cfg.route_groups` the experts are that many groups in order, a group
+scores the sum of its two best choice scores, and a token chooses among
+the experts of its `route_groups_kept` best groups only (`_kept_groups`);
+with none, `route` is what it was, bit for bit.
 
 Product: the (token, expert) pairs are sorted by expert and each of an
 expert's three matrices meets its own rows in ONE grouped product
@@ -27,8 +31,13 @@ those of the routed pairs, up to a row tile an expert hit.  Decode and
 prefill take the same path; only the row tile differs (`_row_tile`).
 
 `expert_layer` also counts, for whoever watches the routing
-(`ROUTED`): the distinct experts held here that a token chose, and the
-most tokens one expert took.
+(`ROUTED`): the distinct experts held here that a token chose, the most
+tokens one expert took, and the pairs that lay in a held expert's group
+(every pair where all experts are held; a share's worth where a share is,
+as a served GigaChat layer holds 16 of 256).  A pass longer than
+`_pass_tokens` (a 16384-token prompt at a hidden width of 7168: every
+pair has a row, here or not, 1.9 GB of them) goes in passes of tokens,
+one after another, under the same counts.
 
 Training (`expert_layer_train`, from `models/pattern.py` under
 `make_train_step`) is the same layer under `jax.grad`: `gmm` is a
@@ -73,9 +82,9 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import (
 from ..ops.pallas_kernels import _interpret
 
 #: what an expert layer counts a pass, in this order
-ROUTED = ("experts_hit", "expert_load_max")
+ROUTED = ("experts_hit", "expert_load_max", "pairs_here")
 #: what a TRAINED expert layer counts a step
-TRAINED = ROUTED + ("pairs_here", "rows_worked")
+TRAINED = ROUTED + ("rows_worked",)
 
 
 def sparse_layers(cfg) -> int:
@@ -95,17 +104,33 @@ def route(router, h, cfg, bias=None) -> Tuple[jax.Array, jax.Array]:
     scores = jax.nn.sigmoid(jnp.einsum(
         "nd,de->ne", h, router.astype(h.dtype),
         preferred_element_type=jnp.float32))
-    if bias is None:
+    if bias is None and not cfg.route_groups:
         top, idx = jax.lax.top_k(scores, cfg.experts_per_token)
     else:
-        _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias),
-                               cfg.experts_per_token)
+        choice = scores if bias is None \
+            else scores + jax.lax.stop_gradient(bias)
+        if cfg.route_groups:
+            choice = _kept_groups(choice, cfg)
+        _, idx = jax.lax.top_k(choice, cfg.experts_per_token)
         top = jnp.take_along_axis(scores, idx, axis=-1)
     scaled = cfg.routed_scale * top
     den = jnp.sum(top, axis=-1, keepdims=True)
     if cfg.route_eps:
         den = den + cfg.route_eps
     return idx.astype(jnp.int32), scaled / den
+
+
+def _kept_groups(choice, cfg):
+    """Choice scores [N, n_experts] with every expert outside a token's
+    `route_groups_kept` best groups at -inf: the experts are
+    `route_groups` groups of equal size in order, and a group scores the
+    sum of its two largest choice scores."""
+    N, E = choice.shape
+    G = cfg.route_groups
+    best2, _ = jax.lax.top_k(choice.reshape(N, G, E // G), 2)
+    _, kept = jax.lax.top_k(jnp.sum(best2, axis=-1), cfg.route_groups_kept)
+    keep = jnp.zeros((N, G), bool).at[jnp.arange(N)[:, None], kept].set(True)
+    return jnp.where(jnp.repeat(keep, E // G, axis=1), choice, -jnp.inf)
 
 
 def _row_tile(pairs: int) -> int:
@@ -126,14 +151,17 @@ def _grouped(x, stack, j: int, sizes, out_dtype):
     groups of which only layer j's have rows: a slice of it handed to a
     kernel is a copy of a layer's experts (537 MB a matrix at 256
     experts of 2048 x 512, written and read again every step), a group
-    with no rows costs nothing."""
+    with no rows costs nothing.  The contraction goes in one tile up to
+    a width of 2048; a wider one (a hidden width of 7168: 29 MB of weight
+    tiles, which the chip's VMEM does not hold) in `_tile`'s."""
     P, K = x.shape
     L, held, _, N = stack.shape
     tn = 1024 if N > 1024 and N % 1024 == 0 else N
     return gmm(x, stack.reshape(L * held, K, N),
                jnp.pad(sizes, (j * held, (L - 1 - j) * held)),
                preferred_element_type=out_dtype,
-               tiling=(_row_tile(P), K, tn), interpret=_interpret())
+               tiling=(_row_tile(P), K if K <= 2048 else _tile(K), tn),
+               interpret=_interpret())
 
 
 def swiglu(p: Dict, h, dt):
@@ -160,6 +188,49 @@ def _sort_pairs(idx, cfg, live):
     return here, order, sizes
 
 
+#: Bytes the sorted rows of one pass may hold in the compute dtype
+#: (`expert_layer`): 8192 tokens at a hidden width of 2048 and 8 experts a
+#: token, 2048 tokens at 7168.
+_PASS_BYTES = 2 ** 28
+
+
+def _pass_tokens(h, cfg) -> int:
+    """Tokens one pass of `expert_layer` takes: the largest power of two
+    whose pairs' rows fit `_PASS_BYTES`."""
+    row = cfg.experts_per_token * h.shape[1] * h.dtype.itemsize
+    return 1 << max((_PASS_BYTES // row).bit_length() - 1, 0)
+
+
+def _expert_pass(mp: Dict, stack: Dict, j: int, h, cfg, live):
+    """`expert_layer` for the tokens of one pass: (out [N, D] float32,
+    sizes [held] int32, the pairs that lay in each held expert's group)."""
+    dt = cfg.compute_dtype
+    N, k = h.shape[0], cfg.experts_per_token
+    with jax.named_scope("hvd.moe.route"):
+        idx, w = route(mp["router"], h, cfg, mp.get("router_bias"))
+        here, order, sizes = _sort_pairs(idx, cfg, live)
+    with jax.named_scope("hvd.moe.experts"):
+        P = N * k
+        pad = -P % _row_tile(P)
+        xs = h[jnp.pad(order // k, (0, pad))]        # [P + pad, D]
+        f32 = jnp.float32
+        up = _grouped(xs, stack["wi"].astype(dt), j, sizes, f32)
+        gate = jax.nn.silu(
+            _grouped(xs, stack["wg"].astype(dt), j, sizes, f32))
+        y = _grouped((up * gate).astype(dt), stack["wd"].astype(dt), j,
+                     sizes, dt)
+        # rows past the pairs that lie in a group were never written
+        y = jnp.where((jnp.arange(P + pad) < jnp.sum(sizes))[:, None], y, 0)
+        # back to the tokens' order, each pair times its weight
+        out = jnp.einsum(
+            "nkd,nk->nd",
+            y[jnp.argsort(order)].reshape(N, k, -1).astype(f32),
+            jnp.where(here, w, 0.0))
+    if "shared" in mp:
+        out = out + swiglu(mp["shared"], h, dt)
+    return out, sizes
+
+
 def expert_layer(mp: Dict, stack: Dict, j: int, h, cfg,
                  live: Optional[jax.Array] = None):
     """The experts' part of sparse layer `j` for tokens h [N, D] (normed,
@@ -171,28 +242,28 @@ def expert_layer(mp: Dict, stack: Dict, j: int, h, cfg,
     (`_grouped` has why).  `live` [N] bool marks the tokens that are
     anybody's (an idle row of a served batch is nobody's): the others
     are routed nowhere, cost nothing and count nothing; their rows of
-    `out` hold the shared expert's part alone."""
-    dt = cfg.compute_dtype
-    N, k = h.shape[0], cfg.experts_per_token
-    idx, w = route(mp["router"], h, cfg, mp.get("router_bias"))
-    here, order, sizes = _sort_pairs(idx, cfg, live)
-    P = N * k
-    pad = -P % _row_tile(P)
-    xs = h[jnp.pad(order // k, (0, pad))]        # [P + pad, D]
-    f32 = jnp.float32
-    up = _grouped(xs, stack["wi"].astype(dt), j, sizes, f32)
-    gate = jax.nn.silu(_grouped(xs, stack["wg"].astype(dt), j, sizes, f32))
-    y = _grouped((up * gate).astype(dt), stack["wd"].astype(dt), j, sizes,
-                 dt)
-    # rows past the pairs that lie in a group were never written
-    y = jnp.where((jnp.arange(P + pad) < jnp.sum(sizes))[:, None], y, 0)
-    # back to the tokens' order, each pair times its weight
-    out = jnp.einsum(
-        "nkd,nk->nd", y[jnp.argsort(order)].reshape(N, k, -1).astype(f32),
-        jnp.where(here, w, 0.0))
-    if "shared" in mp:
-        out = out + swiglu(mp["shared"], h, dt)
-    counts = jnp.stack([jnp.sum(sizes > 0), jnp.max(sizes)])
+    `out` hold the shared expert's part alone.  More tokens than
+    `_pass_tokens` (a long prompt of a wide model: every pair has a row,
+    here or not, until an exchange hands a chip its own) go in passes of
+    that many, one after another, the counts over all of them."""
+    N, D = h.shape
+    step = _pass_tokens(h, cfg)
+    if N <= step:
+        out, sizes = _expert_pass(mp, stack, j, h, cfg, live)
+    else:
+        pad = -N % step
+        live = jnp.ones((N,), bool) if live is None else live
+
+        def one(a):     # kept in the dtype the caller adds it in
+            out, sizes = _expert_pass(mp, stack, j, a[0], cfg, a[1])
+            return out.astype(h.dtype), sizes
+
+        out, sizes = jax.lax.map(
+            one, (jnp.pad(h, ((0, pad), (0, 0))).reshape(-1, step, D),
+                  jnp.pad(live, (0, pad)).reshape(-1, step)))
+        out = out.reshape(-1, D)[:N].astype(jnp.float32)
+        sizes = jnp.sum(sizes, axis=0)
+    counts = jnp.stack([jnp.sum(sizes > 0), jnp.max(sizes), jnp.sum(sizes)])
     return out, counts.astype(jnp.int32)
 
 
@@ -440,7 +511,7 @@ def expert_layer_train(mp: Dict, h, cfg):
                       _collect(y, order, inv, total).reshape(N, k, -1), 0)
         out = jnp.einsum("nkd,nk->nd", y.astype(jnp.float32), w)
     counts = jnp.stack([jnp.sum(sizes > 0), jnp.max(sizes), total,
-                        _rows_worked(total, rows)])
+                        _rows_worked(total, rows)])    # `TRAINED`'s order
     return out, counts.astype(jnp.int32)
 
 

@@ -63,6 +63,10 @@ def refuse_untrained(cfg: TransformerConfig, mesh) -> None:
         if mesh.shape.get(axis, 1) > 1:
             no(f"a {axis} axis (the mesh is dp alone)")
     specs = dict(cfg.attn_specs)
+    if cfg.latent_kinds():
+        no("a latent kind of attention layer (a LatentSpec: "
+           f"{', '.join(cfg.latent_kinds())}; no backward pass through the "
+           "expanded form is written)")
     if any(getattr(specs[t], "window", 0) for t in cfg.attn_kinds()):
         no("an attention window")
     if cfg.attn_gate:
@@ -164,8 +168,8 @@ def pattern_loss_shard(params: Dict, tokens, targets,
                        cfg: TransformerConfig, dp: bool):
     """Per-shard (loss, counts), both replicated over `dp`: the mean
     cross-entropy over every replica's tokens; of the counts, the
-    fullest replica's `ROUTED` and the replicas' summed `pairs_here` and
-    `rows_worked`."""
+    fullest replica's `experts_hit` and `expert_load_max` and the
+    replicas' summed `pairs_here` and `rows_worked`."""
     x, counts = pattern_forward(params, tokens, cfg)
     row = jax.checkpoint(functools.partial(
         _row_loss, params["embed"], params["final_norm"]["scale"],
@@ -174,7 +178,7 @@ def pattern_loss_shard(params: Dict, tokens, targets,
     count = jnp.asarray(targets.size, jnp.float32)
     if dp:
         total, count = lax.psum((total, count), "dp")
-        n = len(experts_mod.ROUTED)
+        n = experts_mod.TRAINED.index("pairs_here")
         counts = jnp.concatenate(
             [lax.pmax(counts[:, :n], "dp"), lax.psum(counts[:, n:], "dp")],
             axis=1)

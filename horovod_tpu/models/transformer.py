@@ -91,6 +91,46 @@ class ConvSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """A LATENT kind of attention layer (multi-head latent attention, as
+    `deepseek_v3` publishes it; models/decode.py, "Latent attention"):
+    queries through a rank of `q_rank` (normed) to `n_heads` heads of
+    `nope_dim + rope_dim`; keys and values through ONE normed latent of
+    `kv_rank` a token, expanded a head to `nope_dim` of key and `v_dim` of
+    value, beside ONE rotated key of `rope_dim` that every head shares.
+    The cache holds the latent and the shared key, `kv_rank + rope_dim`
+    numbers a token and layer whatever the heads (the key in `key_lanes`
+    lanes).  `rotary` turns the `rope_dim` part (all of it); the softmax
+    scale is `(nope_dim + rope_dim) ** -0.5 * scale_factor` (YaRN's
+    `mscale` squared, where the model has one).  No window, no q/k norm
+    a head.  Served and generated; not trained."""
+    n_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rotary: Rotary = Rotary()
+    scale_factor: float = 1.0
+    window = 0              # what the other kinds' readers ask of a spec
+    qk_norm = False
+
+    @property
+    def softmax_scale(self) -> float:
+        return (self.nope_dim + self.rope_dim) ** -0.5 * self.scale_factor
+
+    @property
+    def key_lanes(self) -> int:
+        """The width the shared key is CACHED in: `rope_dim` numbers and
+        zeros up to whole tiles of 128 lanes.  The chip lays a leaf of 64
+        in tiles of (8, 128) and pads it to that in HBM whatever its
+        shape says, and the decode kernel's copies are whole tiles
+        (Mosaic refuses a slice of 64 lanes): the zeros cost no byte that
+        would not be there, and add nothing to a score."""
+        return -(-self.rope_dim // 128) * 128
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     d_model: int = 512
@@ -130,19 +170,26 @@ class TransformerConfig:
     # output.  `expert_bias`: the router carries a `router_bias`
     # [n_experts] that takes part in the CHOICE of experts only (no
     # gradient, no optimizer step); `route_eps` is added to the sum the
-    # chosen scores are renormalised by.  A kind whose spec is a
-    # `ConvSpec` is a gated short convolution in the attention's place.
+    # chosen scores are renormalised by.  `route_groups` > 0: the experts
+    # are that many groups of equal size, a group scores the sum of its
+    # two best choice scores, and a token chooses among the experts of
+    # its `route_groups_kept` best groups only (0: no groups).  A kind
+    # whose spec is a `ConvSpec` is a gated short convolution in the
+    # attention's place; one whose spec is a `LatentSpec` keeps one
+    # compressed latent a token (served and generated, not trained).
     # Empty tuples: one kind for all layers, everything as it was.
     # Served and generated (all but a convolution kind and q/k norm,
     # which refuse by name), and trained on a `dp` mesh
     # (make_train_step -> models/pattern.py: every layer recomputed,
     # attention through the flash kernel; a window, an attention gate, a
-    # shared expert and tp/sp/pp/ep over a pattern refuse by name).
+    # shared expert, a latent kind and tp/sp/pp/ep over a pattern refuse
+    # by name).
     # `moe_every` / `capacity_factor` still mean the uniform model's
     # top-1 layer.
     layer_attn: Tuple[str, ...] = ()
     layer_mlp: Tuple[str, ...] = ()
-    attn_specs: Tuple[Tuple[str, Any], ...] = ()   # AttnSpec | ConvSpec
+    # AttnSpec | ConvSpec | LatentSpec
+    attn_specs: Tuple[Tuple[str, Any], ...] = ()
     attn_gate: bool = False
     experts_per_token: int = 1
     expert_ff: int = 0
@@ -151,6 +198,12 @@ class TransformerConfig:
     experts_held: Optional[Tuple[int, int]] = None
     expert_bias: bool = False
     route_eps: float = 0.0
+    route_groups: int = 0
+    route_groups_kept: int = 0
+    # A latent kind's spec, in the uniform configuration `kind_cfg` makes
+    # of such a kind (its layers and its cache are sized from it); a
+    # model names the kind in `attn_specs` and sets nothing here.
+    latent: Optional[LatentSpec] = None
     # The rotary form of a uniform model whose form is not the plain one
     # (`rope_theta` alone); a patterned model's layers get theirs from
     # `attn_specs`.
@@ -228,6 +281,15 @@ class TransformerConfig:
                         f"attn_specs[{t!r}]: a convolution of "
                         f"{spec.taps} taps")
                 continue
+            if isinstance(spec, LatentSpec):
+                if spec.rope_dim % 2 or min(
+                        spec.n_heads, spec.q_rank, spec.kv_rank,
+                        spec.nope_dim, spec.rope_dim, spec.v_dim) < 1:
+                    raise ValueError(
+                        f"attn_specs[{t!r}]: a latent layer's heads, "
+                        f"ranks and widths are >= 1 and its rope_dim "
+                        f"even, got {spec}")
+                continue
             if spec.n_heads % self.kv_heads:
                 raise ValueError(
                     f"attn_specs[{t!r}]: {spec.n_heads} heads over "
@@ -252,6 +314,15 @@ class TransformerConfig:
                 raise ValueError(
                     f"experts_per_token {self.experts_per_token} of "
                     f"{self.n_experts} experts of width {self.expert_ff}")
+            G, kept = self.route_groups, self.route_groups_kept
+            if G and (self.n_experts % G or self.n_experts // G < 2
+                      or not 1 <= kept <= G
+                      or self.experts_per_token > kept * self.n_experts // G):
+                raise ValueError(
+                    f"route_groups {G} with route_groups_kept {kept}: "
+                    f"{self.n_experts} experts make no such groups of 2 "
+                    f"or more, or the kept ones hold fewer than "
+                    f"experts_per_token {self.experts_per_token}")
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -267,6 +338,11 @@ class TransformerConfig:
         specs = dict(self.attn_specs)
         return tuple(t for t in self.attn_kinds()
                      if isinstance(specs[t], ConvSpec))
+
+    def latent_kinds(self) -> Tuple[str, ...]:
+        specs = dict(self.attn_specs)
+        return tuple(t for t in self.attn_kinds()
+                     if isinstance(specs[t], LatentSpec))
 
     def mlp_kinds(self) -> Tuple[str, ...]:
         return tuple(dict.fromkeys(self.layer_mlp))
@@ -288,10 +364,13 @@ def _kind_cfg(cfg: TransformerConfig, kind: str) -> TransformerConfig:
             "attention configuration; such a model is trained "
             "(make_train_step), not served or generated")
     plain = spec.rotary == Rotary(theta=spec.rotary.theta)
+    latent = spec if isinstance(spec, LatentSpec) else None
     return dataclasses.replace(
         cfg, n_heads=spec.n_heads, attn_window=spec.window,
         rope_theta=spec.rotary.theta,
-        rotary=None if plain else spec.rotary,
+        rotary=None if plain else spec.rotary, latent=latent,
+        # one head of latent and shared key, whatever the query heads
+        n_kv_heads=1 if latent else cfg.n_kv_heads,
         n_layers=cfg.layer_attn.count(kind), prompt_attention="flash",
         layer_attn=(), layer_mlp=(), attn_specs=())
 
@@ -358,6 +437,22 @@ def _pattern_init(key, cfg: TransformerConfig) -> Dict:
                 "w_conv": norm(ks[1], (Lt, D, spec.taps),
                                1.0 / math.sqrt(spec.taps)),
                 "w_out": norm(ks[2], (Lt, D, D), s_d)}
+            continue
+        if isinstance(spec, LatentSpec):
+            H, Rq, R = spec.n_heads, spec.q_rank, spec.kv_rank
+            dn, dr, dv = spec.nope_dim, spec.rope_dim, spec.v_dim
+            ones = lambda n: {"scale": jnp.ones((Lt, n), jnp.float32)}
+            params["attn"][t] = {
+                "ln1": ones(D), "q_norm": ones(Rq), "kv_norm": ones(R),
+                "wq_a": norm(ks[0], (Lt, D, Rq), s_d),
+                "wq_b": norm(ks[1], (Lt, Rq, H, dn + dr),
+                             1.0 / math.sqrt(Rq)),
+                # the latent and, behind it, the shared key before rotation
+                "wkv_a": norm(ks[2], (Lt, D, R + dr), s_d),
+                # a head's key part, then its value
+                "wkv_b": norm(ks[3], (Lt, R, H, dn + dv),
+                              1.0 / math.sqrt(R)),
+                "wo": norm(ks[4], (Lt, H, dv, D), 1.0 / math.sqrt(H * dv))}
             continue
         H = spec.n_heads
         ap = {"ln1": {"scale": jnp.ones((Lt, D), jnp.float32)},

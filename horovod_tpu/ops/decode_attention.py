@@ -37,6 +37,15 @@ A row at `pos` 0 sees one slot, the one just written, with weight
 exactly 1: its output is that slot's V, which the wrapper selects
 outside the kernel (`B x Hkv` vectors), so that idle rows start no copy.
 
+A latent kind's leaves (`latent=True`; models/decode.py, "Latent
+attention") take the same walk: one head of latents [block, 512] and one
+of shared keys [block, 128] (64 numbers and 64 zeros: whole tiles) a
+copy, every query head against them (g is the head count), the block of
+latents used twice, as key and as value, and the scale an argument.
+Such a read is 121 operations a byte where the grouped-query read is 4:
+nearer the chip's ridge than its memory roof, and it still waits for the
+copies.
+
 On the CPU backend the kernel runs interpreted (`_interpret()`), which
 keeps its numerics covered without a chip (tests/test_decode_attention
 .py, against the einsum on the same inputs).
@@ -81,8 +90,9 @@ def read_pct(positions, slots: int, block: int = BLOCK) -> float:
 
 
 def _kernel(layer_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sem, *, scale, window, block):
-    B, Hkv, g, Dh = q_ref.shape
+            kbuf, vbuf, sem, *, scale, window, block, latent):
+    B, Hkv, g, _ = q_ref.shape
+    Dv = o_ref.shape[-1]
     S = k_hbm.shape[3]
     layer = layer_ref[0]
 
@@ -151,9 +161,22 @@ def _kernel(layer_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
             for h in range(Hkv):
                 m, l, acc = carry[h]
                 k, v = kbuf[buf, h], vbuf[buf, h]           # [block, Dh]
-                s = lax.dot_general(
-                    q_ref[b, h], k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
+                contract = (((1,), (1,)), ((), ()))
+                if latent:
+                    # the first leaf is the latent, scored against the
+                    # query's first `Dv` numbers and summed as the value;
+                    # the second the shared key, against the rest
+                    s = lax.dot_general(
+                        q_ref[b, h, :, :Dv], k, contract,
+                        preferred_element_type=jnp.float32)
+                    s = (s + lax.dot_general(
+                        q_ref[b, h, :, Dv:], v, contract,
+                        preferred_element_type=jnp.float32)) * scale
+                    v = k
+                else:
+                    s = lax.dot_general(
+                        q_ref[b, h], k, contract,
+                        preferred_element_type=jnp.float32) * scale
                 s = jnp.where(valid, s, _NEG)               # [g, block]
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
                 alpha = jnp.exp(m - m_new)
@@ -167,7 +190,7 @@ def _kernel(layer_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         init = tuple((jnp.full((g, 1), _NEG, jnp.float32),
                       jnp.zeros((g, 1), jnp.float32),
-                      jnp.zeros((g, Dh), jnp.float32))
+                      jnp.zeros((g, Dv), jnp.float32))
                      for _ in range(Hkv))
         state = lax.fori_loop(0, n, one_block, init)
         for h, (_, l, acc) in enumerate(state):
@@ -179,7 +202,7 @@ def _kernel(layer_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 def decode_attention(q, ck, cv, layer, pos, *, window: int = 0,
-                     block: int = BLOCK):
+                     block: int = BLOCK, scale=None, latent: bool = False):
     """Attention of one query a row over that row's live slots.
 
     q [B, Hkv, g, Dh], rotated; ck, cv [L, B, Hkv, S, Dh], the WHOLE
@@ -188,17 +211,27 @@ def decode_attention(q, ck, cv, layer, pos, *, window: int = 0,
     `pos` [B] int32, each row's depth; `window` the layer's attention
     window, 0 for none.  `S >= block`.  Returns o [B, Hkv, g, Dh] float32:
     softmax(q . K / sqrt(Dh)) . V over the slots whose absolute position
-    is in `(pos - window, pos]`.
+    is in `(pos - window, pos]`; `scale` replaces `1 / sqrt(Dh)`.
+
+    `latent`: the leaves are a latent kind's (models/decode.py, "Latent
+    attention"), ck [L, B, 1, S, R] the latents and cv [L, B, 1, S, Dr]
+    the key all heads share (R and Dr whole tiles of 128 lanes); q
+    [B, 1, H, R + Dr] is every head's absorbed query beside its rotated
+    part, the score of a slot is `scale (q[:R] . c + q[R:] . r)` and the
+    values ARE the latents: z [B, 1, H, R] float32.  A block of latents
+    is copied once and used for both.
     """
     B, Hkv, g, Dh = q.shape
+    Dv = ck.shape[-1] if latent else Dh
     S = ck.shape[3]
     if S < block:
         raise ValueError(f"a ring of {S} slots holds no block of {block}")
     pos = pos.astype(jnp.int32)
     layer = jnp.asarray(layer, jnp.int32)
     o = pl.pallas_call(
-        functools.partial(_kernel, scale=1.0 / (Dh ** 0.5),
-                          window=window, block=block),
+        functools.partial(
+            _kernel, scale=1.0 / (Dh ** 0.5) if scale is None else scale,
+            window=window, block=block, latent=latent),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
@@ -207,19 +240,20 @@ def decode_attention(q, ck, cv, layer, pos, *, window: int = 0,
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((B, Hkv, g, Dh),
+            out_specs=pl.BlockSpec((B, Hkv, g, Dv),
                                    lambda i, *_: (0, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, Hkv, block, Dh), ck.dtype),
-                pltpu.VMEM((2, Hkv, block, Dh), cv.dtype),
+                pltpu.VMEM((2, Hkv, block, ck.shape[-1]), ck.dtype),
+                pltpu.VMEM((2, Hkv, block, cv.shape[-1]), cv.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, Dh), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, Dv), jnp.float32),
         interpret=_interpret(),
         name="decode_attention",
     )(layer.reshape(1), pos, q, ck, cv)
     # A row at depth 0 sees the slot it just wrote and nothing else.
-    first = lax.dynamic_slice(cv, (layer, 0, 0, 0, 0), (1, B, Hkv, 1, Dh))
+    first = lax.dynamic_slice(ck if latent else cv, (layer, 0, 0, 0, 0),
+                              (1, B, Hkv, 1, Dv))
     return jnp.where((pos == 0)[:, None, None, None],
                      first[0].astype(jnp.float32), o)

@@ -1,9 +1,13 @@
 """The serving engine's caches: what `InferenceServer` asks of one
-(`DecodeCache`), the three that answer it (`PagedKVPool` for keys and
+(`DecodeCache`), the four that answer it (`PagedKVPool` for keys and
 values, `StateSlots` for a retention model's state a row, `WindowedKVPool`
 for a patterned model: pages for the layers that see the whole context,
-one ring of `window` slots a row for the layers that see a window), and
-`make_cache`, which picks one from the model's configuration.
+one ring of `window` slots a row for the layers that see a window,
+`KindKVPool` for a patterned model whose layers are of ONE kind that sees
+the whole context: pages and nothing beside them, of per-head keys and
+values or of a latent kind's two leaves of unequal width, one compressed
+latent and one shared key a token), and `make_cache`, which picks one
+from the model's configuration.
 
 A contiguous decode cache ties a sequence's KV bytes to its batch row
 for the whole generation — finished sequences hold pages until the
@@ -621,6 +625,62 @@ class WindowedKVPool(DecodeCache):
         _met.serve_cache_bytes.labels("rings").set(self.ring_bytes)
 
 
+class KindKVPool(PagedKVPool):
+    """The cache of a patterned model whose attention layers are all of
+    ONE kind that sees the whole context: `PagedKVPool` over that kind's
+    layers (`cfg.kind_cfg(kind)`), with no ring beside it.  The pages,
+    the view, the scatters and the gathers are the parent's, whatever the
+    two leaves' widths: a latent kind's (models/decode.py, "Latent
+    attention") are pages of `[1, page_tokens, kv_rank]` and
+    `[1, page_tokens, key_lanes]`, one head of each.  What is its own is
+    the door to the step programs, which take a patterned model's leaves
+    by kind, and the prefill, which is the model's.  Quantized layouts
+    and speculation are refused here, by the kind's name."""
+
+    def __init__(self, cfg, total_pages: int, page_tokens: int,
+                 quantize: Optional[str] = None, rows: int = 0,
+                 view_pages: int = 0, speculative: bool = False):
+        (self.kind,) = cfg.attn_kinds()
+        self.latent = bool(cfg.latent_kinds())
+        named = (f"a latent kind of attention layer (a LatentSpec: "
+                 f"{self.kind})" if self.latent
+                 else "a model with a layer pattern (layer_attn)")
+        for what, asked in (
+                ("quantize", quantize is not None),
+                ("draft_params (speculative serving: the verify pass "
+                 "extends the cache by a chunk)", speculative)):
+            if asked:
+                raise InvalidRequestError(
+                    f"{what} is not supported for {named}")
+        super().__init__(cfg.kind_cfg(self.kind), total_pages, page_tokens,
+                         None, rows, view_pages)
+        self.cfg = cfg          # the model's: what the prefill is built of
+        self.page_bytes = self.k.nbytes + self.v.nbytes
+
+    def board(self, req_id, row, n_tokens, params, prompt, prefill):
+        pids = self.alloc(req_id, n_tokens)
+        scratch = init_decode_cache(self.cfg, 1,
+                                    len(pids) * self.page_tokens)
+        lg, scratch = prefill(params, scratch, jnp.asarray(prompt[None]))
+        self.seat(req_id, row, *(scratch[n][self.kind]
+                                 for n in self.leaves))
+        return lg
+
+    def lend(self, pos) -> Dict:
+        cache = super().lend(pos)
+        return {**{n: {self.kind: cache[n]} for n in self.leaves},
+                "pos": cache["pos"]}
+
+    def take_back(self, cache: Dict) -> None:
+        self.view = tuple(cache[n][self.kind] for n in self.leaves)
+
+    def set_gauges(self) -> None:
+        super().set_gauges()
+        view = sum(a.nbytes for a in self.view or ())
+        _met.serve_cache_bytes.labels(
+            "latent" if self.latent else "pages").set(self.page_bytes + view)
+
+
 def make_cache(cfg, *, rows, view_pages, page_tokens, pool_pages,
                quantize, speculative, rows_held) -> DecodeCache:
     """The `DecodeCache` of a model of this configuration: the one place
@@ -628,11 +688,14 @@ def make_cache(cfg, *, rows, view_pages, page_tokens, pool_pages,
     if cfg.attn_kind == "retention":
         return StateSlots(cfg, rows, rows_held, quantize, speculative)
     if cfg.patterned:
-        return WindowedKVPool(cfg, pool_pages, page_tokens, quantize, rows,
-                              view_pages, speculative)
+        windows = [cfg.kind_cfg(t).attn_window for t in cfg.attn_kinds()]
+        pool = KindKVPool if windows == [0] else WindowedKVPool
+        return pool(cfg, pool_pages, page_tokens, quantize, rows,
+                    view_pages, speculative)
     return PagedKVPool(cfg, pool_pages, page_tokens, quantize, rows,
                        view_pages)
 
 
-__all__ = ["DecodeCache", "PagedKVPool", "PoolExhaustedError",
-           "StateSlots", "WindowedKVPool", "make_cache"]
+__all__ = ["DecodeCache", "KindKVPool", "PagedKVPool",
+           "PoolExhaustedError", "StateSlots", "WindowedKVPool",
+           "make_cache"]

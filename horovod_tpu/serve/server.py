@@ -214,10 +214,12 @@ class InferenceServer:
         self.occupancy_sum = 0.0
         # What a patterned model's expert layers counted (models/experts.py
         # `ROUTED`), summed over layers and steps: distinct experts a
-        # token chose, the most tokens one expert took, and how many
-        # (layer, step) pairs the two sums run over.
+        # token chose, the most tokens one expert took, the (token,
+        # expert) pairs whose expert is held here, and how many (layer,
+        # step) pairs the sums run over.
         self.experts_hit_sum = 0
         self.expert_load_max_sum = 0
+        self.pairs_here_sum = 0
         self.moe_layer_steps = 0
         self.token_latencies_ms: List[float] = []
         self.request_latencies_ms: List[float] = []
@@ -539,11 +541,12 @@ class InferenceServer:
         routed = got[self.max_batch:].reshape(-1, len(ROUTED))
         self._synced = {"dstep": work["dstep"]}
         if len(routed):
-            hit, fullest = routed.sum(axis=0).tolist()   # `ROUTED`'s order
+            hit, fullest, here = routed.sum(axis=0).tolist()  # `ROUTED`
             self.experts_hit_sum += hit
             self.expert_load_max_sum += fullest
+            self.pairs_here_sum += here
             self.moe_layer_steps += len(routed)
-            self._synced["experts_hit"] = hit
+            self._synced.update(experts_hit=hit, pairs_here=here)
         for r in rows:
             self.row_pos[r] += 1
             self.sched.active[r].pos = int(self.row_pos[r])

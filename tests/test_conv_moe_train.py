@@ -371,10 +371,12 @@ def test_rows_in_no_group_read_zero(total, monkeypatch):
 def test_rows_worked_is_counted_and_summed_over_replicas(dp, monkeypatch):
     """`rows_worked`, the fourth of `TRAINED`: whole chunks, at least the
     pairs here and under a chunk more a replica; summed over `dp` as
-    `pairs_here` is, where `ROUTED` is the fullest replica's."""
+    `pairs_here` is, where the two counts before it are the fullest
+    replica's."""
     if len(jax.devices()) < dp:
         pytest.skip(f"needs {dp} devices")
-    assert experts.TRAINED == experts.ROUTED + ("pairs_here", "rows_worked")
+    assert experts.TRAINED == experts.ROUTED + ("rows_worked",)
+    assert experts.TRAINED[2:] == ("pairs_here", "rows_worked")
     monkeypatch.setattr(experts, "_CHUNK_TILES", 1)
     cfg = dataclasses.replace(CFG, experts_held=(0, 4))
     p = transformer_init(jax.random.PRNGKey(3), cfg)
@@ -389,7 +391,7 @@ def test_rows_worked_is_counted_and_summed_over_replicas(dp, monkeypatch):
     sp, so = shard_state(jax.tree_util.tree_map(jnp.array, p), opt.init(p))
     c = np.asarray(step(sp, so, shard_batch((tokens, targets)))[3])
     assert c.shape == (experts.sparse_layers(cfg), len(experts.TRAINED))
-    n = len(experts.ROUTED)
+    n = experts.TRAINED.index("pairs_here")
     np.testing.assert_array_equal(c[:, :n], np.max(alone, axis=0)[:, :n])
     np.testing.assert_array_equal(c[:, n:], np.sum(alone, axis=0)[:, n:])
     pairs, worked = c[:, 2], c[:, 3]
@@ -530,8 +532,12 @@ def test_uniform_and_served_models_keep_their_programs():
     """`mistral7b_train_4k`'s train step and a served pattern's decode
     step and prefill (Laguna's kind: routed experts beside a shared one,
     no bias) lower to the same text as at the parent commit: the new fields at
-    their defaults add no operation.  The digests were taken at the
-    parent (247f370) with this very code; a change of JAX moves both."""
+    their defaults add no operation.  The train step's digest was taken
+    at the parent (247f370) with this very code; the served pattern's two
+    at PR 42, whose expert layer counts a third number a sparse layer
+    (`experts.ROUTED`: `pairs_here`) and nothing else new at the defaults
+    (no groups, no latent kind; a pass is one pass at these sizes).  A
+    change of JAX moves all three."""
     import hashlib
     from horovod_tpu.models import transformer_decode_step
     digest = lambda lowered: hashlib.sha256(
@@ -566,5 +572,5 @@ def test_uniform_and_served_models_keep_their_programs():
     assert (train, decode, prefill) == PARENT_DIGESTS
 
 
-PARENT_DIGESTS = ("cc76927979bf3144", "2812d43bf93cdc95",
-                  "fa30341310c77c55")
+PARENT_DIGESTS = ("cc76927979bf3144", "cffacc3d7f10915b",
+                  "a194293a9374eb98")

@@ -134,9 +134,9 @@ def test_prefill_then_decode_matches_the_reference(model, T0, new):
     assert cache["k"]["full_attention"].shape[3] == slots
     assert int(cache["pos"]) == T0 + new - 1
     # a step of one row: each sparse layer's token chose 4 experts, one
-    # token each
+    # token each, all 4 pairs here
     np.testing.assert_array_equal(np.asarray(cache["routed"]),
-                                  [[4, 1]] * 4)
+                                  [[4, 1, 4]] * 4)
 
 
 def test_generate_is_the_same_walk(model):

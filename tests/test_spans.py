@@ -15,6 +15,7 @@ import pytest
 import horovod_tpu as hvd
 from horovod_tpu.models import (TransformerConfig, transformer_generate,
                                 transformer_init)
+from horovod_tpu.models import experts as experts_mod
 from horovod_tpu.serve import InferenceServer
 from horovod_tpu.trace import core as trace_core
 from horovod_tpu.utils import timeline as tl_mod
@@ -27,9 +28,11 @@ INSIDE_LAUNCH = ("put", "write_through")
 WORK = {"dstep", "rows", "rows_pct", "live_tokens"}
 CACHE_SAYS = {"paged": {"view_read_pct"},
               "windowed": {"view_read_pct", "ring_tokens"},
-              "retention": {"state_read_pct"}}
+              "retention": {"state_read_pct"},
+              "latent": {"view_read_pct"}}
+ROUTING = ("windowed", "latent")   # the kinds whose model routes experts
 COUNTS = {"step", "rows", "admitted", "finished", "decided"}
-ROUTED = {"experts_hit"}
+ROUTED = {"experts_hit", "pairs_here"}
 WINDOW = 4                         # of the patterned model's ring layers
 OUTPUTS = (2, 4, 3, 5, 2)          # tokens asked of the five requests
 # What the parent of the PR that added the spans gives on this traffic
@@ -360,11 +363,29 @@ def _patterned_cfg():
         shared_ff=16, routed_scale=2.5)
 
 
+def _latent_cfg():
+    """Three latent layers (models/decode.py, "Latent attention"), the
+    experts in 4 groups of which 2 are kept, half of group 0 held."""
+    from horovod_tpu.models.transformer import LatentSpec, Rotary
+    spec = LatentSpec(n_heads=4, q_rank=24, kv_rank=16, nope_dim=8,
+                      rope_dim=4, v_dim=12,
+                      rotary=Rotary(theta=1e5, yarn_factor=8,
+                                    yarn_original=8), scale_factor=1.5)
+    return TransformerConfig(
+        vocab_size=64, d_model=32, d_head=12, d_ff=64, n_layers=3,
+        compute_dtype=jnp.float32, layer_attn=("latent",) * 3,
+        layer_mlp=("dense", "experts", "experts"),
+        attn_specs=(("latent", spec),), n_experts=16, experts_per_token=4,
+        expert_ff=16, shared_ff=16, routed_scale=2.5, experts_held=(0, 2),
+        expert_bias=True, route_eps=1e-20, route_groups=4,
+        route_groups_kept=2)
+
+
 def test_patterned_model_counts_its_routing_in_the_one_sync(tmp_path):
     """A model with routed experts: the step's one sync brings the ids
-    and, behind them, two counts a sparse layer (`fetch` says how many
+    and, behind them, three counts a sparse layer (`fetch` says how many
     bytes), from which the server keeps `experts_hit_sum`,
-    `expert_load_max_sum` and `moe_layer_steps`; `prefill` keeps its
+    `expert_load_max_sum`, `pairs_here_sum` and `moe_layer_steps`; `prefill` keeps its
     arguments, `pages` counting the full layers' pages; a uniform model
     counts nothing."""
     cfg = _patterned_cfg()
@@ -374,12 +395,15 @@ def test_patterned_model_counts_its_routing_in_the_one_sync(tmp_path):
     assert {r: len(t) for r, t in tokens.items()} == dict(enumerate(OUTPUTS))
     fetches = [s for s in spans if s[0] == "hvd.serve.fetch"]
     assert len(fetches) == srv.device_steps
-    # [max_batch] ids and (experts hit, fullest expert) of 2 sparse layers
-    assert all(f[3] == {"bytes": 4 * (2 + 2 * 2)} for f in fetches)
+    # [max_batch] ids and `experts.ROUTED` of 2 sparse layers
+    assert len(experts_mod.ROUTED) == 3
+    assert all(f[3] == {"bytes": 4 * (2 + 2 * 3)} for f in fetches)
     assert srv.moe_layer_steps == 2 * srv.device_steps
     rows = srv.occupancy_sum * 2              # active rows, summed
     assert 2 * srv.moe_layer_steps <= srv.experts_hit_sum <= 2 * 2 * rows
     assert srv.moe_layer_steps <= srv.expert_load_max_sum <= 2 * rows
+    # every expert is held: every pair a stepped row routes lies here
+    assert srv.pairs_here_sum == 2 * 2 * rows
     assert srv.logit_fetches == 0
     # the pages' view answers for the view (24 slots: the einsum's)
     assert all(s[3]["view_read_pct"] == 100.0 for s in spans
@@ -393,7 +417,7 @@ def test_patterned_model_counts_its_routing_in_the_one_sync(tmp_path):
 def test_uniform_model_counts_no_routing(traced):
     srv = traced["srv"]
     assert (srv.experts_hit_sum, srv.expert_load_max_sum,
-            srv.moe_layer_steps) == (0, 0, 0)
+            srv.pairs_here_sum, srv.moe_layer_steps) == (0, 0, 0, 0)
 
 
 # -- a step's work, on its own spans, whatever the cache -------------------
@@ -408,6 +432,8 @@ def worked(request, model, tmp_path_factory):
     cfg, params = model
     if kind == "windowed":
         cfg = _patterned_cfg()
+    elif kind == "latent":
+        cfg = _latent_cfg()
     elif kind == "retention":
         cfg = dataclasses.replace(cfg, attn_kind="retention", n_kv_heads=2)
     if kind != "paged":
@@ -446,7 +472,7 @@ def test_launch_and_observe_say_what_the_cache_can(worked):
     for o in observes:
         assert set(o[3]) == COUNTS | (
             set() if not o[3]["rows"]
-            else {"dstep"} | ROUTED if kind == "windowed" else {"dstep"})
+            else {"dstep"} | ROUTED if kind in ROUTING else {"dstep"})
     # the sync an iteration makes is of the step it launched
     assert [o[3]["dstep"] for o in synced] == \
         [s[3]["dstep"] for s in launches]
@@ -466,7 +492,14 @@ def test_sums_of_the_arguments_are_the_servers_counters(worked):
         worked["summed"]["live_tokens"]
     assert sum(o[3].get("experts_hit", 0) for o in observes) == \
         srv.experts_hit_sum
-    assert (srv.experts_hit_sum > 0) == (kind == "windowed")
+    assert sum(o[3].get("pairs_here", 0) for o in observes) == \
+        srv.pairs_here_sum
+    assert (srv.experts_hit_sum > 0) == (kind in ROUTING)
+    # a latent server holds 2 of 16 experts: fewer pairs than the rows
+    # routed, and the gauge of its cache under its own label
+    if kind == "latent":
+        routed = 4 * 2 * srv.occupancy_sum * 2   # k x sparse layers x rows
+        assert 0 < srv.pairs_here_sum < routed
     if kind == "windowed":
         assert sum(s[3]["ring_tokens"] for s in launches) == \
             worked["summed"]["ring_tokens"]

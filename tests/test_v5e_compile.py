@@ -212,7 +212,7 @@ def test_patterned_step_copies_neither_cache_nor_experts(one_chip,
     cache.pop("routed")             # as the server's cache lends it
     lowered = _serve_step_fn(cfg).lower(args[0], cache, args[2])
     logits, ids, out_cache = lowered.out_info
-    assert ids.shape == (PATTERN_ROWS + 4 * 2,)
+    assert ids.shape == (PATTERN_ROWS + 4 * 3,)     # `experts.ROUTED`
     assert out_cache["k"]["sliding_attention"].shape == (3, 32, 8, 512, 128)
     compiled = lowered.compile()
     text = compiled.as_text()
@@ -317,6 +317,103 @@ def test_patterned_train_step_fits_beside_its_state(one_chip, monkeypatch):
     assert text.count("hvd.conv") and text.count("hvd.attn")
 
 
+def _latent_cell():
+    import json
+    import os
+
+    from benchmark.runners.latent_serve import transformer_config
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def load(*p):
+        with open(os.path.join(root, "benchmark", *p)) as f:
+            return json.load(f)
+
+    m = load("configs", "gigachat3.1-702b-a36b-serve.json")
+    sv = load("traffic", "longctx_steady.json")["server"]
+    return m, transformer_config(m), sv
+
+
+#: a v5e's memory as the runtime gives it (`bytes_limit`)
+CHIP_BYTES = 16909336064
+
+
+def _latent_held(m, cfg, sv):
+    """Bytes `gigachat702b_longctx_steady` holds whatever runs: the
+    weights, the pool's pages and the one decode view."""
+    from benchmark.lib import counts_latent
+    token = cfg.n_layers * (512 + 128) * 2      # the key in whole tiles
+    return (2 * counts_latent.param_count(m)
+            + sv["pool_pages"] * m["serve"]["page_tokens"] * token
+            + sv["max_batch"] * sv["max_seq_tokens"] * token)
+
+
+def test_latent_cells_step_fits_and_reads_where_it_lies(one_chip,
+                                                        monkeypatch):
+    """`gigachat702b_longctx_steady`'s decode step (benchmark/configs/
+    gigachat3.1-702b-a36b-serve.json at the traffic's rows and slots):
+    five reads of the latent cache by ops/decode_attention.py's kernel
+    and twelve grouped products over the 16 held experts (a contraction
+    of 7168 in tiles: whole, its weight tiles pass the chip's VMEM); its
+    temporaries stay far under one layer's slice of the view, so no
+    layer's latents are copied out before they are read."""
+    from horovod_tpu.models import experts
+    from horovod_tpu.models.decode import _serve_step_fn
+
+    monkeypatch.setattr(experts, "_interpret", lambda: False)
+    m, cfg, sv = _latent_cell()
+    rows, slots = sv["max_batch"], sv["max_seq_tokens"]
+    args = _step_args(one_chip, cfg, rows, slots)
+    cache = dict(args[1])
+    cache.pop("routed")             # as the server's cache lends it
+    assert cache["k"]["latent"].shape == (5, rows, 1, slots, 512)
+    assert cache["v"]["latent"].shape == (5, rows, 1, slots, 128)
+    lowered = _serve_step_fn(cfg).lower(args[0], cache, args[2])
+    assert lowered.out_info[1].shape == (rows + 4 * 3,)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 5 + 12
+    assert text.count("hvd.attn.latent") and text.count("hvd.moe.experts")
+    mem = compiled.memory_analysis()
+    layer_slice = rows * slots * 512 * 2
+    assert mem.temp_size_in_bytes < layer_slice // 2, mem.temp_size_in_bytes
+    held = _latent_held(m, cfg, sv)
+    assert 0.75 * CHIP_BYTES < held < 0.80 * CHIP_BYTES
+    assert held + mem.temp_size_in_bytes < CHIP_BYTES
+
+
+def test_latent_cells_longest_prefill_fits_beside_the_cache(one_chip,
+                                                            monkeypatch):
+    """The cell's 16384-token prompt, prefilled whole in the expanded
+    form (64 heads of 192 through the flash kernel in groups, the dense
+    MLP and the experts in passes of tokens, the residual stream
+    written): its temporaries fit beside the weights, the pool and the
+    view with room to spare, and no [.., T, T] array is in the program.
+    Left to itself (every head at once, every token at once, the
+    residual stream's addends kept) the program asked for 6.1 GB."""
+    from horovod_tpu.models import experts
+    from horovod_tpu.ops import flash_attention
+    from horovod_tpu.serve.server import _prefill_fn
+
+    monkeypatch.setattr(experts, "_interpret", lambda: False)
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    m, cfg, sv = _latent_cell()
+    T, out = 16384, 512
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(cfg.compute_dtype),
+        transformer_init(jax.random.PRNGKey(0), cfg)))
+    assert sum(a.size for a in jax.tree_util.tree_leaves(params)) \
+        == 4176338944                  # PERF.md 4: 4.176 B, 8.35 GB
+    scratch = jax.eval_shape(lambda: init_decode_cache(cfg, 1, T + out))
+    compiled = _prefill_fn(cfg).lower(
+        one_chip(params), one_chip(scratch),
+        one_chip(jax.ShapeDtypeStruct((1, T), jnp.int32))).compile()
+    mem = compiled.memory_analysis()
+    assert f"{T},{T}]" not in compiled.as_text()
+    assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
+    assert _latent_held(m, cfg, sv) + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes < CHIP_BYTES
+
+
 # Lowered text of the programs that ops/retention_step.py's PR (39) must
 # not move, hashed at its parent (6635e6d) with `_programs` below: the
 # softmax decode step with the kernel over live blocks and with the
@@ -327,7 +424,9 @@ PARENT_TEXT = {
     "mistral_step_kernel": "73c1243f07b3a6dc",
     "mistral_step_einsum": "583b21132fb4cd5f",
     "mistral_prefill": "209877bf1d709583",
-    "laguna_step": "6bef917b6b6201e5",
+    # (PR 42: the expert layer counts a third number a sparse layer,
+    # `experts.ROUTED`'s `pairs_here`; the five others stand)
+    "laguna_step": "ccd0beee2ae71c14",
     "mistral_train": "cc76927979bf3144",
     "brumby_prefill": "fc1050497b5397b8",
 }
