@@ -928,12 +928,6 @@ def _latent_decode_layer(lp, ck, cv, i, x, pos, cfg: TransformerConfig,
     return _latent_out(lp, x, o[:, None], cfg), ck, cv
 
 
-#: The flash kernel's tiles (block_q, block_k) for a latent kind's prompt,
-#: each clamped to a divisor of the padded length: at the kernel's own
-#: 128 x 128 a prompt of 8192 tokens is 262144 grid steps a layer for 64
-#: heads (PERF.md 6, PR 42: the sweep on the v5e).
-_LATENT_FLASH_BLOCKS = (1024, 1024)
-
 #: Numbers one head group's q, k or v of a prompt may hold
 #: (`_latent_prefill_layer`): 8 of 64 heads at 16384 tokens of width 192,
 #: 32 at 4096, every head up to 2048.
@@ -977,8 +971,8 @@ def _latent_prefill_layer(lp, ck, cv, i, x, cfg: TransformerConfig,
                  * (sp.softmax_scale * wide ** 0.5)).astype(dt)
             padded = lambda a: jnp.pad(
                 a, ((0, 0),) * 3 + ((0, wide - a.shape[-1]),))
-            return _flash_prompt(padded(q), padded(k), padded(v), None,
-                                 _LATENT_FLASH_BLOCKS)[..., :sp.v_dim]
+            return _flash_prompt(padded(q), padded(k), padded(v),
+                                 None)[..., :sp.v_dim]
         s = jnp.einsum("bthk,bshk->bhts", q.astype(jnp.float32),
                        k.astype(jnp.float32)) * sp.softmax_scale
         p = jax.nn.softmax(jnp.where(
@@ -1565,17 +1559,49 @@ def _spec_draft_scan(cfg: TransformerConfig, n: int, sampled: bool):
     return jax.jit(run, donate_argnums=(1,))
 
 
-def _flash_prompt(q, k, v, window, blocks=None):
-    """Causal attention of a whole prompt through the flash kernel
-    (ops/flash_attention.py), the prompt padded to the kernel's tile of
-    128: behind a causal mask what is appended changes nothing before
-    it.  No [H, T, T] scores are kept at any length.  `blocks`: the
-    kernels' tiles (None: the kernel's own default)."""
+#: The most rows of q and of k in one tile of the flash kernel over a
+#: prompt, and the most numbers a tile of q, k or v may hold (rows x head
+#: width): a grid step costs about 0.4 us whatever it holds, so a prompt
+#: wants few of them (PERF.md 6, PR 44: 6144 tokens are 2304 steps a head
+#: at the kernel's own 128 x 128 and 36 here), and Mosaic fits 1024 x
+#: 1024 into the v5e's VMEM up to heads of 512, forward and backward
+#: (the backward passes it at heads of 768, 2048 x 2048 at heads of 128).
+_PROMPT_TILE = 1024
+_PROMPT_TILE_NUMBERS = 1024 * 512
+
+
+def prompt_tiles(T: int, d_head: int):
+    """(padded length, (block_q, block_k)) of the flash kernel over a
+    prompt of T tokens with heads of `d_head`: the FEWEST equal square
+    tiles of at most `_PROMPT_TILE` rows that hold it, each a multiple of
+    the kernel's 128.  Up to `_PROMPT_TILE` tokens that is one tile, the
+    prompt padded to 128 and no further; beyond, n tiles pad by less than
+    128 each, so the padding stays under 128 + T / 8 tokens (1100 tokens
+    are two tiles of 640, not one of 2048 nor five of 256; 2944 = 23 x
+    128 are three of 1024, padded by 128).  A window does not narrow a
+    tile: under Laguna's 512 a tile of 1024 works twice the keys a row
+    needs and is still the fastest (the probe: steps cost more than
+    work).  Heads too wide for a tile of 1024 halve it."""
+    most = _PROMPT_TILE
+    while most > 128 and most * d_head > _PROMPT_TILE_NUMBERS:
+        most //= 2
+    n = -(-T // most)
+    tile = -(-T // (128 * n)) * 128
+    return n * tile, (tile, tile)
+
+
+def _flash_prompt(q, k, v, window):
+    """Causal attention of a whole prompt [B, T, H, D] through the flash
+    kernel (ops/flash_attention.py) at the tiles `prompt_tiles` fits to
+    it, the prompt padded to a whole number of them: behind a causal mask
+    what is appended changes nothing before it.  No [H, T, T] scores are
+    kept at any length.  Every caller's rule (a served pattern's layers,
+    a latent kind's expanded form, models/pattern.py's training)."""
     from ..ops.flash_attention import flash_attention
     T = q.shape[1]
-    pad = -T % 128
-    if pad:
-        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    padded, blocks = prompt_tiles(T, q.shape[-1])
+    if padded != T:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, padded - T), (0, 0), (0, 0)))
                    for a in (q, k, v))
     return flash_attention(q, k, v, causal=True, window=window,
                            blocks=blocks)[:, :T]
@@ -1611,7 +1637,8 @@ def _prefill_layer(lp, ck, cv, i, x, cfg: TransformerConfig,
         ck = _cache_write(ck, i, k, 0)
         cv = _cache_write(cv, i, v, 0)
     if cfg.prompt_attention == "flash" and T0 >= 128:
-        o = _flash_prompt(q, k, v, cfg.attn_window or None)
+        with jax.named_scope("hvd.attn"):  # as training's: a trace names it
+            o = _flash_prompt(q, k, v, cfg.attn_window or None)
     else:
         o = seq_mod.full_attention(q, k, v, causal=True,
                                    window=cfg.attn_window or None)

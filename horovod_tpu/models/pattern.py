@@ -45,14 +45,6 @@ from .transformer import (ConvSpec, TransformerConfig, _mlp_block,
                           _rmsnorm, traced_step)
 
 
-#: The flash kernels' tiles (block_q, block_k) in training, each clamped
-#: to a divisor of T.  At 128 x 128 (the served prompts' default) a
-#: 4 x 8192-token layer is half a million grid steps a kernel and the
-#: four kernels of a recomputed layer took 787 ms a step on the v5e
-#: (PERF.md, PR 37: the sweep).
-FLASH_BLOCKS = (1024, 1024)
-
-
 def refuse_untrained(cfg: TransformerConfig, mesh) -> None:
     """What of a patterned model the training path does not run."""
     def no(what: str):
@@ -106,7 +98,7 @@ def attention_mixer(lp: Dict, h, positions, kcfg: TransformerConfig,
     q = _rotate(q, positions, kcfg).astype(dt)
     k = _rotate(k, positions, kcfg).astype(dt)
     if h.shape[1] >= 128:
-        o = _flash_prompt(q, k, v, None, FLASH_BLOCKS)
+        o = _flash_prompt(q, k, v, None)
     else:
         o = seq_mod.full_attention(q, k, v, causal=True)
     return jnp.einsum("bthk,hkd->btd", o.astype(dt), lp["wo"].astype(dt))

@@ -536,8 +536,13 @@ def test_uniform_and_served_models_keep_their_programs():
     at the parent (247f370) with this very code; the served pattern's two
     at PR 42, whose expert layer counts a third number a sparse layer
     (`experts.ROUTED`: `pairs_here`) and nothing else new at the defaults
-    (no groups, no latent kind; a pass is one pass at these sizes).  A
-    change of JAX moves all three."""
+    (no groups, no latent kind; a pass is one pass at these sizes).  The
+    served pattern's PREFILL was taken anew at PR 44, which is the change
+    it was there to catch: its 200 tokens, padded to 256, ran four steps a
+    head of the flash kernel's own 128 x 128 and run one tile of 256 now
+    (`decode.prompt_tiles`; tests/test_prompt_tiles.py holds the rule,
+    the results at every tile, and the two callers whose tiles did not
+    move).  A change of JAX moves all three."""
     import hashlib
     from horovod_tpu.models import transformer_decode_step
     digest = lambda lowered: hashlib.sha256(
@@ -563,7 +568,7 @@ def test_uniform_and_served_models_keep_their_programs():
         lambda p, c, t: transformer_decode_step(p, c, t, pat)).lower(
             pp, cache, jax.ShapeDtypeStruct((2,), jnp.int32)))
     # and its prefill of 200 tokens, which takes the flash kernel at the
-    # kernel's own tiles (training passes its own, `pattern.FLASH_BLOCKS`)
+    # tiles `decode.prompt_tiles` fits to them, as training does
     from horovod_tpu.models import transformer_prefill
     prefill = digest(jax.jit(
         lambda p, c, t: transformer_prefill(p, c, t, pat)).lower(
@@ -573,4 +578,4 @@ def test_uniform_and_served_models_keep_their_programs():
 
 
 PARENT_DIGESTS = ("cc76927979bf3144", "cffacc3d7f10915b",
-                  "a194293a9374eb98")
+                  "b20b100984ea497a")

@@ -10,6 +10,9 @@ handed this file does (a second file, or a call made while a module is
 imported, would make every other worker fail or skip).
 """
 
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -19,6 +22,13 @@ from horovod_tpu.models import (
     init_decode_cache,
     transformer_init,
 )
+
+
+def _benchmark_json(*path):
+    """A file of `benchmark/` (configs, traffic), parsed."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", *path)) as f:
+        return json.load(f)
 
 # mistral7b_chat_steady's decode view (benchmark/traffic/chat_steady.json,
 # benchmark/configs/mistral-7b-serve.json): 32 rows x 3584 slots, pool of
@@ -186,6 +196,12 @@ def test_retention_step_passes_over_its_state_where_it_lies(one_chip):
 PATTERN_ROWS, PATTERN_SLOTS = 32, 7168
 
 
+def _pattern_cell():
+    from benchmark.runners.pattern_serve import transformer_config
+    return transformer_config(
+        _benchmark_json("configs", "laguna-xs2-serve.json"))
+
+
 def test_patterned_step_copies_neither_cache_nor_experts(one_chip,
                                                          monkeypatch):
     """The served step of a patterned model with routed experts at the
@@ -195,18 +211,11 @@ def test_patterned_step_copies_neither_cache_nor_experts(one_chip,
     transposed or copied out, and far under one matrix of ONE layer's experts: the experts' stack
     goes to the grouped product whole, where a layer's slice of it would
     be copied first (537 MB a matrix, 4.4 GB of temporaries in all)."""
-    import json
-    import os
-
-    from benchmark.runners.pattern_serve import transformer_config
     from horovod_tpu.models import experts
     from horovod_tpu.models.decode import _serve_step_fn
 
     monkeypatch.setattr(experts, "_interpret", lambda: False)
-    with open(os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "benchmark", "configs",
-            "laguna-xs2-serve.json")) as f:
-        cfg = transformer_config(json.load(f))
+    cfg = _pattern_cell()
     args = _step_args(one_chip, cfg, PATTERN_ROWS, PATTERN_SLOTS)
     cache = dict(args[1])
     cache.pop("routed")             # as the server's cache lends it
@@ -226,6 +235,57 @@ def test_patterned_step_copies_neither_cache_nor_experts(one_chip,
     assert cache["k"]["full_attention"].shape[1:] == (32, 8, 7168, 128)
     assert temp < k_slice // 2, (temp, k_slice)
     assert temp < one_matrix // 2, (temp, one_matrix)
+
+
+def test_patterned_cells_longest_prefill_takes_its_tiles(one_chip,
+                                                         monkeypatch):
+    """`laguna_xs2_codegen_steady`'s longest prompt (6144 tokens, 256 to
+    come) prefilled at the configuration's widths: the flash kernel at
+    the tiles `decode.prompt_tiles` fits to it, 1024 x 1024, is inside the
+    v5e's VMEM under 48 heads and under 64 with the window of 512; five such calls under the
+    scope `hvd.attn` beside the twelve grouped products, and no
+    [.., T, T] array.  Its temporaries fit beside what the cell holds
+    (10.3 GB of weights, pool, view and rings: PERF.md 4)."""
+    from horovod_tpu.models import decode, experts
+    from horovod_tpu.ops import flash_attention
+    from horovod_tpu.serve.server import _prefill_fn
+
+    monkeypatch.setattr(experts, "_interpret", lambda: False)
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    cfg = _pattern_cell()
+    T, out = 6144, 256
+    assert decode.prompt_tiles(T, cfg.d_head) == (T, (1024, 1024))
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(cfg.compute_dtype),
+        transformer_init(jax.random.PRNGKey(0), cfg)))
+    scratch = jax.eval_shape(lambda: init_decode_cache(cfg, 1, T + out))
+    compiled = _prefill_fn(cfg).lower(
+        one_chip(params), one_chip(scratch),
+        one_chip(jax.ShapeDtypeStruct((1, T), jnp.int32))).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 5 + 12
+    assert text.count("hvd.attn") >= 5
+    assert f"{T},{T}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+@pytest.mark.parametrize("heads,window", [(48, None), (64, 512)])
+def test_every_tile_a_prompt_can_take_fits_the_vmem(one_chip, monkeypatch,
+                                                    heads, window):
+    """The flash kernel at Laguna's two shapes (8 KV heads of 128,
+    bfloat16) at every tile `decode.prompt_tiles` can pick, 128 to 1024
+    in steps of 128, two tiles a prompt: Mosaic takes each (a second or
+    two a kernel)."""
+    from horovod_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    for tile in range(128, 1025, 128):
+        q, k = (one_chip(jax.ShapeDtypeStruct((1, 2 * tile, h, 128),
+                                              jnp.bfloat16))
+                for h in (heads, 8))
+        jax.jit(lambda q, k, v: flash_attention.flash_attention(
+            q, k, v, causal=True, window=window,
+            blocks=(tile, tile))).lower(q, k, k).compile()
 
 
 def test_pool_write_back_moves_slots_only(one_chip):
@@ -260,9 +320,6 @@ def test_patterned_train_step_fits_beside_its_state(one_chip, monkeypatch):
     the scheduler runs loops late and their zeros early: 7.98 GB of
     temporaries where the step without loops asked for 6.94 (9.06 with
     one more mask in the layer: PERF.md 6, PR 41)."""
-    import json
-    import os
-
     import numpy as np
     import optax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -273,13 +330,8 @@ def test_patterned_train_step_fits_beside_its_state(one_chip, monkeypatch):
 
     monkeypatch.setattr(experts, "_interpret", lambda: False)
     monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "lfm2-8b-a1b-train.json")) as f:
-        m = json.load(f)
-    with open(os.path.join(root, "benchmark", "traffic",
-                           "seq8k_b4.json")) as f:
-        tr = json.load(f)
+    m = _benchmark_json("configs", "lfm2-8b-a1b-train.json")
+    tr = _benchmark_json("traffic", "seq8k_b4.json")
     cfg = transformer_config(m, jnp.bfloat16)
     dev, = one_chip(jax.ShapeDtypeStruct((), jnp.int32)).sharding.device_set
     mesh = Mesh(np.array([dev]), ("dp",))
@@ -318,18 +370,9 @@ def test_patterned_train_step_fits_beside_its_state(one_chip, monkeypatch):
 
 
 def _latent_cell():
-    import json
-    import os
-
     from benchmark.runners.latent_serve import transformer_config
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-    def load(*p):
-        with open(os.path.join(root, "benchmark", *p)) as f:
-            return json.load(f)
-
-    m = load("configs", "gigachat3.1-702b-a36b-serve.json")
-    sv = load("traffic", "longctx_steady.json")["server"]
+    m = _benchmark_json("configs", "gigachat3.1-702b-a36b-serve.json")
+    sv = _benchmark_json("traffic", "longctx_steady.json")["server"]
     return m, transformer_config(m), sv
 
 
