@@ -161,6 +161,8 @@ class DecodeCache:
 
       - ``pages_needed(n_tokens)``, ``can_board(n_tokens)``: what such a
         request takes, and whether it may board now;
+        ``scratch_pages(prompt_tokens, n_tokens)``: how many of those
+        pages its boarding prefills (the `prefill` span says it);
       - ``board(req_id, row, n_tokens, params, prompt, prefill)``: run
         the prefill program and leave the row ready for the next step;
         returns the prompt's last logits, not waited for;
@@ -257,12 +259,33 @@ class PagedKVPool(DecodeCache):
     # -- the server's contract (DecodeCache) -----------------------------
 
     def board(self, req_id, row, n_tokens, params, prompt, prefill):
-        pids = self.alloc(req_id, n_tokens)
-        scratch = init_decode_cache(
-            self.cfg, 1, len(pids) * self.page_tokens, self.quantize)
+        return self.board_pages(req_id, row, n_tokens, params, prompt,
+                                prefill)[0]
+
+    def scratch_pages(self, prompt_tokens: int, n_tokens: int) -> int:
+        """The pages of the scratch cache a boarding prefills: the
+        prompt's, whatever is to follow it."""
+        return min(self.pages_needed(prompt_tokens),
+                   self.pages_needed(n_tokens))
+
+    def board_pages(self, req_id, row, n_tokens, params, prompt, prefill,
+                    cfg=None, kind=None) -> Tuple:
+        """How every cache with pages boards a request.  The scratch
+        cache the prefill fills holds the PROMPT's pages and no slot of
+        the output's, so what a boarding compiles (the scratch, the
+        prefill, the bulk write) is keyed by the prompt's length alone;
+        the budget's other pages are zeroed where they lie.  `cfg` is
+        the model's where this pool keeps one `kind` of its layers.
+        Returns the prompt's last logits and the prefilled scratch."""
+        n = self.scratch_pages(len(prompt), n_tokens)
+        self.alloc(req_id, n_tokens, covered=n)
+        scratch = init_decode_cache(cfg or self.cfg, 1,
+                                    n * self.page_tokens, self.quantize)
         lg, scratch = prefill(params, scratch, jnp.asarray(prompt[None]))
-        self.seat(req_id, row, scratch["k"], scratch["v"])
-        return lg
+        self.seat(req_id, row, *(
+            scratch[leaf] if kind is None else scratch[leaf][kind]
+            for leaf in self.leaves))
+        return lg, scratch
 
     def seat(self, req_id, row, cache_k, cache_v) -> None:
         """A prefilled batch-1 cache into the request's pages, and the
@@ -325,8 +348,12 @@ class PagedKVPool(DecodeCache):
 
     # -- alloc / free ---------------------------------------------------
 
-    def alloc(self, seq_id: int, n_tokens: int) -> List[int]:
-        """Allocate (and zero) enough pages for ``n_tokens`` ring slots.
+    def alloc(self, seq_id: int, n_tokens: int,
+              covered: int = 0) -> List[int]:
+        """Allocate enough pages for ``n_tokens`` ring slots and zero
+        them, but for the first ``covered``: those the caller's bulk
+        write covers whole (`board_pages`: the prompt's, the last one's
+        tail with a fresh scratch's zeros).
 
         Zeroing on alloc, not on free, keeps eviction O(1) and makes a
         freshly gathered view bitwise-equal to a fresh contiguous
@@ -341,8 +368,11 @@ class PagedKVPool(DecodeCache):
                 f"need {need} pages for {n_tokens} tokens, only "
                 f"{len(self._free)}/{self.total_pages} free")
         pids = [self._free.pop() for _ in range(need)]
-        self.k, self.v = _zero_pages_jit((self.k, self.v),
-                                         jnp.asarray(pids, jnp.int32))
+        if fresh := pids[covered:]:
+            # (a numpy list: `jnp.asarray` of a Python one compiles a
+            # conversion for every list length)
+            self.k, self.v = _zero_pages_jit(
+                (self.k, self.v), np.asarray(fresh, np.int32))
         self.pages[seq_id] = pids
         if self.on_event is not None:
             self.on_event("alloc", seq_id, len(pids), len(self._free))
@@ -424,19 +454,22 @@ class PagedKVPool(DecodeCache):
                 pids, [s % pt for s in slots], rows, slots)))
 
     def scatter_pages(self, seq_id: int, cache_k, cache_v) -> None:
-        """Install a freshly prefilled contiguous cache (batch 1, ring
-        length EXACTLY this sequence's page budget) into its pages —
-        the admit-time bulk write."""
+        """Install a freshly prefilled contiguous cache (batch 1, a ring
+        of a whole number of pages, the sequence's page budget at most)
+        into the FIRST pages of the sequence — the admit-time bulk
+        write.  Boarding hands it the prompt's pages (`board_pages`)."""
         pids = self.pages[seq_id]
         pt = self.page_tokens
         ring = cache_slots(cache_k)
-        if ring != len(pids) * pt:
+        n, tail = divmod(ring, pt)
+        if tail or n > len(pids):
             raise InvalidRequestError(
-                f"prefill cache ring {ring} != page budget "
+                f"prefill cache ring {ring} is not a whole number of "
+                f"pages of {pt} tokens within the page budget "
                 f"{len(pids) * pt} of sequence {seq_id}")
         self.k, self.v = _scatter_pages_jit(
             (self.k, self.v), (cache_k, cache_v),
-            jnp.asarray(pids, jnp.int32), len(pids))
+            np.asarray(pids[:n], np.int32), n)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -482,6 +515,9 @@ class StateSlots(DecodeCache):
         self.kernel = retention_step.takes(self.view[0])
 
     def pages_needed(self, n_tokens: int) -> int:
+        return 0
+
+    def scratch_pages(self, prompt_tokens: int, n_tokens: int) -> int:
         return 0
 
     def can_board(self, n_tokens: int) -> bool:
@@ -571,18 +607,14 @@ class WindowedKVPool(DecodeCache):
         self.page_bytes = self.pool.k.nbytes + self.pool.v.nbytes
         # what only the pages answer
         for name in ("total_pages", "page_tokens", "pages_needed",
-                     "pages_free", "can_board", "utilization", "release",
-                     "refresh", "write_through"):
+                     "scratch_pages", "pages_free", "can_board",
+                     "utilization", "release", "refresh", "write_through"):
             setattr(self, name, getattr(self.pool, name))
 
     def board(self, req_id, row, n_tokens, params, prompt, prefill):
-        pool = self.pool
-        pids = pool.alloc(req_id, n_tokens)
-        scratch = init_decode_cache(self.cfg, 1,
-                                    len(pids) * pool.page_tokens)
-        lg, scratch = prefill(params, scratch, jnp.asarray(prompt[None]))
-        pool.seat(req_id, row, *(scratch[n][self.paged]
-                                 for n in self.leaves))
+        lg, scratch = self.pool.board_pages(
+            req_id, row, n_tokens, params, prompt, prefill,
+            cfg=self.cfg, kind=self.paged)
         self.rings = tuple(
             dict(zip(self.ringed, _state_install(
                 tuple(ring[t] for t in self.ringed),
@@ -658,13 +690,8 @@ class KindKVPool(PagedKVPool):
         self.page_bytes = self.k.nbytes + self.v.nbytes
 
     def board(self, req_id, row, n_tokens, params, prompt, prefill):
-        pids = self.alloc(req_id, n_tokens)
-        scratch = init_decode_cache(self.cfg, 1,
-                                    len(pids) * self.page_tokens)
-        lg, scratch = prefill(params, scratch, jnp.asarray(prompt[None]))
-        self.seat(req_id, row, *(scratch[n][self.kind]
-                                 for n in self.leaves))
-        return lg
+        return self.board_pages(req_id, row, n_tokens, params, prompt,
+                                prefill, kind=self.kind)[0]
 
     def lend(self, pos) -> Dict:
         cache = super().lend(pos)

@@ -12,7 +12,8 @@ Step anatomy (``step()``; in brackets what a paged cache does):
 
   1. admit   — queued requests ``board`` free rows: prefill-on-admit
                runs ``transformer_prefill`` into a scratch cache [of the
-               request's page budget, then bulk-written into its pages].
+               prompt's pages, bulk-written into the first of the
+               request's pages; the rest of its budget is zeroed].
   2. emit    — each active row emits its pending id (greedy serving):
                the argmax of its logits, picked INSIDE the decode
                program that computed them (a row just admitted: of its
@@ -336,6 +337,7 @@ class InferenceServer:
             with span("prefill", "serve",
                       {"req": rid, "prompt_tokens": T0, "row": seq.row,
                        "pages": self.pool.pages_needed(budget),
+                       "scratch_pages": self.pool.scratch_pages(T0, budget),
                        "queue_wait_us": round(queue_wait * 1e6, 1)},
                       tid=f"req/{rid}"):
                 lg = [c.board(rid, seq.row, budget, p, seq.req.prompt,

@@ -426,7 +426,7 @@ def test_state_install_span_and_counters(model, tmp_path):
         assert s[3]["bytes"] == srv.pool.row_bytes and "row" in s[3]
         assert any(a[1] <= s[1] and s[2] <= a[2] for a in admits)
         assert any(p[1] <= s[1] and s[2] <= p[2] for p in prefills)
-    assert all(p[3]["pages"] == 0 for p in prefills)
+    assert all(p[3]["pages"] == p[3]["scratch_pages"] == 0 for p in prefills)
 
 
 # -- what a state cannot do yet raises, by name ----------------------------

@@ -232,9 +232,10 @@ def test_one_prefill_span_per_admitted_request(traced):
     assert sorted(p[3]["req"] for p in prefills) == sorted(traced["prompts"])
     for p in prefills:
         assert set(p[3]) == {"req", "prompt_tokens", "row", "pages",
-                             "queue_wait_us"}
+                             "scratch_pages", "queue_wait_us"}
         assert p[3]["prompt_tokens"] == 4
         assert p[3]["pages"] == -(-(4 + OUTPUTS[p[3]["req"]]) // 4)
+        assert p[3]["scratch_pages"] == 1       # the prompt's, 4 tokens
         assert p[3]["queue_wait_us"] >= 0
         # a child of exactly one step's admit
         assert len([a for a in traced["spans"] if a[0] == "hvd.serve.admit"
@@ -253,7 +254,7 @@ def test_timeline_prefill_event_keeps_its_shape(traced):
         rid = e["args"]["req"]
         assert (e["ph"], e["cat"], e["tid"]) == ("X", "serve", f"req/{rid}")
         assert {"req", "prompt_tokens", "row"} <= set(e["args"])
-        assert {"pages", "queue_wait_us"} <= set(e["args"])
+        assert {"pages", "scratch_pages", "queue_wait_us"} <= set(e["args"])
     # the step's phases are there too, on the category's own lane
     names = {e["name"] for e in traced["events"]
              if e.get("cat") == "serve" and e.get("tid") == "serve"}
@@ -410,8 +411,9 @@ def test_patterned_model_counts_its_routing_in_the_one_sync(tmp_path):
                if s[0] == "hvd.serve.launch")
     for p in (s for s in spans if s[0] == "hvd.serve.prefill"):
         assert set(p[3]) == {"req", "prompt_tokens", "row", "pages",
-                             "queue_wait_us"}
+                             "scratch_pages", "queue_wait_us"}
         assert p[3]["pages"] == -(-(4 + OUTPUTS[p[3]["req"]]) // 4)
+        assert p[3]["scratch_pages"] == 1
 
 
 def test_uniform_model_counts_no_routing(traced):

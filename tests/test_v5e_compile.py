@@ -239,8 +239,9 @@ def test_patterned_step_copies_neither_cache_nor_experts(one_chip,
 
 def test_patterned_cells_longest_prefill_takes_its_tiles(one_chip,
                                                          monkeypatch):
-    """`laguna_xs2_codegen_steady`'s longest prompt (6144 tokens, 256 to
-    come) prefilled at the configuration's widths: the flash kernel at
+    """`laguna_xs2_codegen_steady`'s longest prompt (6144 tokens, into
+    the scratch cache of its own pages that boarding builds whatever is
+    to come) prefilled at the configuration's widths: the flash kernel at
     the tiles `decode.prompt_tiles` fits to it, 1024 x 1024, is inside the
     v5e's VMEM under 48 heads and under 64 with the window of 512; five such calls under the
     scope `hvd.attn` beside the twelve grouped products, and no
@@ -253,12 +254,12 @@ def test_patterned_cells_longest_prefill_takes_its_tiles(one_chip,
     monkeypatch.setattr(experts, "_interpret", lambda: False)
     monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
     cfg = _pattern_cell()
-    T, out = 6144, 256
+    T = 6144                # (the scratch cache is the prompt's pages)
     assert decode.prompt_tiles(T, cfg.d_head) == (T, (1024, 1024))
     params = jax.eval_shape(lambda: jax.tree_util.tree_map(
         lambda a: a.astype(cfg.compute_dtype),
         transformer_init(jax.random.PRNGKey(0), cfg)))
-    scratch = jax.eval_shape(lambda: init_decode_cache(cfg, 1, T + out))
+    scratch = jax.eval_shape(lambda: init_decode_cache(cfg, 1, T))
     compiled = _prefill_fn(cfg).lower(
         one_chip(params), one_chip(scratch),
         one_chip(jax.ShapeDtypeStruct((1, T), jnp.int32))).compile()
@@ -440,13 +441,13 @@ def test_latent_cells_longest_prefill_fits_beside_the_cache(one_chip,
     monkeypatch.setattr(experts, "_interpret", lambda: False)
     monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
     m, cfg, sv = _latent_cell()
-    T, out = 16384, 512
+    T = 16384               # (the scratch cache is the prompt's pages)
     params = jax.eval_shape(lambda: jax.tree_util.tree_map(
         lambda a: a.astype(cfg.compute_dtype),
         transformer_init(jax.random.PRNGKey(0), cfg)))
     assert sum(a.size for a in jax.tree_util.tree_leaves(params)) \
         == 4176338944                  # PERF.md 4: 4.176 B, 8.35 GB
-    scratch = jax.eval_shape(lambda: init_decode_cache(cfg, 1, T + out))
+    scratch = jax.eval_shape(lambda: init_decode_cache(cfg, 1, T))
     compiled = _prefill_fn(cfg).lower(
         one_chip(params), one_chip(scratch),
         one_chip(jax.ShapeDtypeStruct((1, T), jnp.int32))).compile()
