@@ -1,6 +1,6 @@
 """Parameters, bytes and operations of a patterned decoder with routed
-experts, from shapes alone: the yardstick of `moe_step_roofline.tpot`
-and of `expert_gmm_roofline.tpot`.
+experts, from shapes alone: the yardstick of
+`moe_step_roofline_traced.tpot` and of `expert_gmm_roofline_traced.tpot`.
 
 A decode step must read every weight OUTSIDE the routed experts once, the
 three matrices of each DISTINCT expert that some token of the step chose

@@ -1,5 +1,5 @@
 """Bytes and operations a power-retention LM needs, from shapes alone:
-the yardstick of `state_step_roofline.tpot`.
+the yardstick of `state_step_roofline_traced.tpot`.
 
 A retention layer's cache is, per row and kv head, a state of D x d_head
 numbers and a normaliser of D, D = d_head (d_head + 1) / 2: the distinct

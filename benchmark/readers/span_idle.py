@@ -12,7 +12,8 @@ The profiler aligns the host's and the device's clocks anew in every
 session, to about a millisecond (chip runs, PERF.md section 5).  A phase
 that lies wholly inside an idle gap does not feel that; two phases that
 border the same busy stretch (`launch` before the decode program,
-`fetch` after it) trade that much idle between them: read their sum."""
+`fetch` after it) trade that much idle between them: read their union
+(`span_idle_union`; no metric lists either of the two alone)."""
 from benchmark.reduce import program_spans, xplane
 
 

@@ -8,11 +8,10 @@ inside the traced `[lo, hi]` are taken, each with the `observe` of its
 own `dstep` (so a server that keeps a step in flight pairs as one that
 does not), and their mean work is set against the mean device time of
 the decode program's runs inside the same `[lo, hi]` (the module of
-prefix `match` run most often: `module_time.picked_runs`).  The readers
-this stands beside (`decode_roofline`, `moe_roofline`,
-`kernel_roofline`, `state_roofline`) divide counters summed over the
-whole 40 s window by that time of its last 5 s, and read too high when
-the traced batch is lighter than the window's mean.
+prefix `match` run most often: `module_time.picked_runs`).  These are
+the only serving rooflines: a counter summed over the whole 40 s window
+over that time of its last 5 s reads too high when the traced batch is
+lighter than the window's mean (PERF.md 6, PR 48).
 
 `kind`, with the count it takes from `lib/counts*.py`:
   "decode"  weights once and the live tokens' cache (`decoder_lm`);
@@ -64,8 +63,9 @@ def mean_work(steps, args):
 def op_time_in_runs(t, match, pattern):
     """Device seconds of the operations whose name matches `pattern`
     that started inside a run, within [lo, hi], of the program of prefix
-    `match` run most often there: `kernel_roofline`'s walk, which needs
-    the runs' intervals where `module_time.picked_runs` gives lengths."""
+    `match` run most often there: the yardstick's one interval walk,
+    which needs the runs' intervals where `module_time.picked_runs`
+    gives lengths."""
     runs = {}
     for name, s, e in t.chips[0].modules:
         if name.startswith(match) and s >= t.lo and e <= t.hi:

@@ -98,7 +98,6 @@ class Runner(lm_serve.Runner):
         self.by_id = {}
         self.finished = []
         self.ended = set()
-        self.live_tokens_sum = 0.0
         self.pairs_sum = 0
         self.prefill_tokens = 0
         self.ran_out = False

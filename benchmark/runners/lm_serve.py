@@ -72,7 +72,6 @@ class Runner:
         self.by_id: Dict[int, Tracked] = {}
         self.finished: List[Tracked] = []
         self.ended = set()               # plan indices finished or refused
-        self.live_tokens_sum = 0.0
         self.prefill_tokens = 0
         self.ran_out = False
         # Due requests wait here, first in first out; the server's own
@@ -118,7 +117,6 @@ class Runner:
         with self.ctx.span("server.step"):
             done = srv.step()
         now = clock()
-        live = 0
         for seq in list(srv.sched.active.values()) + done:
             t = self.by_id[seq.req.req_id]
             if t.seq is None:
@@ -127,9 +125,6 @@ class Runner:
             new = len(seq.generated) - len(t.token_times)
             if new:
                 t.token_times.extend([now] * new)
-        for seq in srv.sched.active.values():
-            live += seq.pos
-        self.live_tokens_sum += live
         for seq in done:
             t = self.by_id[seq.req.req_id]
             self.finished.append(t)
@@ -187,7 +182,7 @@ class Runner:
 
         tokens0 = sum(len(t.token_times) for t in self.by_id.values())
         prefill0, steps0 = self.prefill_tokens, srv.device_steps
-        occ0, live0 = srv.occupancy_sum, self.live_tokens_sum
+        occ0 = srv.occupancy_sum
         finished0 = len(self.finished)
         nxt, measured_idx, closed, counters = 0, set(), None, {}
         timeline = []
@@ -201,7 +196,6 @@ class Runner:
             counters.update(
                 device_steps=srv.device_steps - steps0,
                 occupancy_sum=srv.occupancy_sum - occ0,
-                live_tokens_sum=self.live_tokens_sum - live0,
                 output_tokens=tokens1 - tokens0,
                 prefill_tokens=self.prefill_tokens - prefill0,
                 finished_requests=len(self.finished) - finished0)

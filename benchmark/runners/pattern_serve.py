@@ -8,8 +8,8 @@ The driving of the server, the window, the sampling of finished requests
 and the timeline are `lm_serve.Runner`'s, unchanged.  What differs is
 what is built (the configuration's pattern, the weight tree stacked by
 kind of layer), three sums a step that the window's counters do not
-have (what the expert layers counted, and the tokens live in each kind
-of cache), cut at the window's `device_steps`, and the plain reference
+have (what the expert layers counted), cut at the window's
+`device_steps`, and the plain reference
 the served tokens are held against, by the MEAN of the gap that the
 other serving runners take the widest of: routing is discrete
 (`reference/pattern_check.py` has why).
@@ -103,8 +103,6 @@ class Runner(lm_serve.Runner):
         self.by_id = {}
         self.finished = []
         self.ended = set()
-        self.live_tokens_sum = 0.0
-        self.ring_tokens_sum = 0.0
         self.prefill_tokens = 0
         self.ran_out = False
         self.pending = collections.deque()
@@ -114,16 +112,10 @@ class Runner(lm_serve.Runner):
         self._ramp()
 
     def _sums(self) -> tuple:
-        srv = self.server
-        return tuple(getattr(srv, n, 0) for n in SERVER_SUMS) + (
-            self.ring_tokens_sum,)
+        return tuple(getattr(self.server, n, 0) for n in SERVER_SUMS)
 
     def _step(self, clock) -> None:
         super()._step(clock)
-        window = self.m["sliding_window"]
-        self.ring_tokens_sum += sum(
-            min(seq.pos, window)
-            for seq in self.server.sched.active.values())
         self.sums_at[self.server.device_steps] = self._sums()
 
     def window(self, seconds: float):
@@ -133,8 +125,7 @@ class Runner(lm_serve.Runner):
         # the load stays on after the close: cut the sums where the
         # window's own counters were cut
         end = self.sums_at[steps0 + result.counters["device_steps"]]
-        for name, a, b in zip(SERVER_SUMS + ("ring_tokens_sum",),
-                              self.sums_at[steps0], end):
+        for name, a, b in zip(SERVER_SUMS, self.sums_at[steps0], end):
             result.counters[name] = b - a
         if result.counters["moe_layer_steps"]:
             c = result.counters

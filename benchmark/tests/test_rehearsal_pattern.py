@@ -6,15 +6,17 @@ gives counts and correctness, never a time.
 
 tiny.py knows the families it was written with, so this file cuts the
 new family itself, in the same temporary root and as new files only."""
+import glob
 import io
 import json
 import os
 import time
 
-import jax.numpy as jnp
+import jax
 import pytest
 
 from benchmark.lib import harness
+from benchmark.reduce import program_spans
 from benchmark.tests import tiny
 from benchmark.tests.test_rehearsal import build
 
@@ -101,9 +103,27 @@ def test_last_line(root, capsys):
         capsys.readouterr().out
 
 
-def test_counters_and_both_kinds_of_cache(root):
+def _window_and_its_launches(r, tmp_path, seconds):
+    """The window run inside a profiler session -> its result and the
+    `hvd.serve.launch` spans of the window's own device steps, read as a
+    traced run reads them (`reduce/program_spans.py`)."""
+    steps0 = r.server.device_steps
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        res = r.window(seconds)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    mine = range(steps0, steps0 + res.counters["device_steps"])
+    return res, [s for s in program_spans.read_file(path)
+                 if s.name == "hvd.serve.launch"
+                 and s.stats.get("dstep") in mine]
+
+
+def test_counters_and_both_kinds_of_cache(root, tmp_path):
     r = build(root, CELL, 11)
-    res = r.window(0.5)
+    res, launches = _window_and_its_launches(r, tmp_path, 0.5)
     c, srv = res.counters, r.server
     sparse = r.m["mlp_layer_types"][:r.m["num_hidden_layers"]].count(
         "sparse")
@@ -116,10 +136,15 @@ def test_counters_and_both_kinds_of_cache(root):
     assert c["moe_layer_steps"] <= c["expert_load_max_sum"] \
         <= srv.max_batch * c["moe_layer_steps"]
     # every prompt is longer than the window: a ring holds `window`
-    # tokens of each active row, the pages all of them
-    assert c["ring_tokens_sum"] == pytest.approx(
-        r.m["sliding_window"] * c["occupancy_sum"] * srv.max_batch)
-    assert c["live_tokens_sum"] > c["ring_tokens_sum"]
+    # tokens of each stepped row, the pages all of them (the step's own
+    # spans say it: what the traced rooflines read)
+    assert len(launches) == c["device_steps"]
+    assert sum(s.stats["rows"] for s in launches) == pytest.approx(
+        c["occupancy_sum"] * srv.max_batch)
+    for s in launches:
+        assert s.stats["ring_tokens"] == \
+            r.m["sliding_window"] * s.stats["rows"]
+        assert s.stats["live_tokens"] > s.stats["ring_tokens"]
     pool = srv.pool
     assert pool.ring_bytes > 0 and pool.page_bytes > 0
     done = [t for t in r.finished if t.plan.index >= 0 and not t.failed]
@@ -143,9 +168,9 @@ def _broken(monkeypatch, what):
     if what == "routed scale dropped":
         real = experts.route
         monkeypatch.setattr(
-            experts, "route", lambda router, h, cfg: (
+            experts, "route", lambda router, h, cfg, *a: (
                 lambda idx, w: (idx, w / cfg.routed_scale))(
-                    *real(router, h, cfg)))
+                    *real(router, h, cfg, *a)))
     elif what == "shared expert left out":
         real = experts.expert_layer
         monkeypatch.setattr(

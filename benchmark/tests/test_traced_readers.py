@@ -2,21 +2,23 @@
 traced window (`traced_roofline`, `span_idle_union`), on hand-made traces
 with the benchmark's own configurations and peaks.
 
-The trace of a 40 s window whose last 5 s were recorded: the steps of
-those 5 s did HALF the work of the window's mean step, and the decode
-program ran each in the time that work needs at a known share of the
-roofline.  The readers that divide the window's counters by the traced
-time (`decode_roofline`, `moe_roofline`, `kernel_roofline`,
-`state_roofline`: PERF.md 7 (ak)) then read over 100%, and the new one
-the share that was built in."""
+The trace of a 40 s window whose last 5 s were recorded: the decode
+program ran each step of those 5 s in the time its work needs at a known
+share of the roofline, and the reader reads the share that was built in,
+whatever the rest of the window did: it takes no counter of the window
+(a window's sum over the traced time reads over 100% whenever the traced
+steps are lighter than the window's mean: PERF.md 6, PR 48).  Last, the
+manifest's guard: every serving roofline of BENCHMARK.json is read this
+way."""
+import importlib
 import json
 import os
 
 import pytest
 
-from benchmark.readers import (ReadContext, decode_roofline, kernel_roofline,
-                               moe_roofline, span_idle, span_idle_union,
-                               state_roofline, traced_roofline)
+from benchmark.lib.harness import cell_metrics
+from benchmark.readers import (ReadContext, span_idle, span_idle_union,
+                               traced_roofline)
 from benchmark.reduce import program_spans, xplane
 from benchmark.reduce.program_spans import Span
 from benchmark.tests import test_program_spans as fixture
@@ -25,22 +27,18 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 STEP, PREFILL = "jit__lambda(111)", "jit__lambda(222)"
 GMM = r"gmm(\.\d+)?"
-LO, HI, PERIOD, STEPS_IN_WINDOW = 35.0, 40.0, 0.05, 800
+LO, HI, PERIOD = 35.0, 40.0, 0.05
 SHARE = 85.0                       # of the roofline, built into the trace
 
-# kind -> configuration, traffic, the old reader and its parameters, and
-# the TRACED steps' work (the window's mean step did twice that;
-# `moe_layers` is for the old readers' `moe_layer_steps`, no span's)
+# kind -> configuration, traffic and the TRACED steps' work
+CODEGEN_WORK = dict(rows=6, live_tokens=15000, ring_tokens=3000,
+                    experts_hit=160)
 CASES = {
-    "decode": ("mistral-7b-serve", "chat_steady", decode_roofline, {},
+    "decode": ("mistral-7b-serve", "chat_steady",
                dict(rows=6, live_tokens=60000)),
-    "moe": ("laguna-xs2-serve", "codegen_steady", moe_roofline, {},
-            dict(rows=6, live_tokens=15000, ring_tokens=3000,
-                 experts_hit=160, moe_layers=4)),
-    "gmm": ("laguna-xs2-serve", "codegen_steady", kernel_roofline,
-            dict(op=GMM), dict(rows=6, live_tokens=15000, ring_tokens=3000,
-                               experts_hit=160, moe_layers=4)),
-    "state": ("brumby-14b-serve", "longdoc_steady", state_roofline, {},
+    "moe": ("laguna-xs2-serve", "codegen_steady", CODEGEN_WORK),
+    "gmm": ("laguna-xs2-serve", "codegen_steady", CODEGEN_WORK),
+    "state": ("brumby-14b-serve", "longdoc_steady",
               dict(rows=8, live_tokens=40000)),
 }
 
@@ -58,22 +56,14 @@ def _case(kind, with_args=True, with_runs=True, with_ops=True, lag=0):
     """-> (ReadContext, spans).  `lag`: the `observe` of device step n
     lies in iteration n + lag (a server that keeps `lag` steps in
     flight)."""
-    config, traffic, _, _, work = CASES[kind]
+    config, traffic, work = CASES[kind]
     config, traffic = (_json("configs", config + ".json"),
                        _json("traffic", traffic + ".json"))
     peaks = _json("peaks.json")["TPU v5 lite"]
     B = traffic["server"]["max_batch"]
-    twice = STEPS_IN_WINDOW * 2
     ctx = ReadContext(
         cell={"name": "a_cell"}, config=config, traffic=traffic, peaks=peaks,
-        chips=1, samples={}, trace=None, memory_peak_bytes=0,
-        counters={"device_steps": STEPS_IN_WINDOW,
-                  "occupancy_sum": twice * work["rows"] / B,
-                  "live_tokens_sum": twice * work["live_tokens"],
-                  "ring_tokens_sum": twice * work.get("ring_tokens", 0),
-                  "experts_hit_sum": twice * work.get("experts_hit", 0),
-                  "moe_layer_steps":
-                      STEPS_IN_WINDOW * work.get("moe_layers", 0)})
+        chips=1, samples={}, trace=None, memory_peak_bytes=0, counters={})
     busy = _least_s(kind, ctx, work) / (SHARE / 100)   # the unit timed
     spans, modules, ops = [], [], []
     n = int((HI - LO) / PERIOD)
@@ -119,11 +109,8 @@ def _read_new(monkeypatch, kind, ctx, spans):
 
 
 @pytest.mark.parametrize("kind", sorted(CASES))
-def test_window_counted_work_reads_over_100_and_traced_work_true(
-        monkeypatch, kind):
+def test_traced_work_over_traced_time(monkeypatch, kind):
     ctx, spans = _case(kind)
-    _, _, old, params, _ = CASES[kind]
-    assert old.read(ctx, "jit__lambda(", **params) > 100.0
     assert _read_new(monkeypatch, kind, ctx, spans) == pytest.approx(SHARE)
 
 
@@ -161,7 +148,7 @@ def test_a_steps_routing_is_paired_by_dstep(monkeypatch, kind):
     iteration later: the `observe` that says `dstep` n is still step n's."""
     ctx, spans = _case(kind, lag=1)
     steps = traced_roofline.traced_steps(spans, LO, HI)
-    assert all(st["experts_hit"] == CASES[kind][4]["experts_hit"]
+    assert all(st["experts_hit"] == CASES[kind][2]["experts_hit"]
                for d, st in steps.items() if d != max(steps))
     assert "experts_hit" not in steps[max(steps)]   # its sync came later
     assert _read_new(monkeypatch, kind, ctx, spans) == pytest.approx(SHARE)
@@ -220,3 +207,49 @@ def test_launch_and_fetch_trade_idle_and_their_union_does_not(monkeypatch):
                 ctx, ["hvd.serve.launch", "hvd.serve.fetch"])))
     assert readings[0] == pytest.approx((20.0, 20.0, 40.0))
     assert readings[1] == pytest.approx((15.0, 25.0, 40.0))
+
+
+# -- the manifest: what BENCHMARK.json lists, the files hold ----------------
+
+MANIFEST = _json(os.pardir, "BENCHMARK.json")
+SERVING = ("tpot_p90_ms", "serve_tokens_per_s")
+TRACED = ("traced_roofline", "traced_latent")
+
+
+def _serving_roofline(entry):
+    return "roofline" in entry["name"] and entry["moves"] in SERVING
+
+
+@pytest.mark.parametrize("entry", MANIFEST["per_layer"],
+                         ids=lambda e: e["name"])
+def test_a_listed_metric_has_its_file_and_its_reader(entry):
+    """A serving roofline reads work and time in one traced window, or a
+    server that keeps a step in flight cannot be measured by it."""
+    spec = _json("metrics", entry["name"] + ".json")
+    reader = importlib.import_module("benchmark.readers." + spec["reader"])
+    assert callable(reader.read)
+    assert set(entry.get("workloads", ())) <= {
+        w["name"] for w in MANIFEST["workloads"]}
+    if _serving_roofline(entry):
+        assert spec["reader"] in TRACED, (entry["name"], spec["reader"])
+
+
+def test_no_metric_file_without_its_entry():
+    files = {f[:-len(".json")]
+             for f in os.listdir(os.path.join(BENCH, "metrics"))}
+    assert files == {m["name"] for m in MANIFEST["per_layer"]}
+
+
+def _judged_by(cell):
+    """The serving end-to-end metrics the cell reports."""
+    return {m["name"] for m in cell_metrics(MANIFEST, cell, "end_to_end")
+            } & set(SERVING)
+
+
+@pytest.mark.parametrize(
+    "cell", [c for c in MANIFEST["workloads"] if _judged_by(c)],
+    ids=lambda c: c["name"])
+def test_a_serving_cell_keeps_a_traced_roofline_on_its_metric(cell):
+    """... so that a later claim in the cell stays bounded by one."""
+    assert any(_serving_roofline(m) and m["moves"] in _judged_by(cell)
+               for m in cell_metrics(MANIFEST, cell, "per_layer"))
