@@ -1498,15 +1498,29 @@ def _spec_step_fn(cfg: TransformerConfig):
 
 def _serve_step_fn(cfg: TransformerConfig):
     """The server's step: `_spec_step_fn(cfg)`'s program with the greedy
-    pick in it, returning (logits [B, V] f32, ids [B] int32, cache), so a
-    server step syncs on the ids and leaves the logits on the device.
-    ONE program, and a lambda like its siblings: a pick dispatched after
-    the step would run as often as the step, and the trace readers take
-    the `jit__lambda(` run most often for the decode step.  It is kept by
-    the step it is built over, not by `cfg`: whoever clears
-    `_spec_step_fn` to have the step traced again gets this one traced
-    again too."""
+    pick in it, `(params, cache, tokens, prev)` -> (logits [B, V] f32,
+    ids [B] int32, cache), so a server step syncs on the ids and leaves
+    the logits on the device.  `prev` is the `ids` this program returned
+    the step before, STILL ON THE DEVICE: a row whose `tokens` entry is
+    negative is fed its `prev`, so the host dispatches a step before the
+    ids of the step before it have reached it (serve/server.py).  A
+    caller that has synced every id leaves `prev` out.
+    ONE program, and a lambda like its siblings: a pick, or a merge of
+    the two feeds, dispatched beside the step would run as often as the
+    step, and the trace readers take the `jit__lambda(` run most often
+    for the decode step.  It is kept by the step it is built over, not by
+    `cfg`: whoever clears `_spec_step_fn` to have the step traced again
+    gets this one traced again too."""
     return _with_greedy_ids(_spec_step_fn(cfg))
+
+
+def serve_ids_len(cfg: TransformerConfig, rows: int) -> int:
+    """How many int32 `_serve_step_fn(cfg)`'s `ids` hold over `rows`
+    rows: the ids, and behind them what a patterned model's expert
+    layers counted (`experts.ROUTED` a sparse layer)."""
+    if not cfg.patterned:
+        return rows
+    return rows + experts_mod.sparse_layers(cfg) * len(experts_mod.ROUTED)
 
 
 @functools.lru_cache(maxsize=None)
@@ -1521,8 +1535,14 @@ def _with_greedy_ids(step):
             ids = jnp.concatenate([ids, cache["routed"].reshape(-1)])
         return logits, ids, cache
 
-    return jax.jit(lambda p, c, t: picked(*step(p, c, t)),
-                   donate_argnums=(1,))
+    def fed(tokens, prev):
+        if prev is None:
+            return tokens
+        return jnp.where(tokens < 0, prev[:tokens.shape[0]], tokens)
+
+    return jax.jit(
+        lambda p, c, t, prev=None: picked(*step(p, c, fed(t, prev))),
+        donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)

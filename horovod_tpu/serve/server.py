@@ -8,29 +8,52 @@ closer, the draft's like the target's.  The decode kernels run UNCHANGED — the
 vector-``pos`` path in ``models/decode.py``, because continuously
 batched rows sit at different depths of the same step.
 
-Step anatomy (``step()``; in brackets what a paged cache does):
+Step anatomy (``step()``; in brackets what a paged cache does).  ONE
+decode step is kept in flight: iteration i dispatches step i BEFORE it
+waits for step i-1's ids, so the device has step i queued while the
+host does the rest of its work:
 
   1. admit   — queued requests ``board`` free rows: prefill-on-admit
                runs ``transformer_prefill`` into a scratch cache [of the
                prompt's pages, bulk-written into the first of the
                request's pages; the rest of its budget is zeroed].
-  2. emit    — each active row emits its pending id (greedy serving):
-               the argmax of its logits, picked INSIDE the decode
-               program that computed them (a row just admitted: of its
-               prefill's row of logits, on the host); finished rows
-               (max_new / EOS) evict and ``release`` their row BEFORE
-               any device work, so the last token costs no decode step.
-  3. refresh — [only if membership changed: rebuild the pooled view.]
-  4. decode  — one vector-pos ``transformer_decode_step`` (plain), or
-               one speculative round (draft chain + chunked verify)
-               when the SLO controller has flipped speculation on.
-               The programs CONSUME the view (donated, updated in
-               place): the cache lends it and takes back the result.
-               The step's one sync fetches the ``[max_batch]`` ids; the
+  2. sample  — a row whose pending id the HOST holds (a row just
+               admitted: the argmax of its prefill's row of logits;
+               after ``last_logits`` was assigned; after a speculative
+               round) emits it and is fed it; a row whose pending id is
+               the step in flight's is fed ``-1``, "the device's": the
+               decode program picks its token from the ids the step
+               before left on the device.  Finished rows evict and
+               ``release`` their row BEFORE any device work; a row whose
+               pending id is its ``max_new_tokens``-th is left out of
+               the step, so the last token costs no decode step.
+  3. launch  — [``put``: refresh the pooled view if membership changed,]
+               put the step's two ``[max_batch]`` integer arrays on the
+               device, dispatch one vector-pos
+               ``transformer_decode_step`` with the greedy pick in it
+               (``_serve_step_fn``), which CONSUMES the view (donated,
+               updated in place): the cache lends it and takes back the
+               result.  ``ahead`` says whether the step before was still
+               in flight (``steps_ahead`` counts those).
+               [``write_through``: copy each active row's written ring
+               slot(s) into its pages; the pool stays the source of
+               truth.]
+  4. fetch   — the iteration's one sync: the ``[max_batch]`` ids of the
+               step BEFORE the one just dispatched.  They are emitted
+               here, rows they end are evicted, and a row they end by
+               EOS is dropped from the step just dispatched, which
+               stepped it once too often: that id is thrown away
+               (``rows_dropped``), nothing of it is left behind.  The
                ``[max_batch, vocab]`` logits stay on the device and
                come to the host only for who reads ``last_logits``.
-  5. write through — [copy each active row's written ring slot(s)
-               into its pages; the pool stays the source of truth.]
+  5. observe — counts, latency, gauges, the flight recorder.
+
+Whatever is not a plain step after a plain step first LANDS the step in
+flight (waits for its ids, which become pending ids the host holds) and
+then runs as it would with none: a speculative round (draft chain +
+chunked verify, when the SLO controller has flipped speculation on),
+``last_logits`` assigned, a crash's flight record.  An iteration that
+finds no row to step only fetches.
 
 Speculative rounds keep the greedy target chain EXACT: every decided
 token is the argmax of target logits computed over a correct prefix
@@ -43,7 +66,8 @@ The host's side of a step is five spans under one
 with one ``prefill`` a request, ``sample``, ``launch`` with ``put`` and
 ``write_through`` inside, ``fetch``, ``observe``; docs/SERVING.md has
 the table), so a profiler trace shows which of them the device waits
-for; ``launch`` and ``observe`` say what work the step did.
+for; ``launch`` says what work the step it dispatches is, ``observe``
+what the step whose ids were fetched did (both carry ``dstep``).
 
 All host orchestration (clocks, metrics, env) stays OUTSIDE the jitted
 programs; the compiled pieces are the same module-cached
@@ -58,6 +82,7 @@ autotuner knobs are part of the compiled-shape key (docs/AUTOTUNE.md).
 from __future__ import annotations
 
 import atexit
+import contextlib
 import functools
 import time
 import weakref
@@ -71,7 +96,8 @@ from ..common import util
 from ..common.exceptions import InvalidRequestError
 from ..metrics import catalog as _met
 from ..models.decode import (_serve_step_fn, _spec_extend_fn,
-                             _spec_step_fn, transformer_prefill)
+                             _spec_step_fn, serve_ids_len,
+                             transformer_prefill)
 from ..models.experts import ROUTED
 from ..utils import autotune
 from ..utils.timeline import get_timeline, span
@@ -194,24 +220,42 @@ class InferenceServer:
 
         V = cfg.vocab_size
         self.row_pos = np.zeros(self.max_batch, np.int64)
-        # Each row's pending decision is an id.  The logits behind the
-        # ids are the last step's, left on the device, under the rows
-        # decided on the host since (`_fresh`: a prefill's row, a
-        # speculative round's); `last_logits` puts the two together.
+        # Each row's pending decision is an id.  The host holds it in
+        # `_next_ids`, or it is the step in flight's and `_next_ids` says
+        # -1, which is also what the row is fed: "the device's".  The
+        # logits behind the ids are the last step's, left on the device,
+        # under the rows decided on the host since (`_fresh`: a
+        # prefill's row, a speculative round's); `last_logits` puts the
+        # two together.
         self._next_ids = np.zeros(self.max_batch, np.int32)
         self._logits = np.zeros((self.max_batch, V), np.float32)
         self._fresh: Dict[int, np.ndarray] = {}
         self.logit_fetches = 0      # whole logits pulled to the host
+        # The step dispatched whose ids have not reached the host: its
+        # `dstep` and `rows` as its `launch` said them, its `ids` and
+        # the rows it stepped whose ids count (`live`).  `_ids` are the last step's, in flight or landed:
+        # the next step's `prev`.
+        self._flight: Optional[Dict] = None
+        self._ids = jax.device_put(
+            np.zeros(serve_ids_len(cfg, self.max_batch), np.int32))
         # What this iteration's sync brought, and of which device step
-        # (`_plain_step`), for `observe`; empty where none was made.
+        # (`_land`), for `observe`; empty where none was made.
         self._synced: Dict = {}
+        self._ended: List[ActiveSeq] = []   # finished this iteration
         self.step_no = 0
         self._next_req_id = 0
         self._submit_wall: Dict[int, float] = {}
-        # run stats (read by loadgen / the bench)
+        # run stats (read by loadgen / the bench).  `device_steps` and
+        # `occupancy_sum` count the steps whose ids have reached the
+        # host (`_count_step`), as the routing sums below do.
         self.tokens_out = 0
         self.device_steps = 0
         self.spec_steps = 0
+        # Plain steps dispatched while the step before was in flight,
+        # and rows such a step stepped once too often (an EOS found a
+        # step late), whose id was thrown away.
+        self.steps_ahead = 0
+        self.rows_dropped = 0
         self.occupancy_sum = 0.0
         # What a patterned model's expert layers counted (models/experts.py
         # `ROUTED`), summed over layers and steps: distinct experts a
@@ -238,11 +282,12 @@ class InferenceServer:
     @property
     def last_logits(self) -> np.ndarray:
         """The `[max_batch, vocab]` float32 logits the pending ids were
-        picked from: the last step's, with a row admitted since holding
-        its prefill's.  Fetched from the device when asked (counted in
-        `logit_fetches`) and kept until the next step; read-only, since
-        a row written in place would move no id.  Assigning an array
-        picks every pending id again from what was assigned."""
+        picked from: the last step's (waited for, if it is in flight),
+        with a row admitted since holding its prefill's.  Fetched from
+        the device when asked (counted in `logit_fetches`) and kept
+        until the next step; read-only, since a row written in place
+        would move no id.  Assigning an array lands the step in flight
+        and picks every pending id again from what was assigned."""
         if not isinstance(self._logits, np.ndarray):
             self._logits = np.array(self._logits)
             self.logit_fetches += 1
@@ -261,6 +306,7 @@ class InferenceServer:
                 f"last_logits is [max_batch, vocab] = "
                 f"{(self.max_batch, self.cfg.vocab_size)}, got "
                 f"{logits.shape}")
+        self._land()
         self._logits = logits
         self._fresh.clear()
         self._next_ids = np.argmax(logits, -1).astype(np.int32)
@@ -422,11 +468,15 @@ class InferenceServer:
         THIS step (their ``generated`` lists are complete).
 
         A crash inside the step — including ``PoolExhaustedError`` —
-        dumps the flight recorder BEFORE the exception propagates, so
-        the post-mortem ring always covers the failing step."""
+        lands the step in flight (so whoever catches finds every id on
+        the host) and dumps the flight recorder BEFORE the exception
+        propagates, so the post-mortem ring always covers the failing
+        step."""
         try:
             return self._step_impl()
         except BaseException as e:
+            with contextlib.suppress(Exception):    # the device's crash
+                self._land()
             if self.flightrec is not None:
                 reason = ("pool_exhausted"
                           if isinstance(e, PoolExhaustedError)
@@ -450,43 +500,46 @@ class InferenceServer:
         t0 = time.perf_counter()
         with span("admit", "serve"):
             admitted = self._admit()
-        finished: List[ActiveSeq] = []
+        self._synced = {}
+        # A speculative round reads and decides on the host: it starts
+        # from every id there.
+        spec = (self.draft_params is not None and bool(self.sched.active)
+                and (self.force_spec or self.slo.update(self.step_no)))
+        if spec:
+            self._land()
         feed = np.zeros(self.max_batch, np.int64)
+        rows: List[int] = []
         with span("sample", "serve"):
             for row in sorted(self.sched.active):
                 seq = self.sched.active[row]
-                if not seq.done:
-                    tok = int(self._next_ids[row])
-                    seq.generated.append(tok)
-                    self.tokens_out += 1
-                    feed[row] = tok
-                    if len(seq.generated) == 1:
-                        self._first_token(seq)
-                if seq.done:
-                    finished.append(seq)
-                    self._finish(seq)
-        rows = sorted(self.sched.active)
+                if self._next_ids[row] < 0:    # the step in flight's
+                    if len(seq.generated) + 1 >= seq.req.max_new_tokens:
+                        continue    # ends by count once its id lands
+                    feed[row] = -1
+                elif self._emit(seq):
+                    continue
+                else:
+                    feed[row] = seq.generated[-1]
+                rows.append(row)
         decided = 0
-        self._synced = {}
-        if rows:
-            spec = (self.draft_params is not None
-                    and (self.force_spec or self.slo.update(self.step_no)))
-            if spec:
-                t_spec = time.perf_counter()
-                with span("launch", "serve"):
-                    for cache, _ in self._caches:
-                        cache.refresh()
-                    decided = self._spec_round(rows, feed)
-                spec_ms = (time.perf_counter() - t_spec) * 1e3
-                for r in rows:
-                    ob = self._req_obs.get(self.sched.active[r].req.req_id)
-                    if ob is not None:
-                        ob["spec_ms"] += spec_ms
-                self.spec_steps += 1
-            else:
-                self._plain_step(rows, feed)
-            self.device_steps += 1
-            self.occupancy_sum += len(rows) / self.max_batch
+        if not rows:
+            self._land(emit=True)   # nothing to dispatch before the sync
+        elif spec:
+            t_spec = time.perf_counter()
+            with span("launch", "serve"):
+                for cache, _ in self._caches:
+                    cache.refresh()
+                decided = self._spec_round(rows, feed)
+            spec_ms = (time.perf_counter() - t_spec) * 1e3
+            for r in rows:
+                ob = self._req_obs.get(self.sched.active[r].req.req_id)
+                if ob is not None:
+                    ob["spec_ms"] += spec_ms
+            self.spec_steps += 1
+            self._count_step(len(rows))
+        else:
+            self._plain_step(rows, feed)
+        finished, self._ended = self._ended, []
         counts = {"rows": len(rows), "admitted": admitted,
                   "finished": len(finished), "decided": 1 + decided}
         with span("observe", "serve",
@@ -500,48 +553,101 @@ class InferenceServer:
                     _met.serve_intertoken.observe(per_tok / 1e3)
             self._update_gauges()
             if self.flightrec is not None:
-                self.flightrec.record("step", counts, step=self.step_no)
+                self.flightrec.record(
+                    "step", {**counts, "steps_ahead": self.steps_ahead,
+                             "rows_dropped": self.rows_dropped},
+                    step=self.step_no)
         return finished
 
+    def _count_step(self, rows: int) -> None:
+        """A device step over `rows` rows whose ids are on the host.
+        The server's counters count those: with a step in flight
+        `device_steps`, `occupancy_sum` and the routing sums all stand
+        one step behind the dispatches, together."""
+        self.device_steps += 1
+        self.occupancy_sum += rows / self.max_batch
+
+    def _emit(self, seq: ActiveSeq) -> bool:
+        """`seq` emits its pending id, which the host holds; True where
+        that ends it: it is evicted and its row released."""
+        seq.generated.append(int(self._next_ids[seq.row]))
+        self._next_ids[seq.row] = -1    # its next: the coming step's
+        self.tokens_out += 1
+        if len(seq.generated) == 1:
+            self._first_token(seq)
+        if seq.done:
+            self._ended.append(seq)
+            self._finish(seq)
+        return seq.done
+
     def _plain_step(self, rows: Sequence[int], feed: np.ndarray) -> None:
-        """One decode step over `rows`, as three spans.  `launch`
-        (dispatches only) says what work the step is: `dstep`, the
-        ordinal of this device step (`device_steps` as it is
-        dispatched); `rows` and `rows_pct`, the rows stepped, and of
-        `max_batch`; `live_tokens`, the positions the step reads up to,
-        summed over them, each row's own new token counted (`pos + 1`:
-        what `seq.pos` reads AFTER the step); and what the cache alone
-        knows (`DecodeCache.step_args`).  Inside it `put` is the view's
-        refresh and the step's inputs put on the device,
-        `write_through` the carrying of the new slots to where they are
-        kept; the rest of `launch` is the dispatch.  `fetch` is the
-        step's one sync.  What it brought is left for `observe`
-        (`_synced`), which opens after it."""
-        base = self.row_pos.copy()
-        work = {"dstep": self.device_steps, "rows": len(rows),
+        """Dispatch one decode step over `rows`, then land the step
+        before it.  `launch` (dispatches only) says what work the step
+        is: `dstep`, the ordinal of this device step (`device_steps`,
+        and one more where the step before is still in flight: it is
+        counted when it lands); `rows` and `rows_pct`, the rows stepped, and
+        of `max_batch`; `live_tokens`, the positions the step reads up
+        to, summed over them, each row's own new token counted
+        (`pos + 1`: what `seq.pos` reads AFTER the step); what the cache
+        alone knows (`DecodeCache.step_args`); and `ahead`, 1 where the
+        ids of the step before have not reached the host: a row fed -1
+        takes its token from them on the device (`_serve_step_fn`).
+        Inside it `put` is the view's refresh and the step's inputs put
+        on the device, `write_through` the carrying of the new slots to
+        where they are kept; the rest of `launch` is the dispatch.  The
+        host needs no id for any of it: every stepped row advances by
+        one."""
+        rows = list(rows)
+        base = np.zeros_like(self.row_pos)  # a row left out idles: 0
+        base[rows] = self.row_pos[rows]
+        ahead = int(self._flight is not None)
+        work = {"dstep": self.device_steps + ahead, "rows": len(rows),
                 "rows_pct": round(100.0 * len(rows) / self.max_batch, 2),
                 "live_tokens": int(base.sum()) + len(rows),  # idle: 0
-                **self.pool.step_args(base)}
+                **self.pool.step_args(base),
+                "ahead": ahead}
         with span("launch", "serve", work):
             with span("put", "serve"):
                 for cache, _ in self._caches:
                     cache.refresh()
                 lent = self.pool.lend(base)
                 fed = jnp.asarray(feed, jnp.int32)
-            self._logits, ids, cache = _serve_step_fn(self.cfg)(
-                self.params, lent, fed)
+            self._logits, self._ids, cache = _serve_step_fn(self.cfg)(
+                self.params, lent, fed, self._ids)
             del lent, fed       # as temporaries would: let go of here
+            self._ids.copy_to_host_async()
             self._fresh.clear()
             self.pool.take_back(cache)
             with span("write_through", "serve"):
                 self.pool.write_through(rows, base)
-        # the step's one sync: the ids, not the logits they came from
-        # (behind them, a patterned model's routing counts a layer)
-        with span("fetch", "serve", {"bytes": ids.nbytes}):
-            got = np.array(ids)                # copy: row writes on admit
-        self._next_ids = got[:self.max_batch]
+        for r in rows:
+            self.row_pos[r] += 1
+            self.sched.active[r].pos = int(self.row_pos[r])
+        self.steps_ahead += ahead
+        self._land(emit=True, ahead={
+            "dstep": work["dstep"], "rows": len(rows), "ids": self._ids,
+            "live": rows})
+
+    def _land(self, emit: bool = False, ahead: Optional[Dict] = None
+              ) -> None:
+        """Wait for the ids of the step in flight, if one is: `fetch`,
+        the one sync of a step (the ids, not the logits they came from;
+        behind them, a patterned model's routing counts a layer), after
+        which `ahead`, the step just dispatched behind it, is the one in
+        flight.  What the sync brought is left for `observe`
+        (`_synced`).  The ids become the rows' pending ids on the host,
+        for the next `sample`; the iteration that would have sampled
+        them (`emit`) emits them here, and a row they end by EOS leaves
+        `ahead`, which stepped it once more: that id is dropped when it
+        lands and the row's position taken back."""
+        flight, self._flight = self._flight, ahead
+        if flight is None:
+            return
+        with span("fetch", "serve", {"bytes": flight["ids"].nbytes}):
+            got = np.asarray(flight["ids"])
+        self._count_step(flight["rows"])
         routed = got[self.max_batch:].reshape(-1, len(ROUTED))
-        self._synced = {"dstep": work["dstep"]}
+        self._synced = {"dstep": flight["dstep"]}
         if len(routed):
             hit, fullest, here = routed.sum(axis=0).tolist()  # `ROUTED`
             self.experts_hit_sum += hit
@@ -549,9 +655,14 @@ class InferenceServer:
             self.pairs_here_sum += here
             self.moe_layer_steps += len(routed)
             self._synced.update(experts_hit=hit, pairs_here=here)
-        for r in rows:
-            self.row_pos[r] += 1
-            self.sched.active[r].pos = int(self.row_pos[r])
+        for r in flight["live"]:
+            seq = self.sched.active[r]
+            self._next_ids[r] = got[r]
+            if emit and self._emit(seq) and ahead is not None \
+                    and r in ahead["live"]:
+                ahead["live"].remove(r)
+                seq.pos -= 1
+                self.rows_dropped += 1
 
     def _spec_round(self, rows: Sequence[int], feed: np.ndarray) -> int:
         """Draft-propose / chunk-verify round; returns how many EXTRA

@@ -477,6 +477,9 @@ def test_server_end_to_end(model):
         assert done[rid] == np.asarray(want)[0].tolist()
     sparse = 3
     assert srv.moe_layer_steps == sparse * srv.device_steps
+    # one step kept in flight: all but the first of the three requests
+    # that board together and the first of the fourth, which waits for them
+    assert srv.steps_ahead == srv.device_steps - 2
     routed = 4 * sparse * srv.occupancy_sum * srv.max_batch
     assert 0 < srv.pairs_here_sum < routed
     assert srv.experts_hit_sum <= 8 * srv.moe_layer_steps

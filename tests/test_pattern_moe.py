@@ -169,6 +169,9 @@ def test_served_rows_at_mixed_depths(model):
             last[seq.req.req_id] = (len(seq.generated),
                                     srv.last_logits[row].copy())
     assert srv.moe_layer_steps == 4 * srv.device_steps
+    # a step was in flight throughout, reading `last_logits` lands none:
+    # every step but the first of each wave of two requests was ahead
+    assert srv.steps_ahead == srv.device_steps - 2
     for rid, p in zip(rids, prompts):
         assert by_id[rid] == decoded(params, p, 10)[0]
         n, logits = last[rid]            # chose token n of this request

@@ -419,21 +419,58 @@ class TestInferenceServer:
             0, :len(seq.generated)].tolist()
 
 
+    def test_eos_found_a_step_late_leaves_nothing_behind(self, model):
+        """With a step in flight a row's EOS is found after the row was
+        stepped once more: that id is dropped (`rows_dropped`), no token
+        of it is emitted, its position and pages are taken back, and the
+        row's next tenant is exact.  Everything reads as on a server
+        that lands every step, which finds the EOS before it steps."""
+        cfg, params = model
+        rng = np.random.RandomState(23)
+        a, b, c = (rng.randint(0, 64, size=4) for _ in range(3))
+        ref = {}
+        for name, prompt in (("a", a), ("b", b), ("c", c)):
+            toks, _ = transformer_generate(
+                params, cfg, jnp.asarray(prompt[None], jnp.int32), 12)
+            ref[name] = np.asarray(toks)[0].tolist()
+        eos = ref["a"][2]
+        assert eos not in ref["a"][:2]
+        served = {}
+        for cls in (InferenceServer, DecidedOnHost):
+            srv = cls(params, cfg, max_seq_tokens=16, max_batch=2,
+                      page_tokens=4)
+            ids = [srv.submit(a, 12, eos_id=eos), srv.submit(b, 12),
+                   srv.submit(c, 5)]        # c waits for a's row
+            done = {s.req.req_id: s for s in srv.run()}
+            got = [done[i] for i in ids]
+            assert got[0].generated == ref["a"][:3]
+            assert got[1].generated == ref["b"]
+            assert got[2].generated == ref["c"][:5]
+            assert got[2].row == got[0].row
+            # a: its prompt, and the two steps that gave its tokens
+            assert [s.pos for s in got] == [4 + 2, 4 + 11, 4 + 4]
+            assert not srv.row_pos.any()
+            assert srv.pool.pages_free() == srv.pool.total_pages
+            served[cls] = (srv.tokens_out, srv.step_no, srv.rows_dropped,
+                           srv.device_steps)
+        assert served[InferenceServer][:2] == served[DecidedOnHost][:2]
+        assert served[InferenceServer][2:] == (
+            1, served[DecidedOnHost][3])
+        assert served[DecidedOnHost][2] == 0
+
+
 # -- the greedy pick inside the step program -------------------------------
 
 class DecidedOnHost(InferenceServer):
     """The rule the server had while the whole logits came to the host
     every step: a row's next token is `np.argmax` of its row of
-    `last_logits`, taken when the token is emitted.  The ids the step
-    program picked are thrown away."""
+    `last_logits`.  Every step is landed as soon as it is dispatched
+    and the ids its program picked are thrown away: `last_logits`,
+    assigned what was read, picks them again on the host."""
 
-    @property
-    def _next_ids(self):
-        return [int(np.argmax(row)) for row in self.last_logits]
-
-    @_next_ids.setter
-    def _next_ids(self, ids):
-        pass
+    def _plain_step(self, rows, feed):
+        super()._plain_step(rows, feed)
+        self.last_logits = self.last_logits
 
 
 def _kind_model(kind):
@@ -463,7 +500,7 @@ def _spy_on_logits(monkeypatch, name):
 class TestGreedyIdsOnDevice:
     KW = dict(max_seq_tokens=24, max_batch=3, page_tokens=4)
 
-    def _drive(self, srv, steps=40):
+    def _drive(self, srv, steps=40, prompts=None):
         """Three requests at once, four more boarding in mid-flight as
         rows come free (every row is re-used), outputs of 1 to 9."""
         rng = np.random.RandomState(8)
@@ -472,22 +509,42 @@ class TestGreedyIdsOnDevice:
         for step in range(steps):
             for at, n in plan:
                 if at == step:
-                    srv.submit(rng.randint(0, 64, size=rng.randint(3, 9)),
-                               n)
+                    prompt = rng.randint(0, 64, size=rng.randint(3, 9))
+                    rid = srv.submit(prompt, n)
+                    if prompts is not None:
+                        prompts[rid] = prompt
             for seq in srv.step():
                 tokens[seq.req.req_id] = list(seq.generated)
         assert srv.sched.drained() and len(tokens) == len(plan)
         return tokens
 
     def test_tokens_are_those_decided_on_the_host(self, kind):
+        """One step kept in flight, each row fed the device's id, gives
+        the tokens of a server that lands every step and picks on the
+        host, in as many steps; and both give `transformer_generate`'s,
+        a request alone."""
         cfg, params = _kind_model(kind)
         srv = InferenceServer(params, cfg, **self.KW)
         host = DecidedOnHost(params, cfg, **self.KW)
-        assert self._drive(srv) == self._drive(host)
-        assert srv.device_steps == host.device_steps > 10
+        prompts = {}
+        tokens = self._drive(srv, prompts=prompts)
+        assert tokens == self._drive(host)
+        assert (srv.device_steps, srv.step_no) == \
+            (host.device_steps, host.step_no)
+        assert srv.device_steps > 10
         # nobody asked for the logits; the host's rule asks every token
         assert srv.logit_fetches == 0
         assert host.logit_fetches == host.device_steps
+        # the batch never emptied before the end: every step but the
+        # first found the one before it in flight; the host's rule none
+        assert srv.steps_ahead == srv.device_steps - 1
+        assert host.steps_ahead == 0
+        assert srv.rows_dropped == host.rows_dropped == 0
+        for rid, prompt in prompts.items():
+            ref, _ = transformer_generate(
+                params, cfg, jnp.asarray(prompt[None], jnp.int32), 9)
+            assert tokens[rid] == \
+                np.asarray(ref)[0, :len(tokens[rid])].tolist()
 
     def test_last_logits_is_what_the_ids_were_picked_from(
             self, kind, monkeypatch):
@@ -546,6 +603,10 @@ class TestGreedyIdsOnDevice:
         assert seq.generated == [int(np.argmax(prefills[-1][0]))]
 
     def test_assigned_logits_move_the_tokens(self, kind):
+        """`last_logits` read and assigned between two steps, with a
+        step in flight: reading waits for its logits and lands nothing
+        (the next step is still dispatched ahead of its ids); assigning
+        lands it, so the step after starts from ids the host holds."""
         cfg, params = _kind_model(kind)
         srv = InferenceServer(params, cfg, **self.KW)
         rng = np.random.RandomState(10)
@@ -553,17 +614,54 @@ class TestGreedyIdsOnDevice:
             srv.submit(rng.randint(0, 64, size=5), 8)
         srv.step()
         srv.step()
+        # `device_steps` counts the steps whose ids have landed
+        assert (srv.device_steps, srv.steps_ahead) == (1, 1)
+        logits = srv.last_logits.copy()
+        srv.step()
+        assert (srv.device_steps, srv.steps_ahead) == (2, 2)
         logits = srv.last_logits.copy()
         n = {r: len(s.generated) for r, s in srv.sched.active.items()}
         srv.last_logits = -logits        # whole, as the rehearsal does
         np.testing.assert_array_equal(srv.last_logits, -logits)
+        # landed, not emitted: the tokens come in the step that follows
+        assert srv.device_steps == 3
+        assert n == {r: len(s.generated)
+                     for r, s in srv.sched.active.items()}
         srv.step()
+        assert (srv.device_steps, srv.steps_ahead) == (3, 2)
         assert len(n) == 3
         for r, s in srv.sched.active.items():
             assert s.generated[n[r]] == int(np.argmin(logits[r])) \
                 != int(np.argmax(logits[r]))
+        srv.step()
+        assert (srv.device_steps, srv.steps_ahead) == (4, 3)
         with pytest.raises(HorovodTpuError, match="max_batch, vocab"):
             srv.last_logits = logits[:2]
+
+    def test_an_empty_batch_lands_the_step_in_flight(self, kind):
+        """A request's last token costs no decode step: the iteration
+        that finds no row to step only fetches, and the first step of
+        the next request finds nothing in flight."""
+        cfg, params = _kind_model(kind)
+        srv = InferenceServer(params, cfg, **self.KW)
+        rng = np.random.RandomState(12)
+        for i, n in enumerate([3, 4]):
+            prompt = rng.randint(0, 64, size=5)
+            srv.submit(prompt, n)
+            grew = []
+            while not srv.sched.drained():
+                done = srv.step()
+                seqs = list(srv.sched.active.values()) + done
+                grew.append(sum(len(s.generated) for s in seqs))
+            # a token a `server.step()`, the first in the admitting one
+            assert grew == list(range(1, n + 1))
+            assert srv.step_no == [3, 7][i]
+            assert srv.device_steps == [2, 5][i]
+            assert srv.steps_ahead == [1, 3][i]
+            ref, _ = transformer_generate(
+                params, cfg, jnp.asarray(prompt[None], jnp.int32), n)
+            assert seqs[-1].generated == np.asarray(ref)[0].tolist()
+        assert srv.rows_dropped == 0
 
 
 @pytest.mark.parametrize("rounds", ["SSPPPP", "PPSSPP", "SPSPSP"])
@@ -581,13 +679,26 @@ def test_speculative_rounds_among_plain_steps(model, rounds):
     for _ in range(3):
         prompt = rng.randint(0, 64, size=4)
         reqs[srv.submit(prompt, 14)] = prompt
-    got = {}
+    plain_steps = []
+    real = srv._plain_step
+    srv._plain_step = lambda rows, feed: (plain_steps.append(srv.step_no),
+                                          real(rows, feed))
+    got, ahead, was_plain = {}, 0, False
     for i in range(60):
         srv.force_spec = rounds[i % len(rounds)] == "S"
         for seq in srv.step():
             got[seq.req.req_id] = seq.generated
+        # a plain step is dispatched ahead of the ids of the step before
+        # exactly where that was a plain step too: a round lands it, and
+        # so does an iteration that finds no row to step
+        plain = plain_steps[-1:] == [i]
+        ahead += plain and was_plain
+        was_plain = plain
     assert srv.sched.drained()
     assert 0 < srv.spec_steps < srv.device_steps
+    assert len(plain_steps) == srv.device_steps - srv.spec_steps
+    assert srv.steps_ahead == ahead < srv.device_steps - srv.spec_steps
+    assert (ahead > 0) == ("PP" in rounds)
     for rid, prompt in reqs.items():
         ref, _ = transformer_generate(
             params, cfg, jnp.asarray(prompt[None], jnp.int32), 14)

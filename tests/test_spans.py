@@ -24,8 +24,10 @@ from horovod_tpu.utils.timeline import span, start_timeline, stop_timeline
 PHASES = ("admit", "sample", "launch", "fetch", "observe")
 INSIDE_LAUNCH = ("put", "write_through")
 # What a plain step's `launch` says of its work whatever the cache, what
-# each kind of cache adds, and what `observe` says after the step's sync.
-WORK = {"dstep", "rows", "rows_pct", "live_tokens"}
+# each kind of cache adds, and what `observe` says after the iteration's
+# sync, which is of the step dispatched the iteration BEFORE: one step is
+# kept in flight.
+WORK = {"dstep", "rows", "rows_pct", "live_tokens", "ahead"}
 CACHE_SAYS = {"paged": {"view_read_pct"},
               "windowed": {"view_read_pct", "ring_tokens"},
               "retention": {"state_read_pct"},
@@ -47,13 +49,13 @@ def model():
     return cfg, transformer_init(jax.random.PRNGKey(0), cfg)
 
 
-def _serve(model, after_step=None, **kw):
+def _serve(model, after_step=None, cls=InferenceServer, **kw):
     """Five requests through a two-row server; -> (server, prompts by
     request, generated tokens by request).  `after_step(server)` is
     called between steps, where a benchmark's runner looks."""
     cfg, params = model
-    srv = InferenceServer(params, cfg, max_seq_tokens=24, max_batch=2,
-                          page_tokens=4, **kw)
+    srv = cls(params, cfg, max_seq_tokens=24, max_batch=2, page_tokens=4,
+              **kw)
     rng = np.random.RandomState(2)
     prompts = {}
     for n in OUTPUTS:
@@ -184,8 +186,8 @@ def test_step_and_observe_carry_the_steps_counts(traced):
         obs, = _inside(traced["spans"], s, "hvd.serve.observe")
         # a step that made a sync says of which device step; this model
         # routes nothing, so nothing of experts
-        assert set(obs[3]) == COUNTS | ({"dstep"} if obs[3]["rows"]
-                                        else set())
+        synced = bool(_inside(traced["spans"], s, "hvd.serve.fetch"))
+        assert set(obs[3]) == COUNTS | ({"dstep"} if synced else set())
         assert obs[3]["step"] == s[3]["step"]
         prefills = _inside(traced["spans"], s, "hvd.serve.prefill")
         assert obs[3]["admitted"] == len(prefills)
@@ -199,6 +201,7 @@ def test_step_and_observe_carry_the_steps_counts(traced):
 
 def test_phases_nest_without_overlap_and_cover_the_step(traced):
     covered = total = 0
+    in_flight = False       # a step dispatched and not fetched, on entry
     for s in _steps(traced["spans"]):
         launches = _inside(traced["spans"], s, "hvd.serve.launch")
         held = [k for l in launches for k in _inside(traced["spans"], l)]
@@ -206,10 +209,14 @@ def test_phases_nest_without_overlap_and_cover_the_step(traced):
                 if k[0] != "hvd.serve.prefill" and k not in held]
         names = [k[0].rsplit(".", 1)[1] for k in kids]
         rows = _inside(traced["spans"], s, "hvd.serve.observe")[0][3]["rows"]
-        # a step that decodes has all five, in this order; one that only
-        # retires its last rows launches and fetches nothing
-        assert names == (list(PHASES) if rows
-                         else ["admit", "sample", "observe"])
+        # in this order: a step that decodes launches, and BEHIND the
+        # launch fetches the ids of the step the iteration before
+        # launched; the first after a drain has none to fetch; one that
+        # only retires its last rows launches nothing and only fetches
+        assert names == [p for p in PHASES
+                         if (p != "launch" or rows)
+                         and (p != "fetch" or in_flight)]
+        in_flight = bool(rows)
         for a, b in zip(kids, kids[1:]):
             assert a[2] <= b[1]
         # the host's two parts of a launch lie inside it, one after the
@@ -220,6 +227,7 @@ def test_phases_nest_without_overlap_and_cover_the_step(traced):
             assert a[2] <= b[1]
         covered += sum(k[2] - k[1] for k in kids)
         total += s[2] - s[1]
+    assert not in_flight                    # the drain landed the last
     assert covered >= 0.95 * total
     # nothing of the server lies outside a step
     in_steps = sum(len(_inside(traced["spans"], s)) + 1
@@ -264,9 +272,10 @@ def test_timeline_prefill_event_keeps_its_shape(traced):
 
 
 def test_fetch_carries_the_bytes_of_the_steps_one_sync(traced):
-    """A plain step syncs on `[max_batch]` int32 ids (max_batch 2), and
-    the whole logits come to the host only for who reads `last_logits`,
-    which `logit_fetches` counts."""
+    """A plain step is synced on once, an iteration after its launch:
+    on its `[max_batch]` int32 ids (max_batch 2); the whole logits come
+    to the host only for who reads `last_logits`, which `logit_fetches`
+    counts."""
     fetches = [s for s in traced["spans"] if s[0] == "hvd.serve.fetch"]
     assert len(fetches) == traced["srv"].device_steps
     assert all(f[3] == {"bytes": 4 * 2} for f in fetches)
@@ -427,9 +436,8 @@ def test_uniform_model_counts_no_routing(traced):
 @pytest.fixture(scope="module", params=sorted(CACHE_SAYS))
 def worked(request, model, tmp_path_factory):
     """A server of each kind of cache run inside a profiler session,
-    with what a benchmark's runner would have summed between its steps
-    from `sched.active` (runners/lm_serve.py `live_tokens_sum`,
-    pattern_serve.py `ring_tokens_sum`) kept beside."""
+    with what a runner would sum between its steps from `sched.active`
+    (each row's `pos`, and `min(pos, window)`) kept beside."""
     kind = request.param
     cfg, params = model
     if kind == "windowed":
@@ -460,25 +468,72 @@ def _named(spans, name):
 
 def test_launch_and_observe_say_what_the_cache_can(worked):
     """`launch` of a plain step: the step's ordinal, its rows and live
-    tokens, and from the cache its own share read, `ring_tokens` from a
-    cache that keeps rings and from no other.  `observe`: the step whose
-    sync it follows, and that step's routing from a model that routes."""
+    tokens, whether the step before was still in flight, and from the
+    cache its own share read, `ring_tokens` from a cache that keeps
+    rings and from no other.  `observe`: the rows of the step this
+    iteration launched, and of the step whose ids it FETCHED, the one
+    launched an iteration before, the ordinal and, from a model that
+    routes, that step's routing."""
     kind, spans = worked["kind"], worked["spans"]
     launches, observes = _named(spans, "launch"), _named(spans, "observe")
     assert launches
     for s in launches:
         assert set(s[3]) == WORK | CACHE_SAYS[kind]
         assert s[3]["rows_pct"] == 100.0 * s[3]["rows"] / 2
-    synced = [o for o in observes if o[3]["rows"]]
-    assert len(synced) == len(launches)
+    assert [o[3]["rows"] for o in observes if o[3]["rows"]] == \
+        [s[3]["rows"] for s in launches]
+    synced = [o for o in observes if "dstep" in o[3]]
     for o in observes:
         assert set(o[3]) == COUNTS | (
-            set() if not o[3]["rows"]
+            set() if o not in synced
             else {"dstep"} | ROUTED if kind in ROUTING else {"dstep"})
-    # the sync an iteration makes is of the step it launched
+    # every step is synced on once, in the order of the launches
     assert [o[3]["dstep"] for o in synced] == \
         [s[3]["dstep"] for s in launches]
-    assert [o[3]["rows"] for o in synced] == [s[3]["rows"] for s in launches]
+    # and not by the iteration that launched it: by the next, which
+    # launches its own step (if it has a row to step) BEFORE it fetches
+    steps = _steps(spans)
+    for step, after in zip(steps, steps[1:] + [None]):
+        launch = _inside(spans, step, "hvd.serve.launch")
+        fetch = _inside(spans, step, "hvd.serve.fetch")
+        obs, = _inside(spans, step, "hvd.serve.observe")
+        assert len(launch) <= 1 and len(fetch) <= 1
+        if launch and fetch:
+            assert launch[0][2] <= fetch[0][1]
+            assert launch[0][3]["ahead"] == 1
+            assert obs[3]["dstep"] == launch[0][3]["dstep"] - 1
+        elif launch:
+            assert launch[0][3]["ahead"] == 0 and "dstep" not in obs[3]
+        if launch:
+            fetched_by, = _inside(spans, after, "hvd.serve.observe")
+            assert fetched_by[3]["dstep"] == launch[0][3]["dstep"]
+
+
+class LandsEveryStep(InferenceServer):
+    """The order the server had before it kept a step in flight: a
+    step's ids are waited for as soon as it is dispatched, and every row
+    is fed from the host."""
+
+    def _plain_step(self, rows, feed):
+        super()._plain_step(rows, feed)
+        self._land()
+
+
+def test_a_step_in_flight_moves_no_token_of_any_cache(worked):
+    """Five requests through two rows, so that rows board in mid-flight
+    and every row is re-used, a step kept in flight throughout: token
+    for token, step for step and count for count what the server gives
+    that lands every step.  (Against `transformer_generate`: the paged
+    and the state cache in test_serve.py and above, the patterned in
+    test_pattern_moe.py, the latent in test_latent.py.)"""
+    srv = worked["srv"]
+    landed, _, tokens = _serve(worked["model"], cls=LandsEveryStep)
+    assert tokens == worked["tokens"]
+    for name in ("device_steps", "step_no", "occupancy_sum", "tokens_out",
+                 "experts_hit_sum", "expert_load_max_sum", "pairs_here_sum",
+                 "moe_layer_steps", "rows_dropped"):
+        assert getattr(srv, name) == getattr(landed, name)
+    assert landed.steps_ahead == 0 < srv.steps_ahead
 
 
 def test_sums_of_the_arguments_are_the_servers_counters(worked):
@@ -490,6 +545,9 @@ def test_sums_of_the_arguments_are_the_servers_counters(worked):
     assert sum(s[3]["rows"] for s in launches) == srv.occupancy_sum * 2
     assert sum(s[3]["rows_pct"] for s in launches) == \
         100 * srv.occupancy_sum
+    assert sum(s[3]["ahead"] for s in launches) == srv.steps_ahead
+    # every step but the first of a batch: this traffic never drains
+    assert srv.steps_ahead == srv.device_steps - 1
     assert sum(s[3]["live_tokens"] for s in launches) == \
         worked["summed"]["live_tokens"]
     assert sum(o[3].get("experts_hit", 0) for o in observes) == \
@@ -514,7 +572,9 @@ def test_put_and_write_through_lie_inside_launch(worked):
     """The host's two parts of a launch, for every cache (a retention
     server carries nothing to anywhere: its `write_through` opens all
     the same, around nothing), in order and apart; nothing else lies in
-    a launch, and each launch is followed by its fetch."""
+    a launch, not the fetch either: a launch is followed by the fetch of
+    the step BEFORE its own, and its own step's comes behind the next
+    launch (or, the last one's, alone in the draining iteration)."""
     spans = worked["spans"]
     launches = _named(spans, "launch")
     for l in launches:
@@ -523,8 +583,13 @@ def test_put_and_write_through_lie_inside_launch(worked):
                                    "hvd.serve.write_through")
         assert l[1] <= put[1] <= put[2] <= wt[1] <= wt[2] <= l[2]
         assert put[3] == {} and wt[3] == {}
+    fetches = _named(spans, "fetch")
     assert len(_named(spans, "put")) == len(launches) == \
-        len(_named(spans, "write_through")) == len(_named(spans, "fetch"))
+        len(_named(spans, "write_through")) == len(fetches)
+    for mine, following, fetch in zip(launches, launches[1:] + [None],
+                                      fetches):
+        assert mine[2] <= fetch[1]
+        assert following is None or following[2] <= fetch[1]
 
 
 def test_tracing_moves_no_token_of_any_cache(worked):
@@ -535,7 +600,7 @@ def test_tracing_moves_no_token_of_any_cache(worked):
     assert tokens == worked["tokens"]
     for name in ("device_steps", "step_no", "occupancy_sum", "tokens_out",
                  "experts_hit_sum", "expert_load_max_sum",
-                 "moe_layer_steps"):
+                 "moe_layer_steps", "steps_ahead", "rows_dropped"):
         assert getattr(srv, name) == getattr(worked["srv"], name)
 
 

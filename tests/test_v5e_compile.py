@@ -91,6 +91,15 @@ def _step_args(one_chip, cfg, rows, slots, quantize=None):
     return one_chip(params), one_chip(cache), one_chip(tokens)
 
 
+def _prev_ids(one_chip, cfg, rows):
+    """The fourth argument of the SERVER's step (`_serve_step_fn`): the
+    ids the step before left on the device, which a row fed -1 takes its
+    token from."""
+    from horovod_tpu.models.decode import serve_ids_len
+    return one_chip(jax.ShapeDtypeStruct((serve_ids_len(cfg, rows),),
+                                         jnp.int32))
+
+
 # mistral7b_doc_saturated's view (benchmark/traffic/doc_saturated.json):
 # 28 rows x 3840 slots, 7.5 of the kernel's blocks of 512.
 DOC_ROWS, DOC_SLOTS = 28, 3840
@@ -150,7 +159,7 @@ def test_server_step_picks_its_ids_in_the_one_program(one_chip, widths,
     args = _step_args(one_chip, cfg, rows, slots)
     cache = args[1]
     bare = _spec_step_fn(cfg).lower(*args).compile()
-    lowered = _serve_step_fn(cfg).lower(*args)
+    lowered = _serve_step_fn(cfg).lower(*args, _prev_ids(one_chip, cfg, rows))
     logits, ids, out_cache = lowered.out_info
     assert (logits.shape, logits.dtype) == ((rows, cfg.vocab_size),
                                             jnp.float32)
@@ -179,7 +188,8 @@ def test_retention_step_passes_over_its_state_where_it_lies(one_chip):
     args = _step_args(one_chip, cfg, STATE_ROWS, 1)
     cache = args[1]
     assert cache["s"].shape == (8, 16, 8, 8320, 128)
-    compiled = _serve_step_fn(cfg).lower(*args).compile()
+    compiled = _serve_step_fn(cfg).lower(
+        *args, _prev_ids(one_chip, cfg, STATE_ROWS)).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     mem = compiled.memory_analysis()
@@ -219,7 +229,8 @@ def test_patterned_step_copies_neither_cache_nor_experts(one_chip,
     args = _step_args(one_chip, cfg, PATTERN_ROWS, PATTERN_SLOTS)
     cache = dict(args[1])
     cache.pop("routed")             # as the server's cache lends it
-    lowered = _serve_step_fn(cfg).lower(args[0], cache, args[2])
+    lowered = _serve_step_fn(cfg).lower(
+        args[0], cache, args[2], _prev_ids(one_chip, cfg, PATTERN_ROWS))
     logits, ids, out_cache = lowered.out_info
     assert ids.shape == (PATTERN_ROWS + 4 * 3,)     # `experts.ROUTED`
     assert out_cache["k"]["sliding_attention"].shape == (3, 32, 8, 512, 128)
@@ -411,7 +422,8 @@ def test_latent_cells_step_fits_and_reads_where_it_lies(one_chip,
     cache.pop("routed")             # as the server's cache lends it
     assert cache["k"]["latent"].shape == (5, rows, 1, slots, 512)
     assert cache["v"]["latent"].shape == (5, rows, 1, slots, 128)
-    lowered = _serve_step_fn(cfg).lower(args[0], cache, args[2])
+    lowered = _serve_step_fn(cfg).lower(
+        args[0], cache, args[2], _prev_ids(one_chip, cfg, rows))
     assert lowered.out_info[1].shape == (rows + 4 * 3,)
     compiled = lowered.compile()
     text = compiled.as_text()
